@@ -17,8 +17,9 @@ key, and the test suite forces `--xla_force_host_platform_device_count=8`
 `FTS_WARMUP=1 pytest tests/` (the session fixture shares the suite's
 flags), and use this CLI for the bench/production environment.
 
-Run this once after changing kernels, jax versions, or clearing
-`~/.cache/fts_tpu_jax` (override: FTS_TPU_JAX_CACHE): afterwards every
+Run this once after changing kernels, jax versions, or clearing the
+persistent cache (the directory named by JAX_COMPILATION_CACHE_DIR, else
+`<checkout>/.jax_cache`): afterwards every
 `BatchedTransferVerifier.verify`, test session, and bench run replays the
 whole verify plane from persistent-cache hits — zero recompiles
 (`cache_misses` stays 0 in the `ftsmetrics show` compile summary).

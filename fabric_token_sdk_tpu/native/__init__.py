@@ -4,6 +4,12 @@
 challenge recomputation over verified blocks. The .so is compiled once
 with the system C compiler into this package directory; any failure falls
 back to pure-Python hashlib transparently.
+
+`_fastser.so` and `_bn254.so` are BUILD PRODUCTS of the committed
+`fastser.c` / `bn254.c`, never inputs: they are git-ignored, `_load`
+builds them when absent or older than their source, and `chip_smoke.py`
+removes and rebuilds both on every run so a stale binary left in a
+working tree is never what a bring-up run trusts.
 """
 
 from __future__ import annotations

@@ -26,12 +26,15 @@ import jax as _jax
 # XLA AOT cache entries bake in the compiling host's CPU features; loading
 # an entry produced on a different machine triggers cpu_aot_loader
 # machine-feature-mismatch warnings ("could lead to SIGILL") and, worse,
-# can crash mid-kernel (the BENCH_r05 rc=124). The persistent cache dir is
-# therefore HOST-KEYED: the first process writes a HOST_FINGERPRINT marker
-# (platform + codegen-relevant CPU flags); any later process whose
-# fingerprint differs is diverted to a per-host subdirectory, so foreign
-# AOT entries are NEVER loaded. Diversions count the entries they skipped
-# under `jax.cache.foreign_skipped`. Opt out: FTS_CACHE_FINGERPRINT=0.
+# can crash mid-kernel (the BENCH_r05 rc=124). The DEFAULT (in-checkout)
+# cache dir is therefore HOST-KEYED: the first process writes a
+# HOST_FINGERPRINT marker (platform + codegen-relevant CPU flags); any
+# later process whose fingerprint differs — a checkout copied to another
+# machine with its cache — is diverted to a per-host subdirectory, so
+# foreign AOT entries are NEVER loaded. Diversions count the entries they
+# skipped under `jax.cache.foreign_skipped`. Opt out:
+# FTS_CACHE_FINGERPRINT=0. A directory named by JAX_COMPILATION_CACHE_DIR
+# is used exactly as given — whoever placed it owns its contents.
 
 _FINGERPRINT_MARKER = "HOST_FINGERPRINT"
 
@@ -126,17 +129,25 @@ def _resolve_cache_dir(base: str, fingerprint: str) -> str:
 
 
 # Persistent compilation cache: the pairing/Miller programs are large and
-# XLA (esp. :CPU) compiles them slowly; cache them across processes.
-_cache_dir = _os.environ.get(
-    "FTS_TPU_JAX_CACHE", _os.path.expanduser("~/.cache/fts_tpu_jax")
+# XLA compiles them slowly; cache them across processes. The directory is
+# placed from OUTSIDE: when JAX_COMPILATION_CACHE_DIR is set, jax has
+# already read it into `jax_compilation_cache_dir` and nothing here
+# touches it. Otherwise the cache lives at the fixed path
+# `<checkout>/.jax_cache` (git-ignored), derived from this package's own
+# location — never from the home directory, a temp name, a pid or the
+# time, because a cache that moves between runs never hits.
+_CHECKOUT = _os.path.dirname(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 )
-if _os.environ.get("FTS_CACHE_FINGERPRINT", "1") != "0":
-    _cache_dir = _resolve_cache_dir(_cache_dir, host_fingerprint())
-try:
+DEFAULT_CACHE_DIR = _os.path.join(_CHECKOUT, ".jax_cache")
+
+
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _cache_dir = DEFAULT_CACHE_DIR
+    if _os.environ.get("FTS_CACHE_FINGERPRINT", "1") != "0":
+        _cache_dir = _resolve_cache_dir(_cache_dir, host_fingerprint())
     _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-except Exception:  # older jax without the knobs
-    pass
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
 
 
 # ---------------------------------------------------------- observability
@@ -159,37 +170,34 @@ def _install_jax_monitoring() -> None:
     def _event_name(raw: str) -> str:
         return "jax." + raw.strip("/").replace("/", ".").removeprefix("jax.")
 
-    try:
-        from jax import monitoring as _mon
+    from jax import monitoring as _mon
 
-        def _on_event(name, **kw):
-            _mx.REGISTRY.counter(_event_name(name)).inc()
-            # cache traffic is a lifecycle event: a run that suddenly
-            # starts MISSING the persistent cache shows up in the flight
-            # ring right next to the phase that triggered it
-            if "compilation_cache" in name:
-                ev = _event_name(name)
-                _mx.flight("cache", event=ev)
-                # listeners fire synchronously on the compiling thread,
-                # so the dispatch ledger's active frame names the
-                # program whose cache entry this was
-                _devobs.note_cache(ev)
+    def _on_event(name, **kw):
+        _mx.REGISTRY.counter(_event_name(name)).inc()
+        # cache traffic is a lifecycle event: a run that suddenly
+        # starts MISSING the persistent cache shows up in the flight
+        # ring right next to the phase that triggered it
+        if "compilation_cache" in name:
+            ev = _event_name(name)
+            _mx.flight("cache", event=ev)
+            # listeners fire synchronously on the compiling thread,
+            # so the dispatch ledger's active frame names the
+            # program whose cache entry this was
+            _devobs.note_cache(ev)
 
-        def _on_duration(name, duration, **kw):
-            # the histogram's own `count` is the event count — e.g. the
-            # backend_compile histogram count IS the distinct-program count
-            _mx.REGISTRY.histogram(_event_name(name) + ".seconds").observe(duration)
-            if "backend_compile" in name:
-                _mx.flight(
-                    "compile", seconds=round(duration, 3),
-                    program=_devobs.current_program(),
-                )
-                _devobs.note_compile(duration)
+    def _on_duration(name, duration, **kw):
+        # the histogram's own `count` is the event count — e.g. the
+        # backend_compile histogram count IS the distinct-program count
+        _mx.REGISTRY.histogram(_event_name(name) + ".seconds").observe(duration)
+        if "backend_compile" in name:
+            _mx.flight(
+                "compile", seconds=round(duration, 3),
+                program=_devobs.current_program(),
+            )
+            _devobs.note_compile(duration)
 
-        _mon.register_event_listener(_on_event)
-        _mon.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # older jax without monitoring
-        pass
+    _mon.register_event_listener(_on_event)
+    _mon.register_event_duration_secs_listener(_on_duration)
 
     # Persistent-cache load failures surface as `warnings.warn(...)` from
     # jax._src.compiler (`Error reading persistent compilation cache

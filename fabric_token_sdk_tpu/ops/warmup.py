@@ -3,9 +3,9 @@
 The staged execution model (`ops/stages.py`, `ops/pairing.py` tiles)
 makes the verifier's distinct-program set a small constant; this module
 compiles that whole set ahead of time — populating the persistent XLA
-compilation cache (`FTS_TPU_JAX_CACHE`, default `~/.cache/fts_tpu_jax`) —
-so no verify, test, or benchmark ever pays a surprise giant compile
-mid-flight. After `warmup()` (or `python cmd/ftswarmup.py`), a
+compilation cache (`JAX_COMPILATION_CACHE_DIR`, else
+`<checkout>/.jax_cache`; see `ops/__init__.py`) — so no verify, test, or
+benchmark ever pays a surprise giant compile mid-flight. After `warmup()` (or `python cmd/ftswarmup.py`), a
 `BatchedTransferVerifier.verify` recompiles nothing: every program loads
 as a `jax.compilation_cache.cache_hits` hit (`cache_misses` stays 0).
 
@@ -99,11 +99,8 @@ def warmup(
     """
     prev_min_compile = None
     if persist_all:
-        try:
-            prev_min_compile = jax.config.jax_persistent_cache_min_compile_time_secs
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:  # older jax without the knob
-            pass
+        prev_min_compile = jax.config.jax_persistent_cache_min_compile_time_secs
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     before = {c: mx.REGISTRY.counter(c).value for c in _CACHE_COUNTERS}
     compiles_before = mx.REGISTRY.histogram(_COMPILES).count
@@ -129,13 +126,10 @@ def warmup(
         # confine persist-everything to the warmup set: later incidental
         # compiles go back to the configured persistence threshold
         if prev_min_compile is not None:
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs",
-                    prev_min_compile,
-                )
-            except Exception:
-                pass
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs",
+                prev_min_compile,
+            )
     total = time.time() - t_total
     summary = {
         "programs": len(programs),

@@ -49,7 +49,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import curve as cv, pairing as pr, stages as st, tower as tw
@@ -163,11 +162,11 @@ def _fused_pairing_product(Ps, Qs, mesh: Mesh):
     pads/degrades)."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("dp", "mp"), P("dp", "mp")),
         out_specs=P("dp"),
-        check_rep=False,
+        check_vma=False,
     )
     def run(ps, qs):
         f = pr.miller_loop(ps, qs)  # (b_local, k_local, 6, 2, L)

@@ -54,6 +54,7 @@ DEGRADED_REQUIRED = {
 # type-checked when present; a tuple including NoneType allows null
 _NULLABLE_NUM = _NUM + (type(None),)
 OPTIONAL = {
+    "device_kind": str,  # jax.devices()[0].device_kind, beside `platform`
     "prove_vs_host": _NULLABLE_NUM,
     "prove_txs_per_s": _NULLABLE_NUM,  # nullable in the degraded form
     "stage_warmup_s": _NUM,

@@ -1,4 +1,4 @@
-"""Compile-budget regression guards (VERDICT round-5 weak #4).
+"""Compile-budget regression guards (the round-5 review's weak #4).
 
 The staged stage/pairing tiles exist so that the number of distinct XLA
 programs stays a SMALL CONSTANT as batch size, transfer shape
@@ -387,6 +387,52 @@ def test_foreign_cache_dir_is_never_loaded(tmp_path):
     marker.write_text("")
     assert ops._resolve_cache_dir(base, fp) == base
     assert marker.read_text().strip() == fp
+
+
+def test_cache_dir_defaults_to_the_checkout():
+    """Nothing placed the cache from outside: it lives at the fixed
+    in-checkout path (a cache that moves between runs never hits), not
+    under the home directory."""
+    import jax
+
+    from fabric_token_sdk_tpu import ops
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert ops.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    assert jax.config.jax_compilation_cache_dir == (
+        placed or ops.DEFAULT_CACHE_DIR
+    )
+
+
+def test_cache_dir_placed_from_outside_is_used_exactly(tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR` set: the program uses exactly that
+    directory — entries land there, and nothing of ours (fingerprint
+    marker, per-host subdirectory) is written into it."""
+    import subprocess
+    import sys
+
+    placed = tmp_path / "placed"
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "import fabric_token_sdk_tpu.ops\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 3 + 1)(jnp.arange(8)).block_until_ready()\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", script], cwd=repo, capture_output=True,
+        text=True, timeout=120,
+        env=dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(placed)),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == str(placed)
+    names = sorted(os.listdir(placed))
+    assert any(n.endswith("-cache") for n in names), names
+    assert not any(
+        n == "HOST_FINGERPRINT" or n.startswith("host-") for n in names
+    ), names
 
 
 def _prove_reqs(pp, rng, in_vals, out_vals, count):
