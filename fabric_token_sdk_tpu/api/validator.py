@@ -26,6 +26,7 @@ from .driver import Driver, ValidationError
 from .request import TokenRequest
 from ..drivers import identity
 from ..models.token import ID
+from ..utils import devobs
 
 
 @dataclass
@@ -74,7 +75,18 @@ class RequestValidator:
         input_match leg pins to ledger state, so the driver skips its
         per-tx conservation arithmetic. Records without a verdict (and
         every failure) run the full scalar checks.
+
+        The call is `fts:validate` in the host plane of a profiler trace
+        (`utils/devobs.py:annotate`).
         """
+        with devobs.annotate("validate"):
+            return self._validate(
+                request, resolve_input, now, transfer_proofs, sig_verified,
+                conservation,
+            )
+
+    def _validate(self, request, resolve_input, now, transfer_proofs,
+                  sig_verified, conservation) -> ValidationResult:
         result = ValidationResult()
         payload = request.marshal_to_sign()
         sv = sig_verified or {}

@@ -66,10 +66,13 @@ class _MeshBound:
 
 def _spanned(name):
     """Wrap a verify method in a metrics span (no-op when disabled) and
-    a dispatch-ledger plane tag (`utils/devobs.py`): every stage
-    dispatch the method triggers records its occupancy under the plane
-    named by the span's middle token (`batch.sign.verify` -> `sign`,
-    every `batch.*.verify` verifier -> `verify`)."""
+    a dispatch-ledger plane (`utils/devobs.py`): every stage dispatch
+    the method triggers records its occupancy and its enqueue / wait
+    time under the plane named by the span's middle token
+    (`batch.sign.verify` -> `sign`, every `batch.*.verify` verifier ->
+    `verify`). The outermost call on a thread is the plane span, whose
+    time outside any dispatch frame is the plane's host glue; a nested
+    verifier (transfer -> wf / membership / ps) is part of it."""
     middle = name.split(".")[1] if "." in name else name
     plane = middle if middle in ("sign", "prove") else "verify"
 

@@ -369,25 +369,32 @@ def _product_rows(f):
     return f[:, 0]
 
 
-def _miller_tiles(Pf, Qf, start: int, stop: int):
-    """Sequential miller-tile walk over [start, stop) tile indices."""
-    return [
-        np.asarray(
-            miller_loop(
+def _miller_tiles(frame, Pf, Qf, start: int, stop: int):
+    """Sequential miller-tile walk over [start, stop) tile indices: each
+    tile is enqueued (`frame.tile()`) and then read back
+    (`frame.wait()`), one round trip at a time."""
+    outs = []
+    for t in range(start * MILLER_TILE, stop * MILLER_TILE, MILLER_TILE):
+        with frame.tile():
+            f = miller_loop(
                 jnp.asarray(Pf[t : t + MILLER_TILE]),
                 jnp.asarray(Qf[t : t + MILLER_TILE]),
             )
-        )
-        for t in range(start * MILLER_TILE, stop * MILLER_TILE, MILLER_TILE)
-    ]
+        with frame.wait():
+            outs.append(np.asarray(f))
+    return outs
 
 
-def _fexp_tiles(f, start: int, stop: int):
-    """Sequential product+final-exp walk over [start, stop) tile indices."""
-    return [
-        np.asarray(final_exp(_product_rows(jnp.asarray(f[t : t + FEXP_TILE]))))
-        for t in range(start * FEXP_TILE, stop * FEXP_TILE, FEXP_TILE)
-    ]
+def _fexp_tiles(frame, f, start: int, stop: int):
+    """Sequential product+final-exp walk over [start, stop) tile indices
+    (enqueue, then read back, per tile — as `_miller_tiles`)."""
+    outs = []
+    for t in range(start * FEXP_TILE, stop * FEXP_TILE, FEXP_TILE):
+        with frame.tile():
+            gt = final_exp(_product_rows(jnp.asarray(f[t : t + FEXP_TILE])))
+        with frame.wait():
+            outs.append(np.asarray(gt))
+    return outs
 
 
 def _sharded_tiles(fn, ntiles: int, workers: int, *args):
@@ -444,16 +451,20 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
     mx.counter("pairing.staged.rows").inc(B)
     mx.counter("pairing.staged.legs").inc(N)
     mx.counter("pairing.staged.miller_tiles").inc((N + pad) // MILLER_TILE)
+    # the two ledger frames below (utils/devobs.py) are the only timers
+    # of the tile walks; `pairing.product_staged` spans the whole call
     with mx.span("pairing.product_staged", rows=B, legs_per_row=K):
         # all inter-stage glue (concat/mask/reshape/pad) stays in numpy so
         # the ONLY device programs are the three tile kernels — no
         # per-shape concatenate/select programs on the accelerator
+        n_miller = (N + pad) // MILLER_TILE
         with devobs.dispatch(
-            "miller_tile", rows=N, padded_rows=pad, dp=dp, mp=mp
-        ), mx.timed("pairing.staged.miller.seconds"):
+            "miller_tile", rows=N, padded_rows=pad, tiles=n_miller,
+            dp=dp, mp=mp,
+        ) as frame:
             f = np.concatenate(
                 _sharded_tiles(
-                    _miller_tiles, (N + pad) // MILLER_TILE, dp * mp, Pf, Qf
+                    _miller_tiles, n_miller, dp * mp, frame, Pf, Qf
                 ),
                 axis=0,
             )
@@ -469,13 +480,12 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
             f = np.concatenate(
                 [f, np.broadcast_to(one_np, (padB, K, 6, 2, L))], axis=0
             )
-        mx.counter("pairing.staged.fexp_tiles").inc((B + padB) // FEXP_TILE)
+        n_fexp = (B + padB) // FEXP_TILE
+        mx.counter("pairing.staged.fexp_tiles").inc(n_fexp)
         with devobs.dispatch(
-            "fexp_tile", rows=B, padded_rows=padB, dp=dp
-        ), mx.timed("pairing.staged.product_fexp.seconds"):
-            gts = _sharded_tiles(
-                _fexp_tiles, (B + padB) // FEXP_TILE, dp, f
-            )
+            "fexp_tile", rows=B, padded_rows=padB, tiles=n_fexp, dp=dp
+        ) as frame:
+            gts = _sharded_tiles(_fexp_tiles, n_fexp, dp, frame, f)
     return np.concatenate(gts, axis=0)[:B]
 
 

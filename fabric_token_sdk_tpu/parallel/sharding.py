@@ -227,12 +227,15 @@ def sharded_pairing_product(Ps, Qs, mesh, fused: Optional[bool] = None):
             pad = (-B) % cfg.dp
             with devobs.dispatch(
                 "fused_pairing", rows=B * K, padded_rows=pad * K,
-                dp=cfg.dp, mp=cfg.mp,
-            ):
-                gt = _fused_pairing_product(
-                    shard_rows(Ps, mesh), shard_rows(Qs, mesh), mesh
-                )
-            return np.asarray(gt)[:B]
+                tiles=1, dp=cfg.dp, mp=cfg.mp,
+            ) as frame:
+                with frame.tile():
+                    gt = _fused_pairing_product(
+                        shard_rows(Ps, mesh), shard_rows(Qs, mesh), mesh
+                    )
+                with frame.wait():
+                    gt = np.asarray(gt)
+            return gt[:B]
     return pr.pairing_product_staged(Ps, Qs, dp=cfg.dp, mp=cfg.mp)
 
 
@@ -269,6 +272,6 @@ def sharded_schnorr_rows(table: cv.FixedBaseTable, resp, stmts, chals,
     dp = mesh_dp(mesh)
     fixed = run_rows_dp(st._g1_msm_tile, np.asarray(resp), dp=dp,
                         consts=(table.flat,))
-    sc = run_rows_dp(cv.scalar_mul, np.asarray(stmts), np.asarray(chals),
+    sc = run_rows_dp(st._g1_mul_tile, np.asarray(stmts), np.asarray(chals),
                      dp=dp)
     return run_rows_dp(st._g1_sub_tile, fixed, sc, dp=dp)

@@ -604,6 +604,17 @@ class Network:
                 "grouping_s": round(timings.get("grouping_s", 0.0), 6),
                 "device_verify_s": round(timings.get("device_verify_s", 0.0), 6),
                 "sign_verify_s": round(timings.get("sign_verify_s", 0.0), 6),
+                # the two device planes from inside (utils/devobs.py):
+                # this block's seconds in dispatch frames, of those
+                # blocked on a read-back, and in host glue between them
+                # (zero for a block the policy kept on the host)
+                **{
+                    k: round(timings.get(k, 0.0), 6)
+                    for k in (
+                        "verify_frames_s", "verify_wait_s", "verify_glue_s",
+                        "sign_frames_s", "sign_wait_s", "sign_glue_s",
+                    )
+                },
                 # batch-first host passes (FTS_HOST_BATCH): block-level
                 # sign / proof / conservation work hoisted out of the
                 # per-tx loop — their wall is NOT in host_validate_s

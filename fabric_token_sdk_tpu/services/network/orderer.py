@@ -38,6 +38,7 @@ the wire to remote submitters.
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import threading
 import time
@@ -47,7 +48,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from ...api.request import TokenRequest
 from ...api.validator import SIG_AUDITOR, RequestValidator
 from ...drivers import identity
-from ...utils import faults, resilience, slo
+from ...utils import devobs, faults, resilience, slo
 from ...utils import metrics as mx
 from ...utils.tracing import logger
 
@@ -378,6 +379,30 @@ class Orderer:
         return sub.event
 
 
+@contextlib.contextmanager
+def _plane_share(plane: str, timings: dict):
+    """Put this block's share of one device plane's dispatch ledger
+    (`utils/devobs.py`) into `timings`: `<plane>_frames_s` (inside
+    dispatch frames: enqueue + read-back), `<plane>_wait_s` (of that,
+    blocked on the device) and `<plane>_glue_s` (inside the plane's
+    verify calls, outside any frame: Fiat-Shamir, limb encode/decode).
+    Taken as a window delta of the plane's aggregate: stage A runs one
+    block at a time. Zeros for a block kept on the host, or with the
+    ledger off."""
+    keys = ("stage_s", "wait_s", "glue_s")
+    zero = dict.fromkeys(keys, 0.0)
+    before = devobs.plane_snapshot().get(plane, zero)
+    try:
+        yield
+    finally:
+        after = devobs.plane_snapshot().get(plane, zero)
+        stage_s, wait_s, glue_s = (after[k] - before[k] for k in keys)
+        for name, v in (("frames_s", stage_s + wait_s), ("wait_s", wait_s),
+                        ("glue_s", glue_s)):
+            key = f"{plane}_{name}"
+            timings[key] = timings.get(key, 0.0) + v
+
+
 class BlockValidationPipeline:
     """The batched proof plane for one block.
 
@@ -454,9 +479,21 @@ class BlockValidationPipeline:
         counter) still describes the device plane alone; the ledger
         merges the two maps only when handing verdicts to the per-tx
         validator. `None` (the default) skips the host pass — direct
-        callers see the exact device-only behavior."""
+        callers see the exact device-only behavior.
+
+        `timings` also gains the block's share of the `verify` plane's
+        dispatch ledger: `verify_frames_s`, `verify_wait_s`,
+        `verify_glue_s` (`_plane_share`); the call is `fts:stageA.proof`
+        in a profiler trace."""
         if timings is None:
             timings = {}
+        with devobs.annotate("stageA.proof"), _plane_share("verify", timings):
+            return self._proof_verdicts(requests, timings, host_verdicts)
+
+    def _proof_verdicts(
+        self, requests: Sequence[TokenRequest], timings: dict,
+        host_verdicts: Optional[Dict[int, Dict[int, bool]]],
+    ) -> Dict[int, Dict[int, bool]]:
         timings.setdefault("grouping_s", 0.0)
         timings.setdefault("device_verify_s", 0.0)
         if not self.policy.use_batched:
@@ -725,9 +762,18 @@ class BlockValidationPipeline:
         and an OPEN breaker skips even the obligation collection until
         a half-open probe heals it (replacing the old process-lifetime
         construction-failure latch). `timings` gains `sign_verify_s`
-        (time inside the batched call, including failed ones)."""
+        (time inside the batched call, including failed ones) and the
+        block's share of the `sign` plane's dispatch ledger:
+        `sign_frames_s`, `sign_wait_s`, `sign_glue_s` (`_plane_share`);
+        the call is `fts:stageA.sign` in a profiler trace."""
         if timings is None:
             timings = {}
+        with devobs.annotate("stageA.sign"), _plane_share("sign", timings):
+            return self._sign_verdicts(requests, timings)
+
+    def _sign_verdicts(
+        self, requests: Sequence[TokenRequest], timings: dict,
+    ) -> Dict[int, Dict[tuple, tuple]]:
         timings.setdefault("sign_verify_s", 0.0)
         if not self.sign_enabled():
             # device plane off (CPU auto / forced host): the batch-first

@@ -59,7 +59,7 @@ from ...api.driver import ValidationError
 from ...api.request import TokenRequest
 from ...api.validator import RequestValidator
 from ...models.token import ID
-from ...utils import faults, profiler
+from ...utils import devobs, faults, profiler
 from ...utils import metrics as mx
 from ...utils.tracing import logger
 from .ledger import FinalityEvent, Network, TxStatus
@@ -253,7 +253,8 @@ class LedgerServer:
             if isinstance(msg, dict) else None
         )
         try:
-            with mx.use_trace(ctx):
+            # `fts:server.dispatch` in the host plane of a profiler trace
+            with mx.use_trace(ctx), devobs.annotate("server.dispatch"):
                 with mx.span("remote.server.dispatch", op=op):
                     return self._dispatch_op(op, msg)
         except ValidationError as e:

@@ -32,7 +32,7 @@ import threading
 import zlib
 from typing import Iterator, List, Tuple
 
-from ...utils import faults
+from ...utils import devobs, faults
 from ...utils import metrics as mx
 from ...utils.tracing import logger
 
@@ -90,7 +90,9 @@ class WriteAheadLog:
 
     def append(self, payload: bytes) -> None:
         faults.fire("wal.append")
-        with self._lock, mx.timed("wal.append.seconds"):
+        # `fts:wal.append` in the host plane of a profiler trace
+        with self._lock, mx.timed("wal.append.seconds"), \
+                devobs.annotate("wal.append"):
             if self.poisoned:
                 raise WALError(
                     f"wal {self.path}: poisoned by an earlier append failure "

@@ -8,14 +8,32 @@ and how much work was padding (waste). This module is the single place
 those numbers are recorded. Every device entry point
 (`ops/stages.run_rows`, the staged pairing dispatches, and through
 them the batched verifiers/signer/prover) opens a `dispatch(...)`
-frame naming the canonical XLA program it is about to run; the frame
-records requested vs padded rows, dp/mp placement, and wall time, and
-feeds the metrics registry:
+frame naming the canonical XLA program it is about to run. A frame
+lasts until the program's results are back on the host and is the ONLY
+timer at that boundary: it records requested vs padded rows, dp/mp
+placement, wall time and how that wall divides into `stage_s` (the host
+preparing and enqueueing tiles) and `wait_s` (the host blocked on a
+read-back), and feeds the metrics registry:
 
   * ``device.dispatch.seconds``            — all dispatches, one histogram
   * ``device.dispatch.<program>.seconds``  — per-program wall time
   * ``device.<plane>.occupancy``           — rows / (rows + padding)
   * ``device.<program>.padded_rows``       — cumulative padding waste
+  * ``device.<plane>.{span,stage,wait,glue}_us`` — per plane span (below)
+
+The outermost `plane(...)` block on a thread is a **plane span** (one
+batched verify / sign / prove call). Its time outside any frame is host
+glue — Fiat-Shamir hashing, limb encode/decode, numpy reshapes — so per
+plane `span_s = stage_s + wait_s + glue_s` exactly (`plane_snapshot()`).
+A frame that no plane span encloses is its own span with no glue.
+
+Frames, tiles, read-backs and plane spans are also on the profiler's
+clock: while a `jax.profiler` session runs they show in the host plane
+of the trace as `fts:<plane>`, `fts:<plane>:<program>` (one tile's
+enqueue) and `fts:wait:<plane>:<program>` (one read-back), next to the
+device's `XLA Ops` (`annotate()` marks the host layers the same way).
+With no session a `TraceAnnotation` is one atomic check; `jax` is never
+imported from here (no `jax` in the process, no session to write to).
 
 Frames are thread-local, so the `jax.monitoring` compile/cache
 listeners (ops/__init__) can attribute backend compile wall time and
@@ -39,6 +57,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -49,12 +68,14 @@ __all__ = [
     "enabled",
     "dispatch",
     "plane",
+    "annotate",
     "attribute",
     "current_program",
     "note_compile",
     "note_cache",
     "note_degrade",
     "snapshot",
+    "plane_snapshot",
     "reset",
     "health_section",
     "section",
@@ -71,14 +92,43 @@ _tl = threading.local()
 _lock = threading.Lock()
 # (plane, program) -> aggregate dict
 _programs: Dict[Tuple[str, str], dict] = {}
+# plane -> aggregate of its plane spans (see `plane_snapshot`)
+_planes: Dict[str, dict] = {}
 # best-effort fallback for compile events fired on sharding worker
 # threads (the dispatch frame lives on the caller's thread)
 _last_frame: Optional[Tuple[str, str]] = None
+
+_NULL = contextlib.nullcontext()
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is there
 
 
 def enabled() -> bool:
     """Ledger switch; read per entry so tests/operators can flip it."""
     return os.environ.get("FTS_DEVOBS", "1") != "0"
+
+
+def _annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` — one atomic check unless a
+    profiler session is running. A process that never imported jax
+    cannot have a session, and this module must not be the one that
+    imports it (the load generator and host-only nodes stay off jax)."""
+    global _trace_annotation
+    ta = _trace_annotation
+    if ta is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        if prof is None:
+            return _NULL
+        ta = _trace_annotation = prof.TraceAnnotation
+    return ta(name)
+
+
+def annotate(name: str):
+    """`with devobs.annotate("validate"):` puts the block into the host
+    plane of a running `jax.profiler` trace as `fts:<name>` — the mark
+    of a host layer boundary (stage A proof/sign, host validate, WAL
+    append, server dispatch) on the same clock as the device's events.
+    Records nothing else; passthrough when the ledger is off."""
+    return _annotation("fts:" + name) if enabled() else _NULL
 
 
 def _entry(frame: Tuple[str, str]) -> dict:
@@ -89,6 +139,8 @@ def _entry(frame: Tuple[str, str]) -> dict:
             "rows": 0,
             "padded_rows": 0,
             "wall_s": 0.0,
+            "stage_s": 0.0,
+            "wait_s": 0.0,
             "dp": 1,
             "mp": 1,
             "compiles": 0,
@@ -104,19 +156,65 @@ def current_plane() -> str:
     return getattr(_tl, "plane", None) or DEFAULT_PLANE
 
 
+def _close_plane_span(
+    pl: str, span_s: float, frames_s: float, wait_s: float
+) -> None:
+    """One plane span into the per-plane aggregate and the always-on
+    microsecond counters (`span_us = stage_us + wait_us + glue_us`
+    exactly: the three parts are rounded, the total is their sum)."""
+    stage_s = frames_s - wait_s
+    glue_s = max(0.0, span_s - frames_s)
+    with _lock:
+        p = _planes.get(pl)
+        if p is None:
+            p = _planes[pl] = {
+                "calls": 0, "span_s": 0.0, "stage_s": 0.0,
+                "wait_s": 0.0, "glue_s": 0.0,
+            }
+        p["calls"] += 1
+        p["span_s"] += stage_s + wait_s + glue_s
+        p["stage_s"] += stage_s
+        p["wait_s"] += wait_s
+        p["glue_s"] += glue_s
+    stage_us = round(stage_s * 1e6)
+    wait_us = round(wait_s * 1e6)
+    glue_us = round(glue_s * 1e6)
+    mx.counter(f"device.{pl}.span_us").inc(stage_us + wait_us + glue_us)
+    mx.counter(f"device.{pl}.stage_us").inc(stage_us)
+    mx.counter(f"device.{pl}.wait_us").inc(wait_us)
+    mx.counter(f"device.{pl}.glue_us").inc(glue_us)
+
+
 @contextlib.contextmanager
 def plane(name: str):
     """Tag dispatches in this block with a logical plane (verify, sign,
-    prove, ...). Passthrough when the ledger is off."""
+    prove, ...). The OUTERMOST such block on a thread is the plane span:
+    its wall time splits into the frames it encloses (`stage_s` +
+    `wait_s`) and the rest, host glue (`glue_s`); a nested block (the
+    transfer verifier calling the wf / membership / PS verifiers) only
+    re-tags and is never counted twice. Passthrough when the ledger is
+    off."""
     if not enabled():
         yield
         return
     prev = getattr(_tl, "plane", None)
     _tl.plane = name
+    if getattr(_tl, "span", None) is not None:
+        try:
+            yield
+        finally:
+            _tl.plane = prev
+        return
+    # [sum of the enclosed frames' wall_s, of their wait_s]
+    acc = _tl.span = [0.0, 0.0]
+    t0 = time.monotonic()
     try:
-        yield
+        with _annotation("fts:" + name):
+            yield
     finally:
         _tl.plane = prev
+        _tl.span = None
+        _close_plane_span(name, time.monotonic() - t0, acc[0], acc[1])
 
 
 @contextlib.contextmanager
@@ -137,20 +235,90 @@ def attribute(program: str, plane_name: Optional[str] = None):
         _tl.frame = prev
 
 
+class _Wait:
+    """One read-back of an open frame: timed into the frame's `wait_s`
+    and marked `fts:wait:<plane>:<program>` on the profiler's clock."""
+
+    __slots__ = ("_waits", "_ann", "_t0")
+
+    def __init__(self, frame: "_Frame"):
+        self._waits = frame.waits
+        self._ann = _annotation(frame.wait_name)
+
+    def __enter__(self):
+        self._t0 = time.monotonic()
+        self._ann.__enter__()
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self._waits.append(time.monotonic() - self._t0)
+
+
+class _Frame:
+    """What `dispatch(...)` yields: the caller marks each tile's enqueue
+    with `tile()` and wraps each blocking read-back in `wait()`."""
+
+    __slots__ = ("tile_name", "wait_name", "waits")
+
+    def __init__(self, pl: str, program: str):
+        self.tile_name = f"fts:{pl}:{program}"
+        self.wait_name = f"fts:wait:{pl}:{program}"
+        # seconds of every read-back; appended from whichever thread
+        # walks the tiles (list.append is atomic)
+        self.waits: list = []
+
+    def tile(self):
+        return _annotation(self.tile_name)
+
+    def wait(self):
+        return _Wait(self)
+
+
+class _OffFrame:
+    """`dispatch(...)` with the ledger off: nothing timed or marked."""
+
+    __slots__ = ()
+
+    def tile(self):
+        return _NULL
+
+    def wait(self):
+        return _NULL
+
+
+_OFF = _OffFrame()
+
+
 @contextlib.contextmanager
 def dispatch(
     program: str,
     *,
     rows: int,
     padded_rows: int = 0,
+    tiles: int = 0,
     dp: int = 1,
     mp: int = 1,
     plane: Optional[str] = None,
 ):
-    """Record one device dispatch of `program`: requested vs padded
-    rows, dp/mp placement, wall time. Passthrough when off."""
+    """Record one device dispatch of `program`, from the first byte of
+    host preparation until its results are on the host: requested vs
+    padded rows, dp/mp placement, and `wall_s = stage_s + wait_s`.
+
+    `wait_s` is the host blocked on a device result — what the caller
+    wrapped in `frame.wait()`; `stage_s` is the rest of the frame — the
+    host padding, transferring, enqueueing and reassembling. Where each
+    tile is enqueue-then-read-back (the pairing walks) both are summed
+    over the tiles. Walked from worker threads (`dp * mp > 1`) the
+    read-backs of different threads overlap: their seconds are summed
+    over the threads and capped at the frame's wall, so `wait_s` is
+    thread-time and `stage_s` what is left of the caller's wall; with
+    `dp = mp = 1` (the chip cells) both are exact.
+
+    When span recording is on (`FTS_METRICS=1`) the frame records
+    itself as the `device.dispatch` span — no second timer. Yields the
+    frame; passthrough (an inert frame) when off."""
     if not enabled():
-        yield
+        yield _OFF
         return
     global _last_frame
     pl = plane or current_plane()
@@ -158,11 +326,14 @@ def dispatch(
     prev = getattr(_tl, "frame", None)
     _tl.frame = frame
     _last_frame = frame
+    fr = _Frame(pl, program)
     t0 = time.monotonic()
     try:
-        yield
+        yield fr
     finally:
-        wall = time.monotonic() - t0
+        t1 = time.monotonic()
+        wall = t1 - t0
+        wait = min(sum(fr.waits), wall)
         _tl.frame = prev
         with _lock:
             e = _entry(frame)
@@ -170,8 +341,17 @@ def dispatch(
             e["rows"] += rows
             e["padded_rows"] += padded_rows
             e["wall_s"] += wall
+            e["stage_s"] += wall - wait
+            e["wait_s"] += wait
             e["dp"] = dp
             e["mp"] = mp
+        span = getattr(_tl, "span", None)
+        if span is not None:
+            span[0] += wall
+            span[1] += wait
+        else:
+            # no plane span around it: the frame is its own, glue-free
+            _close_plane_span(pl, wall, wall, wait)
         total = rows + padded_rows
         mx.histogram("device.dispatch.seconds").observe(wall)
         mx.histogram(f"device.dispatch.{program}.seconds").observe(wall)
@@ -181,6 +361,12 @@ def dispatch(
             ).observe(rows / total)
         if padded_rows:
             mx.counter(f"device.{program}.padded_rows").inc(padded_rows)
+        if mx.enabled():
+            mx.record_timed_span(
+                "device.dispatch", t0, t1, plane=pl, program=program,
+                rows=rows, tiles=tiles, stage_s=round(wall - wait, 6),
+                wait_s=round(wait, 6),
+            )
 
 
 def _active_frame() -> Tuple[str, str]:
@@ -252,11 +438,22 @@ def snapshot() -> Dict[Tuple[str, str], dict]:
         }
 
 
+def plane_snapshot() -> Dict[str, dict]:
+    """Raw per-plane aggregates of the plane spans: `calls`, `span_s`,
+    `stage_s`, `wait_s`, `glue_s` with `span_s = stage_s + wait_s +
+    glue_s` — for window diffing (the orderer takes a block's share so);
+    values are copies. Kept apart from `snapshot()`, whose entries are
+    programs."""
+    with _lock:
+        return {pl: dict(p) for pl, p in _planes.items()}
+
+
 def reset() -> None:
     """Drop all ledger state (registry metrics are untouched)."""
     global _last_frame
     with _lock:
         _programs.clear()
+        _planes.clear()
     _last_frame = None
 
 
@@ -274,6 +471,7 @@ def health_section() -> dict:
     """The `device` block of `Network.health()` / the `ops.health` RPC:
     per-plane occupancy plus the full per-program ledger."""
     snap = snapshot()
+    spans = plane_snapshot()
     programs: Dict[str, dict] = {}
     planes: Dict[str, dict] = {}
     for (pl, prog), e in sorted(snap.items()):
@@ -289,6 +487,8 @@ def health_section() -> dict:
             "occupancy": _occ(e["rows"], e["padded_rows"]),
             "waste_frac": _waste(e["rows"], e["padded_rows"]),
             "wall_s": round(e["wall_s"], 6),
+            "stage_s": round(e["stage_s"], 6),
+            "wait_s": round(e["wait_s"], 6),
             "p50_s": round(p50, 6) if p50 is not None else None,
             "p99_s": round(p99, 6) if p99 is not None else None,
             "dp": e["dp"],
@@ -306,9 +506,14 @@ def health_section() -> dict:
         agg["dispatches"] += e["dispatches"]
         agg["rows"] += e["rows"]
         agg["padded_rows"] += e["padded_rows"]
-    for agg in planes.values():
+    for pl, agg in planes.items():
         agg["occupancy"] = _occ(agg["rows"], agg["padded_rows"])
         agg["waste_frac"] = _waste(agg["rows"], agg["padded_rows"])
+        sp = spans.get(pl)
+        if sp is not None:
+            agg["calls"] = sp["calls"]
+            for k in ("span_s", "stage_s", "wait_s", "glue_s"):
+                agg[k] = round(sp[k], 6)
     return {"enabled": enabled(), "planes": planes, "programs": programs}
 
 

@@ -423,6 +423,32 @@ def span(name: str, **attrs):
         REGISTRY.histogram(name + ".seconds").observe(s.duration)
 
 
+def record_timed_span(name: str, start: float, end: float,
+                      **attrs) -> Optional[Span]:
+    """Record a span that its owner already timed (monotonic `start` /
+    `end`, on this thread) under the thread's open span, in its trace —
+    for a timer that must stay the only one at its boundary: the device
+    dispatch frame (`utils/devobs.py`) records itself so. Feeds no
+    `<name>.seconds` histogram (the owner keeps its own). Gated like
+    `span`."""
+    if not _enabled:
+        return None
+    s = Span(name, start, end=end, attrs=attrs)
+    s.start_unix = time.time() - (time.monotonic() - start)
+    s.span_id = _new_id(4)
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        parent = stack[-1]
+        if parent.trace_id:
+            s.trace_id = parent.trace_id
+            s.parent_span_id = parent.span_id
+            REGISTRY.counter("trace.spans").inc()
+        parent.children.append(s)
+    else:
+        REGISTRY.record_span_root(s)
+    return s
+
+
 def record_span(name: str, start_unix: float, end_unix: float,
                 trace: Optional[TraceContext] = None, **attrs) -> Optional[Span]:
     """Record an already-timed root span (for work measured across

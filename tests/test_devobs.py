@@ -8,8 +8,10 @@ passthrough (no ledger state, no registry writes, no threads), and on
 or off the accept/reject verdicts of an identical workload must not
 change.
 """
+import os
 import random
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -201,18 +203,12 @@ def test_clamp_site_is_attributed():
 # ===================================================================
 
 
-def _run_scenario():
+def _run_scenario(policy=None):
     """Deterministic mixed-verdict workload (the profiler's scenario):
     issue, then two transfers of which the second double-spends."""
-    pp = FabTokenPublicParams()
-    network = Network(
-        RequestValidator(FabTokenDriver(pp)),
-        policy=BlockPolicy(max_block_txs=8),
+    network, parties = _scenario_network(
+        policy or BlockPolicy(max_block_txs=8)
     )
-    parties = {
-        name: Party(name, FabTokenDriver(pp), network)
-        for name in ("issuer-node", "alice-node", "bob-node")
-    }
     parties["issuer-node"].new_issuer_wallet("issuer")
     alice = parties["alice-node"].new_owner_wallet("alice", anonymous=False)
     bob = parties["bob-node"].new_owner_wallet("bob", anonymous=False)
@@ -238,10 +234,358 @@ def _run_scenario():
     return [e.status for e in events]
 
 
-def test_ledger_never_perturbs_verdicts(monkeypatch):
+@pytest.mark.parametrize("sign_batched", [False, True],
+                         ids=["host", "device_sign_plane"])
+def test_ledger_never_perturbs_verdicts(monkeypatch, sign_batched):
+    """Ledger on and off: the same verdicts — on the host path, and on
+    the path whose frames, read-backs and plane spans are timed (the
+    device sign plane)."""
+    policy = BlockPolicy(
+        max_block_txs=8, sign_batched=sign_batched, sign_min_batch=2
+    )
     monkeypatch.setenv("FTS_DEVOBS", "1")
-    on_statuses = _run_scenario()
+    on_statuses = _run_scenario(policy)
     assert on_statuses == [TxStatus.VALID, TxStatus.INVALID]
+    assert bool(devobs.snapshot()) == sign_batched
     monkeypatch.setenv("FTS_DEVOBS", "0")
-    off_statuses = _run_scenario()
+    devobs.reset()
+    off_statuses = _run_scenario(policy)
     assert off_statuses == on_statuses
+    assert devobs.snapshot() == {}
+
+
+# ===================================================================
+# the frame lasts until the results are on the host, and says how its
+# time divides (stage_s / wait_s); the plane span adds the host glue
+# ===================================================================
+
+
+class _SlowResult:
+    """A tile result whose read-back blocks, as a device array's does
+    while the device is still computing it."""
+
+    def __init__(self, rows, delay_s):
+        self.rows, self.delay_s = rows, delay_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.delay_s)
+        return np.asarray(self.rows)
+
+
+def _slow_tile(delay_s):
+    def slow_tile(rows):
+        return _SlowResult(np.asarray(rows) + 1, delay_s)
+
+    return slow_tile
+
+
+def _counters(*names):
+    return {n: mx.REGISTRY.counter(n).value for n in names}
+
+
+def _plane_counters(pl):
+    return _counters(*(f"device.{pl}.{k}_us"
+                       for k in ("span", "stage", "wait", "glue")))
+
+
+def test_frame_wall_includes_the_read_back():
+    """The regression test of the enqueue-time bug: `run_rows` used to
+    close its frame once the tiles were ENQUEUED; the blocking read-back
+    came two statements later, outside every timer."""
+    rows = np.arange(12, dtype=np.int32).reshape(12, 1)
+    out = st.run_rows(_slow_tile(0.05), rows)
+    assert (out == rows + 1).all()
+    e = devobs.snapshot()[("stages", "slow_tile")]
+    assert (e["dispatches"], e["rows"], e["padded_rows"]) == (1, 12, 4)
+    # two tiles, each blocking 50 ms when read back
+    assert e["wait_s"] >= 0.1
+    assert e["wall_s"] >= e["stage_s"] + 0.1
+    assert e["wall_s"] == pytest.approx(e["stage_s"] + e["wait_s"])
+    assert e["stage_s"] < 0.05  # enqueueing did not block
+    prog = devobs.health_section()["programs"]["stages:slow_tile"]
+    assert prog["wait_s"] >= 0.1 and prog["stage_s"] >= 0
+    # the aggregate histograms saw the whole frame, not the enqueue
+    assert mx.REGISTRY.histogram(
+        "device.dispatch.slow_tile.seconds"
+    ).quantile(0.5) >= 0.1
+
+
+def test_frame_without_a_plane_span_is_its_own_span():
+    before = _plane_counters("stages")
+    st.run_rows(_slow_tile(0.01), np.zeros((8, 1), dtype=np.int32))
+    p = devobs.plane_snapshot()["stages"]
+    assert p["calls"] == 1 and p["glue_s"] == 0.0
+    assert p["span_s"] == pytest.approx(p["stage_s"] + p["wait_s"])
+    after = _plane_counters("stages")
+    assert after["device.stages.glue_us"] == before["device.stages.glue_us"]
+    assert after["device.stages.span_us"] > before["device.stages.span_us"]
+
+
+def test_plane_span_splits_into_stage_wait_and_glue():
+    """`span_s = stage_s + wait_s + glue_s`; a nested verifier call is
+    part of the outermost span, never a second one; the microsecond
+    counters say the same as the ledger's seconds."""
+    from fabric_token_sdk_tpu.crypto.batch import _spanned
+
+    rows = np.zeros((8, 1), dtype=np.int32)
+
+    @_spanned("batch.wf.verify")
+    def inner():
+        st.run_rows(_slow_tile(0.02), rows)
+
+    @_spanned("batch.transfer.verify")
+    def outer():
+        st.run_rows(_slow_tile(0.02), rows)
+        time.sleep(0.03)  # host glue between two frames
+        inner()
+
+    before = _plane_counters("verify")
+    outer()
+    assert set(devobs.plane_snapshot()) == {"verify"}
+    p = devobs.plane_snapshot()["verify"]
+    assert p["calls"] == 1  # the nested call counted once
+    assert p["wait_s"] >= 0.04
+    assert p["glue_s"] >= 0.03
+    assert p["span_s"] == pytest.approx(
+        p["stage_s"] + p["wait_s"] + p["glue_s"], abs=1e-9
+    )
+    e = devobs.snapshot()[("verify", "slow_tile")]
+    assert e["dispatches"] == 2
+    assert e["wall_s"] == pytest.approx(p["stage_s"] + p["wait_s"])
+    # counters: integer microseconds of the same numbers, summing exactly
+    after = _plane_counters("verify")
+    d = {k.rsplit(".", 1)[1]: after[k] - before[k] for k in after}
+    assert d["span_us"] == d["stage_us"] + d["wait_us"] + d["glue_us"]
+    for k in ("span", "stage", "wait", "glue"):
+        assert abs(d[f"{k}_us"] - p[f"{k}_s"] * 1e6) <= 2, k
+    # the operator's view carries the per-plane split
+    hp = devobs.health_section()["planes"]["verify"]
+    assert hp["calls"] == 1 and hp["glue_s"] >= 0.03
+    assert benchschema.validate_device(devobs.section()) == []
+
+
+def test_worker_thread_walk_keeps_the_identity():
+    """dp > 1: tiles are enqueued from worker threads, read back on the
+    caller's; `wall_s = stage_s + wait_s` still holds."""
+    rows = np.arange(32, dtype=np.int32).reshape(32, 1)
+    out = st.run_rows(_slow_tile(0.01), rows, dp=2)
+    assert (out == rows + 1).all()
+    e = devobs.snapshot()[("stages", "slow_tile")]
+    assert e["wait_s"] >= 0.04
+    assert e["wall_s"] == pytest.approx(e["stage_s"] + e["wait_s"])
+
+
+def test_frame_records_itself_as_the_span():
+    """With span recording on, the frame is the `device.dispatch` span
+    under the caller's open span (same start and end, no second timer,
+    no second observation of `device.dispatch.seconds`)."""
+    was = mx.enabled()
+    mx.enable(True)
+    try:
+        agg = _hist_count("device.dispatch.seconds")
+        with mx.span("test.devobs.parent") as parent:
+            st.run_rows(_slow_tile(0.01), np.zeros((5, 1), dtype=np.int32))
+        (child,) = [c for c in parent.children if c.name == "device.dispatch"]
+        assert child.attrs["program"] == "slow_tile"
+        assert child.attrs["plane"] == "stages"
+        assert (child.attrs["rows"], child.attrs["tiles"]) == (5, 1)
+        assert child.attrs["wait_s"] >= 0.01
+        e = devobs.snapshot()[("stages", "slow_tile")]
+        assert child.duration == pytest.approx(e["wall_s"])
+        assert _hist_count("device.dispatch.seconds") == agg + 1
+    finally:
+        mx.enable(was)
+
+
+# ===================================================================
+# per block: the planes' share in `block.commit`
+# ===================================================================
+
+_SHARE_FIELDS = ("verify_frames_s", "verify_wait_s", "verify_glue_s",
+                 "sign_frames_s", "sign_wait_s", "sign_glue_s")
+
+
+def _last_block_commit():
+    return [e for e in mx.FLIGHT.tail(200) if e["kind"] == "block.commit"][-1]
+
+
+def _scenario_network(policy):
+    pp = FabTokenPublicParams()
+    network = Network(RequestValidator(FabTokenDriver(pp)), policy=policy)
+    parties = {
+        name: Party(name, FabTokenDriver(pp), network)
+        for name in ("issuer-node", "alice-node", "bob-node")
+    }
+    return network, parties
+
+
+def test_block_commit_carries_the_sign_planes_share():
+    """A block whose signatures rode the device sign plane says how that
+    time divides; a block the policy kept on the host carries zeros."""
+    sign_on = BlockPolicy(max_block_txs=8, sign_batched=True, sign_min_batch=2)
+    statuses = _run_scenario(sign_on)
+    assert statuses == [TxStatus.VALID, TxStatus.INVALID]
+    blk = _last_block_commit()
+    assert all(f in blk for f in _SHARE_FIELDS)
+    assert blk["sign_verify_s"] > 0
+    assert blk["sign_frames_s"] > 0 and blk["sign_wait_s"] > 0
+    assert blk["sign_glue_s"] > 0  # parse, encode, decode, Fiat-Shamir
+    assert blk["sign_wait_s"] <= blk["sign_frames_s"]
+    assert blk["sign_frames_s"] + blk["sign_glue_s"] <= blk["sign_verify_s"]
+    # fabtoken has no proof plane: the verify fields are there, and zero
+    assert blk["device_verify_s"] == 0
+    assert [blk[f] for f in _SHARE_FIELDS[:3]] == [0, 0, 0]
+    assert {pl for pl, _prog in devobs.snapshot()} == {"sign"}
+
+    devobs.reset()
+    _run_scenario(BlockPolicy(max_block_txs=8, sign_batched=False))
+    blk = _last_block_commit()
+    assert [blk[f] for f in _SHARE_FIELDS] == [0] * 6
+    assert devobs.snapshot() == {}
+
+
+class _StubVerifier:
+    """A batched proof verifier of the real shape (a `_spanned` verify
+    over `run_rows` stages with host work between them) without the
+    real programs' compile time."""
+
+    def __init__(self):
+        from fabric_token_sdk_tpu.crypto.batch import _spanned
+
+        self.verify = _spanned("batch.transfer.verify")(self._verify)
+
+    def _verify(self, rows):
+        a = np.arange(len(rows) * 2, dtype=np.int32).reshape(-1, 1)
+        st.run_rows(_slow_tile(0.01), a)
+        time.sleep(0.02)
+        st.run_rows(_slow_tile(0.01), a)
+        return [True] * len(rows)
+
+
+class _StubDriver:
+    def transfer_batch_plan(self, action):
+        return ((1, 1), (action,))
+
+    def batch_verifier(self, mesh=None):
+        return _StubVerifier()
+
+
+def test_proof_verdicts_put_the_verify_planes_share_into_timings():
+    from types import SimpleNamespace
+
+    from fabric_token_sdk_tpu.services.network.orderer import (
+        BlockValidationPipeline,
+    )
+
+    requests = [
+        SimpleNamespace(transfers=[SimpleNamespace(action=b"a%d" % i)])
+        for i in range(3)
+    ]
+    validator = SimpleNamespace(driver=_StubDriver())
+    timings = {}
+    verdicts = BlockValidationPipeline(
+        validator, BlockPolicy(min_batch=2)
+    ).proof_verdicts(requests, timings)
+    assert verdicts == {0: {0: True}, 1: {0: True}, 2: {0: True}}
+    assert timings["verify_wait_s"] >= 0.02
+    assert timings["verify_glue_s"] >= 0.02
+    assert timings["verify_frames_s"] >= timings["verify_wait_s"]
+    assert (timings["verify_frames_s"] + timings["verify_glue_s"]
+            <= timings["device_verify_s"])
+    # kept on the host by the policy: zeros, and no frame
+    devobs.reset()
+    timings = {}
+    BlockValidationPipeline(
+        validator, BlockPolicy(min_batch=9)
+    ).proof_verdicts(requests, timings)
+    assert [timings[f] for f in _SHARE_FIELDS[:3]] == [0, 0, 0]
+    assert devobs.snapshot() == {}
+
+
+# ===================================================================
+# on the profiler's clock
+# ===================================================================
+
+
+def _fts_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    return [
+        (ev.name, ev.start_ns, ev.duration_ns)
+        for p in ProfileData.from_file(path).planes
+        if p.name.startswith("/host:")
+        for line in p.lines for ev in line.events
+        if ev.name.startswith("fts:")
+    ]
+
+
+def test_frames_are_on_the_profilers_clock(tmp_path, monkeypatch):
+    """Under a `jax.profiler` session one `run_rows` call leaves a
+    `fts:<plane>:<program>` event per tile and a `fts:wait:...` event
+    per read-back in the host plane, and `annotate` its `fts:<name>`;
+    outside a session, and with the ledger off, nothing is recorded."""
+    import jax
+
+    from fabric_token_sdk_tpu.crypto.batch import _spanned
+
+    rows = np.zeros((12, 1), dtype=np.int32)
+    st.run_rows(_slow_tile(0.001), rows)  # no session: nothing recorded
+
+    @_spanned("batch.wf.verify")
+    def verify():
+        with devobs.annotate("validate"):
+            pass
+        st.run_rows(_slow_tile(0.001), rows)
+
+    jax.profiler.start_trace(str(tmp_path / "on"))
+    try:
+        st.run_rows(_slow_tile(0.001), rows)
+        verify()
+    finally:
+        jax.profiler.stop_trace()
+    events = _fts_events(str(tmp_path / "on"))
+    names = [n for n, _s, _d in events]
+    assert names.count("fts:stages:slow_tile") == 2  # one per tile
+    assert names.count("fts:wait:stages:slow_tile") == 2
+    assert names.count("fts:verify:slow_tile") == 2
+    assert names.count("fts:wait:verify:slow_tile") == 2
+    assert names.count("fts:verify") == 1  # the plane span
+    assert names.count("fts:validate") == 1
+    # a read-back lasts at least as long as the result blocked
+    assert all(d >= 1e6 for n, _s, d in events if n.startswith("fts:wait:"))
+    # the plane span covers its tiles and read-backs
+    (span,) = [e for e in events if e[0] == "fts:verify"]
+    for n, s, d in events:
+        if n.endswith("verify:slow_tile"):
+            assert span[1] <= s and s + d <= span[1] + span[2]
+
+    monkeypatch.setenv("FTS_DEVOBS", "0")
+    jax.profiler.start_trace(str(tmp_path / "off"))
+    try:
+        verify()
+    finally:
+        jax.profiler.stop_trace()
+    assert _fts_events(str(tmp_path / "off")) == []
+
+
+def test_off_moves_no_plane_counter_and_no_timing(monkeypatch):
+    monkeypatch.setenv("FTS_DEVOBS", "0")
+    names = [f"device.{pl}.{k}_us" for pl in ("stages", "verify")
+             for k in ("span", "stage", "wait", "glue")]
+    before = _counters(*names)
+    agg = _hist_count("device.dispatch.seconds")
+    with devobs.plane("verify"):
+        out = st.run_rows(_slow_tile(0.0), np.ones((3, 1), dtype=np.int32))
+    assert (out == 2).all()
+    with devobs.dispatch("offtest_prog", rows=1) as frame:
+        with frame.tile(), frame.wait():
+            pass
+    assert devobs.snapshot() == {} and devobs.plane_snapshot() == {}
+    assert _counters(*names) == before
+    assert _hist_count("device.dispatch.seconds") == agg
+    assert devobs.health_section()["planes"] == {}

@@ -283,6 +283,9 @@ def test_ops_rpcs_answer_live_during_slow_commits(tmp_path):
         "host_conservation_s", "host_input_match_s", "wal_s", "merge_s",
         "host_sign_batch_s", "host_proof_batch_s",
         "host_conservation_batch_s",
+        # the device planes' own split (dispatch ledger, utils/devobs.py)
+        "verify_frames_s", "verify_wait_s", "verify_glue_s",
+        "sign_frames_s", "sign_wait_s", "sign_glue_s",
     }
 
 
