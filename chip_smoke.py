@@ -337,7 +337,6 @@ def phase_tiles(run: Run, rng, pairing: bool) -> None:
             tower as tw,
         )
 
-        R = st.ROW_TILE
         second_s = {}
 
         def twice(name, fn, *arrays):
@@ -354,6 +353,7 @@ def phase_tiles(run: Run, rng, pairing: bool) -> None:
             return hm.g2_mul(hm.G2_GEN, rng.randrange(1, hm.R))
 
         # g1 add: generic rows + infinity, doubling, inverse
+        R = st.tile_rows("g1_add_tile")
         a = [g1() for _ in range(R)]
         b = [g1() for _ in range(R)]
         a[0], b[1], b[2], b[3] = None, None, a[2], hm.g1_neg(a[3])
@@ -364,6 +364,7 @@ def phase_tiles(run: Run, rng, pairing: bool) -> None:
               "g1_add_tile disagrees with hostmath.g1_add")
 
         # g1 / g2 variable-base scalar mul, edge scalars included
+        R = max(st.tile_rows("g1_mul_tile"), st.tile_rows("g2_mul_tile"))
         ks = [rng.randrange(hm.R) for _ in range(R)]
         ks[0], ks[1], ks[2] = 0, 1, hm.R - 1
         pts = [g1() for _ in range(R)]
@@ -381,6 +382,7 @@ def phase_tiles(run: Run, rng, pairing: bool) -> None:
 
         # fixed-base msm, 3 bases
         bases = [g1() for _ in range(3)]
+        R = st.tile_rows("g1_msm3_tile")
         rows = [[rng.randrange(hm.R) for _ in range(3)] for _ in range(R)]
         got = cv.decode_points(twice(
             "g1_msm3_tile", st.g1_msm_rows, cv.FixedBaseTable(bases).flat,

@@ -12,7 +12,7 @@ transactions verify through a SMALL CONSTANT set of XLA programs:
 Execution model (staged tiles — see `ops/stages.py`): every verifier is a
 HOST-SIDE composition of primitive stage kernels (fixed-base multiexp,
 variable-base scalar mul, Jacobian add/sub, batch to-affine — each jit'd
-once at one canonical ROW_TILE shape) plus the compile-once pairing tiles
+once at one canonical `stages.tile_rows` shape) plus the compile-once pairing tiles
 (`ops/pairing.py`). All glue between stages — row flattening, challenge
 repetition, broadcasting parameter points, Fiat-Shamir re-hashing — is
 host numpy, so the distinct-program count is independent of batch size,
@@ -37,11 +37,6 @@ from ..ops import curve as cv, curve2 as cv2, limbs as lb, pairing as pr, \
 from ..parallel.sharding import MeshConfig
 from ..utils import devobs
 from ..utils import metrics as mx, resilience
-
-# Canonical tile height for all stage kernels (re-exported for compat;
-# the runner lives in ops/stages.py).
-ROW_TILE = st.ROW_TILE
-
 
 class _MeshBound:
     """Mixin: a verifier bound to an optional `MeshConfig` — its stage

@@ -21,7 +21,7 @@ output: the unchanged host `TransferVerifier` (and the batched
 identically — device proving may only accelerate, never change,
 accept/reject.
 
-Program-set discipline: every device step is a canonical ROW_TILE stage
+Program-set discipline: every device step is a canonical `stages.tile_rows` stage
 tile or the staged K=2 pairing product, all of which `ops/warmup.py`
 precompiles — batch-proving a NEW transfer shape compiles zero XLA
 programs post-warmup (see `tests/test_compile_budget.py`).

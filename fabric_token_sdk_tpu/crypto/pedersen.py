@@ -25,7 +25,7 @@ def commit(openings: Sequence[int], bases: Sequence, curve=None):
 class BatchedPedersen:
     """Batched fixed-base committer over the compile-once stage tiles.
 
-    B commitments over the same bases run as ROW_TILE slabs of the
+    B commitments over the same bases run as `stages.tile_rows` slabs of the
     canonical `g1_msm` tile (`ops/stages.py`), so the program count is
     independent of B — this is the commit engine of the batched transfer
     prover (`crypto/batch_prove.py`: WF announcements, digit

@@ -12,7 +12,8 @@ host numpy. Verifiers become host-side compositions of these stages, so
 the total distinct-program count is a small constant — independent of
 batch size, transfer shape, and parameter set.
 
-Stage inventory (ROW_TILE flat rows each; tables/keys are ARGUMENTS, not
+Stage inventory (`tile_rows(program)` flat rows each — one height per
+program per backend, see `tile_rows`; tables/keys are ARGUMENTS, not
 baked constants, so one executable serves every parameter set):
 
   G1:  msm tile (per nbases in {1,2,3}), variable-base scalar-mul tile,
@@ -35,6 +36,7 @@ persistent cache ahead of time.
 
 from __future__ import annotations
 
+import functools
 import os
 from concurrent.futures import ThreadPoolExecutor
 
@@ -50,10 +52,46 @@ from ..utils import metrics as mx
 from ..utils import resilience, sysmon
 from ..utils.tracing import logger
 
-# Canonical tile height: every stage kernel sees exactly ROW_TILE flat
-# rows (batches are flattened over (B, n) and padded by repeating row 0;
-# padded outputs are discarded).
-ROW_TILE = 8
+# Tile height: every dispatch of a stage program sees exactly
+# `tile_rows(program)` flat rows (batches are flattened over (B, n) and
+# padded by repeating row 0; padded outputs are discarded). ONE height
+# per program name, so the shape `stage_programs()` registers (what
+# `ops/warmup.py` and the benchmark compile ahead) is by construction
+# the shape `run_rows` dispatches. Derived from the backend, not set:
+# no environment variable, no policy field, no second shape per name.
+#
+# Off the chip a tile's time grows with its rows (XLA's CPU backend:
+# 25-50 s per zk transaction at any height), so the small tile stays.
+_HOST_TILE_ROWS = 8
+# On the chip an operation on 8 rows x 32 limbs (a quarter of one vector
+# register) is nearly all fixed issue cost, and at 128 rows the rows
+# fill the 128 lanes. One warm dispatch on a TPU v5e, ms at 8 / 64 /
+# 128 / 256 rows (sweep: PERF.md section 6, PR 26): g1_mul 71.7 / 105.4
+# / 62.5 / 80.7, g2_mul 96.7 / 196.6 / 101.1 / 182.5, g1_msm3 41.9 /
+# 61.5 / 36.9 / 46.6. The rule: over the stage calls of a full 64-tx
+# (2,2) block (18 of the proof plane, 128-768 rows each; 3 of the sign
+# plane, 192 rows) take the T that minimises
+# sum(ceil(rows / T) * c_program(T)) among the T with
+# c_program(T) <= 1.5 * c_program(8) for the programs that cost, so
+# that a block of one tile per call (2 txs) loses at most half again.
+# 256 would give 1.23 s a block against 1.49 s but g2_mul(256) = 1.89 x
+# g2_mul(8); 128 is within the limit (0.87-1.05 x). One T for all ten:
+# only programs worth under 50 ms of a 14 s block together have an
+# optimum of their own that differs by more than 20 % of their time.
+_TPU_TILE_ROWS = 128
+
+
+@functools.cache
+def _on_tpu() -> bool:
+    # asked at first use, never at import: this starts the backend
+    return jax.default_backend() == "tpu"
+
+
+def tile_rows(program: str) -> int:
+    """Rows one dispatch of stage program `program` (a
+    `stage_programs()` name) holds on this process's backend."""
+    return _TPU_TILE_ROWS if _on_tpu() else _HOST_TILE_ROWS
+
 
 # ------------------------------------------------------------ tile kernels
 
@@ -190,15 +228,16 @@ def default_mp() -> int:
     return mp if n > 0 else 1
 
 
-def _run_span(frame, kernel, consts, arrays, start, stop):
-    """Sequentially ENQUEUE the tile kernel over [start, stop) row slabs
-    (JAX dispatch is asynchronous: nothing here waits for a result).
-    Each tile's transfer + dispatch is one `frame.tile()` mark."""
+def _run_span(frame, kernel, consts, arrays, rows, start, stop):
+    """Sequentially ENQUEUE the tile kernel over the `rows`-high slabs
+    [start, stop) (JAX dispatch is asynchronous: nothing here waits for
+    a result). Each tile's transfer + dispatch is one `frame.tile()`
+    mark."""
     outs = []
-    for t in range(start, stop, ROW_TILE):
+    for t in range(start * rows, stop * rows, rows):
         with frame.tile():
             outs.append(kernel(
-                *consts, *(jnp.asarray(a[t : t + ROW_TILE]) for a in arrays)
+                *consts, *(jnp.asarray(a[t : t + rows]) for a in arrays)
             ))
     return outs
 
@@ -263,7 +302,7 @@ def run_tile_spans(fn, ntiles: int, workers: int, *args, calls, shards,
 
 
 def dp_spans(ntiles: int, dp: int):
-    """Split `ntiles` ROW_TILE slabs into at most `dp` contiguous,
+    """Split `ntiles` tile slabs into at most `dp` contiguous,
     tile-aligned (start_tile, stop_tile) spans — the row partition of the
     per-shard stage-tile dispatch (`parallel/sharding.py`)."""
     dp = max(1, min(dp, ntiles))
@@ -299,12 +338,13 @@ def _program_of(kernel, arrays) -> str:
 
 
 def run_rows(kernel, *arrays, consts=(), dp=None):
-    """Run `kernel(*consts, *tiles)` over ROW_TILE slabs of flat-row
-    numpy arrays -> numpy. The staged successor of the old
-    `crypto.batch._run_tiled`.
+    """Run `kernel(*consts, *tiles)` over `tile_rows(program)`-high
+    slabs of flat-row numpy arrays -> numpy. The staged successor of
+    the old `crypto.batch._run_tiled`.
 
     * `arrays` share a leading flat row axis N; rows are padded to a
-      ROW_TILE multiple by repeating row 0 (padded outputs discarded).
+      multiple of the tile height by repeating row 0 (padded outputs
+      discarded).
     * `consts` are parameter tensors (window tables, public keys) passed
       whole to every tile call — arguments, not baked jit constants.
     * Tiles are CONTIGUOUS numpy views of a single padded buffer (one
@@ -321,8 +361,10 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
     N = arrays[0].shape[0]
     if N == 0:
         raise ValueError("run_rows: empty row batch (caller must guard)")
-    pad = (-N) % ROW_TILE
-    ntiles = (N + pad) // ROW_TILE
+    program = _program_of(kernel, arrays)
+    rows = tile_rows(program)
+    pad = (-N) % rows
+    ntiles = (N + pad) // rows
     dp = default_dp() if dp is None else max(1, dp)
     # ONE timer per dispatch: the ledger frame (utils/devobs.py), from
     # the padding until the last tile's result is back on the host. It
@@ -330,8 +372,7 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
     # read-back time, and is the per-kernel span a critical-path trace
     # (cmd/ftstrace.py) renders under the block's device verify.
     with devobs.dispatch(
-        _program_of(kernel, arrays), rows=N, padded_rows=pad,
-        tiles=ntiles, dp=dp,
+        program, rows=N, padded_rows=pad, tiles=ntiles, dp=dp,
     ) as frame:
         if pad:
             padded = []
@@ -349,9 +390,7 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
         mx.counter("batch.tiled.transfers").inc(ntiles * len(arrays))
         # every tile is enqueued before the first read-back below
         outs = run_tile_spans(
-            lambda a, b: _run_span(
-                frame, kernel, consts, arrays, a * ROW_TILE, b * ROW_TILE
-            ),
+            lambda a, b: _run_span(frame, kernel, consts, arrays, rows, a, b),
             ntiles, dp,
             calls=mx.counter("stages.sharded_calls"),
             shards=mx.counter("stages.shards"),
@@ -451,19 +490,24 @@ def affine_to_jac_np(p: np.ndarray) -> np.ndarray:
 
 def stage_programs():
     """Yield (name, jitted_fn, canonical arg shapes) for every stage
-    program, for AOT precompilation (`ops/warmup.py`). int32 throughout."""
-    R, L = ROW_TILE, lb.NLIMBS
+    program, for AOT precompilation (`ops/warmup.py`). int32 throughout.
+    The leading axis of every row argument is `tile_rows(name)`."""
+    L = lb.NLIMBS
     W = 1 << cv.WINDOW_BITS
-    for nbases in (1, 2, 3):
-        yield (
-            f"g1_msm{nbases}_tile",
-            _g1_msm_tile,
-            ((nbases * cv.DIGITS_PER_SCALAR, W, 3 * L), (R, nbases, L)),
-        )
-    yield ("g1_mul_tile", _g1_mul_tile, ((R, 3, L), (R, L)))
-    yield ("g1_add_tile", _g1_add_tile, ((R, 3, L), (R, 3, L)))
-    yield ("g1_sub_tile", _g1_sub_tile, ((R, 3, L), (R, 3, L)))
-    yield ("g1_to_affine_tile", _g1_to_affine_tile, ((R, 3, L),))
-    yield ("g2_mul_tile", _g2_mul_tile, ((R, 3, 2, L), (R, L)))
-    yield ("g2_add_tile", _g2_add_tile, ((R, 3, 2, L), (R, 3, 2, L)))
-    yield ("g2_to_affine_tile", _g2_to_affine_tile, ((R, 3, 2, L),))
+    g1, g2, k = (3, L), (3, 2, L), (L,)
+    programs = [
+        (f"g1_msm{n}_tile", _g1_msm_tile, ((n, L),),
+         ((n * cv.DIGITS_PER_SCALAR, W, 3 * L),))
+        for n in (1, 2, 3)
+    ] + [
+        ("g1_mul_tile", _g1_mul_tile, (g1, k), ()),
+        ("g1_add_tile", _g1_add_tile, (g1, g1), ()),
+        ("g1_sub_tile", _g1_sub_tile, (g1, g1), ()),
+        ("g1_to_affine_tile", _g1_to_affine_tile, (g1,), ()),
+        ("g2_mul_tile", _g2_mul_tile, (g2, k), ()),
+        ("g2_add_tile", _g2_add_tile, (g2, g2), ()),
+        ("g2_to_affine_tile", _g2_to_affine_tile, (g2,), ()),
+    ]
+    for name, fn, row_args, consts in programs:
+        R = tile_rows(name)
+        yield name, fn, consts + tuple((R,) + a for a in row_args)

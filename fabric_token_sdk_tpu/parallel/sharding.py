@@ -249,7 +249,7 @@ def mesh_dp(mesh) -> Optional[int]:
 def run_rows_dp(kernel, *arrays, mesh=None, dp: Optional[int] = None,
                 consts=()):
     """Per-shard stage-tile dispatch: partition the flat rows into dp
-    contiguous ROW_TILE-aligned spans and run each span through the
+    contiguous tile-aligned spans (`stages.tile_rows`) and run each span through the
     canonical compile-once tile executable (`stages.run_rows`). Results
     are bit-identical to the unsharded runner and NO new XLA program is
     compiled — the dp axis exists purely in the host-side dispatch."""

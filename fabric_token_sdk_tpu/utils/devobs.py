@@ -138,6 +138,7 @@ def _entry(frame: Tuple[str, str]) -> dict:
             "dispatches": 0,
             "rows": 0,
             "padded_rows": 0,
+            "tile_rows": 0,
             "wall_s": 0.0,
             "stage_s": 0.0,
             "wait_s": 0.0,
@@ -302,7 +303,9 @@ def dispatch(
 ):
     """Record one device dispatch of `program`, from the first byte of
     host preparation until its results are on the host: requested vs
-    padded rows, dp/mp placement, and `wall_s = stage_s + wait_s`.
+    padded rows, the height of its tiles (`tile_rows` = dispatched rows
+    / `tiles`, of the newest dispatch: it says which shape of the
+    program ran), dp/mp placement, and `wall_s = stage_s + wait_s`.
 
     `wait_s` is the host blocked on a device result — what the caller
     wrapped in `frame.wait()`; `stage_s` is the rest of the frame — the
@@ -334,12 +337,16 @@ def dispatch(
         t1 = time.monotonic()
         wall = t1 - t0
         wait = min(sum(fr.waits), wall)
+        total = rows + padded_rows
+        height = total // tiles if tiles else 0
         _tl.frame = prev
         with _lock:
             e = _entry(frame)
             e["dispatches"] += 1
             e["rows"] += rows
             e["padded_rows"] += padded_rows
+            if height:
+                e["tile_rows"] = height
             e["wall_s"] += wall
             e["stage_s"] += wall - wait
             e["wait_s"] += wait
@@ -352,7 +359,6 @@ def dispatch(
         else:
             # no plane span around it: the frame is its own, glue-free
             _close_plane_span(pl, wall, wall, wait)
-        total = rows + padded_rows
         mx.histogram("device.dispatch.seconds").observe(wall)
         mx.histogram(f"device.dispatch.{program}.seconds").observe(wall)
         if total:
@@ -364,8 +370,8 @@ def dispatch(
         if mx.enabled():
             mx.record_timed_span(
                 "device.dispatch", t0, t1, plane=pl, program=program,
-                rows=rows, tiles=tiles, stage_s=round(wall - wait, 6),
-                wait_s=round(wait, 6),
+                rows=rows, tiles=tiles, tile_rows=height,
+                stage_s=round(wall - wait, 6), wait_s=round(wait, 6),
             )
 
 
@@ -484,6 +490,7 @@ def health_section() -> dict:
             "dispatches": e["dispatches"],
             "rows": e["rows"],
             "padded_rows": e["padded_rows"],
+            "tile_rows": e["tile_rows"],
             "occupancy": _occ(e["rows"], e["padded_rows"]),
             "waste_frac": _waste(e["rows"], e["padded_rows"]),
             "wall_s": round(e["wall_s"], 6),

@@ -24,7 +24,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 # Persistent XLA compilation cache is configured centrally in
 # fabric_token_sdk_tpu/ops/__init__.py (JAX_COMPILATION_CACHE_DIR, else
-# <checkout>/.jax_cache); kernels are row-tiled (crypto/batch.py ROW_TILE)
+# <checkout>/.jax_cache); kernels are row-tiled (ops/stages.py tile_rows)
 # and setup fixtures seeded so cache entries hit across runs.
 
 import random

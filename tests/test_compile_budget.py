@@ -13,6 +13,7 @@ the budget directly.
 import os
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,6 +21,7 @@ from fabric_token_sdk_tpu.crypto import batch, hostmath as hm
 from fabric_token_sdk_tpu.crypto import token as tok, wellformedness as wf
 from fabric_token_sdk_tpu.crypto.setup import setup
 from fabric_token_sdk_tpu.ops import curve as cv, pairing as pr, stages as st
+from fabric_token_sdk_tpu.utils import devobs
 from fabric_token_sdk_tpu.utils import metrics as mx
 
 COMPILES = "jax.core.compile.backend_compile_duration.seconds"
@@ -57,7 +59,7 @@ def _wf_txs(pp, rng, in_vals, out_vals, count):
 
 
 def test_stage_rows_program_count_is_batch_invariant(rng):
-    """`stages.run_rows` slices every flat-row batch into ROW_TILE slabs,
+    """`stages.run_rows` slices every flat-row batch into slabs of one height,
     so changing the batch size must compile ZERO new programs — and the
     window table is an ARGUMENT, so a different table of the same base
     count must share the executable too."""
@@ -80,7 +82,7 @@ def test_stage_rows_program_count_is_batch_invariant(rng):
     before = _compiles()
     st.g1_msm_rows(table.flat, scal(11))
     assert _compiles() - before == 0, (
-        "changing batch size recompiled the msm tile — the ROW_TILE slab "
+        "changing batch size recompiled the msm tile — the one-height slab "
         "contract is broken"
     )
 
@@ -91,6 +93,55 @@ def test_stage_rows_program_count_is_batch_invariant(rng):
         "a different parameter set recompiled the msm tile — tables must "
         "be arguments, not baked constants"
     )
+
+
+STAGE_PROGRAM_NAMES = (
+    "g1_msm1_tile", "g1_msm2_tile", "g1_msm3_tile", "g1_mul_tile",
+    "g1_add_tile", "g1_sub_tile", "g1_to_affine_tile", "g2_mul_tile",
+    "g2_add_tile", "g2_to_affine_tile",
+)
+
+
+def test_stage_program_names_are_the_ten():
+    assert sorted(n for n, _f, _s in st.stage_programs()) == sorted(
+        STAGE_PROGRAM_NAMES)
+
+
+@pytest.mark.parametrize("backend", ["host", "tpu"])
+@pytest.mark.parametrize("name", STAGE_PROGRAM_NAMES)
+def test_registered_shape_is_the_dispatched_shape(monkeypatch, name, backend):
+    """"No backend compile inside the window" rests on this: the shape
+    `stage_programs()` registers for a program (what `warmup` and the
+    benchmark's `warm_programs` compile ahead) is the only shape
+    `run_rows` ever hands that program, whatever the batch size — on
+    this backend and on the chip's tile height alike. The kernel is
+    replaced by a recorder (no arithmetic, no compile): what is pinned is
+    the runner's slicing."""
+    monkeypatch.setattr(st, "_on_tpu", lambda: backend == "tpu")
+    fn, shapes = {n: (f, sh) for n, f, sh in st.stage_programs()}[name]
+    n_consts = 1 if fn is st._g1_msm_tile else 0
+    seen = set()
+
+    def recorder(*args):
+        seen.add(tuple(a.shape for a in args))
+        out = jax.eval_shape(fn, *args)
+        return np.zeros(out.shape, np.int32)
+
+    # the runner names a kernel by identity: the recorder stands for `name`
+    monkeypatch.setattr(st, "_PROGRAM_NAMES", {id(recorder): name})
+    T = st.tile_rows(name)
+    assert all(sh[0] == T for sh in shapes[n_consts:])
+    consts = tuple(np.zeros(sh, np.int32) for sh in shapes[:n_consts])
+    before = devobs.snapshot().get(("stages", name), {"dispatches": 0})
+    sweep = (1, 3, T - 1, T, T + 3, 2 * T + 1)
+    for B in sweep:
+        arrays = [np.zeros((B,) + sh[1:], np.int32) for sh in shapes[n_consts:]]
+        out = st.run_rows(recorder, *arrays, consts=consts)
+        assert out.shape[0] == B
+    assert seen == {tuple(shapes)}, (name, seen)
+    e = devobs.snapshot()[("stages", name)]
+    assert e["dispatches"] - before["dispatches"] == len(sweep)
+    assert e["tile_rows"] == T
 
 
 def test_dispatch_ledger_pins_program_set_across_batch_sweep(rng):
@@ -131,7 +182,7 @@ def test_dispatch_ledger_pins_program_set_across_batch_sweep(rng):
         "padded_rows", 0
     )
     assert rows == sum(sweep)
-    assert padded == sum((-B) % st.ROW_TILE for B in sweep)
+    assert padded == sum((-B) % st.tile_rows("g1_msm3_tile") for B in sweep)
     # and the sweep compiled at most the one tile program (0 when an
     # earlier test already compiled it), never one per batch size
     assert after[frame]["compiles"] - before.get(frame, {}).get(

@@ -167,10 +167,13 @@ def test_msm_dispatch_ledgered_with_canonical_program_name():
     e = devobs.snapshot()[frame]
     assert e["dispatches"] == 1
     assert e["rows"] == 5
-    # run_rows pads the 5-row batch up to the ROW_TILE slab
-    assert e["padded_rows"] == (-5) % st.ROW_TILE
+    # run_rows pads the 5-row batch up to one tile of the program's height
+    T = st.tile_rows("g1_msm1_tile")
+    assert e["padded_rows"] == (-5) % T
+    assert e["tile_rows"] == T
     prog = devobs.health_section()["programs"]["stages:g1_msm1_tile"]
-    assert prog["occupancy"] == pytest.approx(5 / (5 + (-5) % st.ROW_TILE))
+    assert prog["occupancy"] == pytest.approx(5 / (5 + (-5) % T))
+    assert prog["tile_rows"] == T
 
 
 # ===================================================================

@@ -2,7 +2,8 @@
 
 Every primitive stage in `ops/stages.py` is pinned against
 `crypto/hostmath.py` on random inputs, including padding edges (batch
-sizes that are not ROW_TILE multiples) and the host-glue helpers.
+sizes that are not multiples of the tile height, at the host's height
+and at the chip's) and the host-glue helpers.
 """
 
 import numpy as np
@@ -70,6 +71,69 @@ def test_g1_to_affine_rows_matches_decode(rng):
     aff = st.g1_to_affine_rows(jac)
     # affine limbs must decode to the same canonical points
     assert _decode_affine_g1(aff) == cv.decode_points(jac)
+
+
+# row counts around one tile of height T: a lone row, one short of a
+# tile, a full tile, a tile and a ragged second one
+@pytest.mark.parametrize(
+    "offset", [None, -1, 0, 3], ids=["1", "T-1", "T", "T+3"])
+@pytest.mark.parametrize("backend", ["host", "tpu"])
+def test_cheap_stage_rows_match_host_at_either_tile_height(
+    monkeypatch, backend, offset
+):
+    """`run_rows` at the host's tile height and at the chip's (the
+    backend observation patched; still the CPU's arithmetic): the four
+    cheap programs agree with hostmath row for row, whatever the height
+    and however many rows were padding. (The scalar-mul tiles at the
+    chip's height cost minutes on the CPU: `-m slow`, below.)"""
+    monkeypatch.setattr(st, "_on_tpu", lambda: backend == "tpu")
+    T = st.tile_rows("g1_add_tile")
+    assert (T > 8) == (backend == "tpu")
+    assert {st.tile_rows(n) for n in (
+        "g1_sub_tile", "g1_to_affine_tile", "g2_add_tile")} == {T}
+    N = 1 if offset is None else T + offset
+    # a small pool of host points, cycled up to N rows; rows 1-3 are the
+    # add's edge cases (infinity, doubling, inverse)
+    ps = [hm.g1_mul(hm.G1_GEN, 3 + i) for i in range(7)]
+    qs = [hm.g1_mul(hm.G1_GEN, 100 + i) for i in range(7)]
+    qs[1], qs[2], qs[3] = None, ps[2], hm.g1_neg(ps[3])
+    idx = [i % 7 for i in range(N)]
+    a, b = _g1_jac(ps)[idx], _g1_jac(qs)[idx]
+    want_add = [hm.g1_add(p, q) for p, q in zip(ps, qs)]
+    want_sub = [hm.g1_add(p, hm.g1_neg(q) if q else None)
+                for p, q in zip(ps, qs)]
+    assert cv.decode_points(st.g1_add_rows(a, b)) == [want_add[i] for i in idx]
+    assert cv.decode_points(st.g1_sub_rows(a, b)) == [want_sub[i] for i in idx]
+    # to-affine of non-trivial Z: the doubled points, still Jacobian
+    dbl = st.g1_add_rows(a, a)
+    assert _decode_affine_g1(st.g1_to_affine_rows(dbl)) == [
+        hm.g1_add(ps[i], ps[i]) for i in idx
+    ]
+    p2 = [hm.g2_mul(hm.G2_GEN, 3 + i) for i in range(7)]
+    q2 = [hm.g2_mul(hm.G2_GEN, 50 + i) for i in range(7)]
+    a2 = np.asarray(cv2.encode_points(p2))[idx]
+    b2 = np.asarray(cv2.encode_points(q2))[idx]
+    want2 = [hm.g2_add(p, q) for p, q in zip(p2, q2)]
+    assert cv2.decode_points(st.g2_add_rows(a2, b2)) == [want2[i] for i in idx]
+
+
+@pytest.mark.slow
+def test_scalar_mul_rows_match_host_at_the_chips_tile_height(monkeypatch, rng):
+    """One ragged call of each scalar-mul program at the chip's height
+    on the CPU backend (minutes): same answers as hostmath."""
+    monkeypatch.setattr(st, "_on_tpu", lambda: True)
+    N = st.tile_rows("g1_mul_tile") + 3
+    pts = [hm.g1_mul(hm.G1_GEN, 3 + i) for i in range(5)]
+    pts2 = [hm.g2_mul(hm.G2_GEN, 3 + i) for i in range(5)]
+    ks = _scalars(rng, 5)
+    idx = [i % 5 for i in range(N)]
+    k = cv.encode_scalars(ks)[idx]
+    got = st.g1_mul_rows(_g1_jac(pts)[idx], k)
+    want = [hm.g1_mul(p, x) for p, x in zip(pts, ks)]
+    assert cv.decode_points(got) == [want[i] for i in idx]
+    got = st.g2_mul_rows(np.asarray(cv2.encode_points(pts2))[idx], k)
+    want = [hm.g2_mul(p, x) for p, x in zip(pts2, ks)]
+    assert cv2.decode_points(got) == [want[i] for i in idx]
 
 
 def test_affine_to_jac_np_round_trips():
