@@ -1,9 +1,23 @@
 """Multi-process network: a fault-tolerant TCP ledger node + thin client.
 
 Reference parity: the SDK talks to a Fabric network over gRPC
-(`token/services/network/fabric`); here a JSON-over-TCP node hosts the
+(`token/services/network/fabric`); here a framed-TCP node hosts the
 MVCC ledger + validator, and `RemoteNetwork` exposes the same API surface
 as the in-process `Network` so parties can live in separate processes.
+
+Wire format — one frame per message, both directions, every op
+(submit, replication, ops plane):
+
+    u32 N | u32 H | H bytes of JSON header | raw segments, back to back
+
+all lengths big-endian; N counts everything after itself and is the
+number held against the frame cap. Payload bytes (token requests,
+ledger outputs, WAL records, snapshots) never travel inside the JSON:
+the header's `"$bin"` entry lists, per key and in order, the length (or
+list of lengths) of that key's raw segments, which must fill the rest of
+the frame exactly. A 64-tx hand-over of 167 KB requests is a 10.7 MB
+frame. The receiver checks N against the cap, allocates ONE buffer of N
+bytes and fills it with `recv_into`.
 
 Fault tolerance (client side):
 
@@ -26,7 +40,11 @@ Fault tolerance (client side):
 Server side: per-op dispatch errors are logged with traceback and
 returned typed (`remote.dispatch.errors.<op>`); inbound frames are
 capped (`FTS_REMOTE_MAX_FRAME`, default 16 MiB) so a corrupt or hostile
-length prefix can never force an arbitrary-size allocation.
+length prefix can never force an arbitrary-size allocation; a frame
+under the cap that does not decode is counted
+(`remote.frames.malformed`) and refused the same typed way. Every
+inbound `submit`/`submit_many` frame is counted (`remote.frame.bytes`,
+`remote.frame.recv_us`: length prefix -> decoded message).
 
 Live ops plane: the node answers side-effect-free introspection RPCs —
 `ops.health` (uptime, height, WAL state, queue depth, in-flight txs,
@@ -51,6 +69,7 @@ import os
 import random
 import socket
 import socketserver
+import struct
 import threading
 import time
 from typing import Callable, List, Optional, Tuple
@@ -103,31 +122,93 @@ def _parse_endpoints(spec: str) -> List[Tuple[str, int]]:
     return out
 
 
+_U32 = struct.Struct(">I")
+_BIN = "$bin"  # header key: the layout of the frame's raw segments
+_RAW = (bytes, bytearray)  # what travels as a segment (len() is its byte count)
+
+
 def _send_msg(sock: socket.socket, obj: dict) -> None:
-    raw = json.dumps(obj).encode()
-    sock.sendall(len(raw).to_bytes(4, "big") + raw)
+    """One frame (the wire format, in the module docstring). Top-level
+    values that are `bytes`, or non-empty lists of `bytes`, leave the JSON
+    header and follow it as raw segments."""
+    head, layout, segs = {}, {}, []
+    for k, v in obj.items():
+        if isinstance(v, _RAW):
+            layout[k] = len(v)
+            segs.append(v)
+        elif isinstance(v, list) and v and all(isinstance(x, _RAW) for x in v):
+            layout[k] = [len(x) for x in v]
+            segs.extend(v)
+        else:
+            head[k] = v
+    if layout:
+        head[_BIN] = layout
+    raw = json.dumps(head).encode()
+    n = _U32.size + len(raw) + sum(len(x) for x in segs)
+    sock.sendall(b"".join([_U32.pack(n), _U32.pack(len(raw)), raw, *segs]))
 
 
-def _recv_msg(sock: socket.socket, max_frame: Optional[int] = None) -> Optional[dict]:
-    hdr = b""
-    while len(hdr) < 4:
-        chunk = sock.recv(4 - len(hdr))
-        if not chunk:
-            return None
-        hdr += chunk
-    n = int.from_bytes(hdr, "big")
+def _recv_exact(sock: socket.socket, view: memoryview) -> bool:
+    """Fill `view` from the socket; False when the peer closed first."""
+    got = 0
+    while got < len(view):
+        k = sock.recv_into(view[got:])
+        if not k:
+            return False
+        got += k
+    return True
+
+
+def _recv_prefix(sock: socket.socket, max_frame: Optional[int] = None) -> Optional[int]:
+    """The next frame's length, checked against the cap BEFORE anything
+    is allocated for it; None when the peer closed the connection."""
+    hdr = bytearray(_U32.size)
+    if not _recv_exact(sock, memoryview(hdr)):
+        return None
+    (n,) = _U32.unpack(hdr)
     cap = max_frame if max_frame is not None else _max_frame()
     if n > cap:
         # reject BEFORE allocating: a corrupt/hostile prefix must not
         # drive an arbitrary-size allocation
         raise FrameTooLarge(f"frame of {n} bytes exceeds cap of {cap}")
-    buf = b""
-    while len(buf) < n:
-        chunk = sock.recv(min(65536, n - len(buf)))
-        if not chunk:
-            return None
-        buf += chunk
-    return json.loads(buf.decode())
+    return n
+
+
+def _recv_body(sock: socket.socket, n: int) -> Optional[dict]:
+    """The `n` bytes after a length prefix, received into ONE buffer and
+    decoded: the header's fields plus, under their own keys, the raw
+    segments as `bytes` (or lists of `bytes`)."""
+    buf = bytearray(n)
+    view = memoryview(buf)
+    if not _recv_exact(sock, view):
+        return None
+    if n < _U32.size:
+        raise ValueError(f"malformed frame: {n} bytes hold no header length")
+    (h,) = _U32.unpack_from(buf)
+    at = _U32.size + h
+    if at > n:
+        raise ValueError(f"malformed frame: header of {h} bytes in {n}")
+    msg = json.loads(bytes(view[_U32.size:at]))
+    layout = msg.pop(_BIN, {}) if isinstance(msg, dict) else {}
+    if not isinstance(layout, dict):
+        raise ValueError("malformed frame: the segment layout is no object")
+    for k, lens in layout.items():
+        many = isinstance(lens, list)
+        out = []
+        for ln in (lens if many else [lens]):
+            if not isinstance(ln, int) or ln < 0 or at + ln > n:
+                raise ValueError("malformed frame: segments overrun it")
+            out.append(bytes(view[at:at + ln]))
+            at += ln
+        msg[k] = out if many else out[0]
+    if at != n:
+        raise ValueError(f"malformed frame: {n - at} bytes belong to no segment")
+    return msg
+
+
+def _recv_msg(sock: socket.socket, max_frame: Optional[int] = None) -> Optional[dict]:
+    n = _recv_prefix(sock, max_frame)
+    return None if n is None else _recv_body(sock, n)
 
 
 class LedgerServer:
@@ -172,7 +253,21 @@ class LedgerServer:
             def _serve(self):
                 while True:
                     try:
-                        msg = _recv_msg(self.request)
+                        n = _recv_prefix(self.request)
+                        if n is None:
+                            return
+                        # from the length prefix to the decoded message:
+                        # the wait for a client's next request is not in it
+                        t0 = time.monotonic()
+                        # `fts:server.recv` in the host plane of a trace
+                        with devobs.annotate("server.recv"):
+                            msg = _recv_body(self.request, n)
+                        if isinstance(msg, dict) and msg.get("op") in (
+                            "submit", "submit_many"
+                        ):
+                            mx.counter("remote.frame.bytes").inc(n)
+                            mx.counter("remote.frame.recv_us").inc(
+                                int((time.monotonic() - t0) * 1e6))
                     except FrameTooLarge as e:
                         mx.counter("remote.frames.rejected").inc()
                         logger.warning("ledger server: %s", e)
@@ -186,6 +281,19 @@ class LedgerServer:
                         return  # stream is desynced: drop the connection
                     except OSError:
                         return  # client reset mid-frame
+                    except ValueError as e:
+                        # a frame that does not decode: nothing after it
+                        # on this stream can be trusted either
+                        mx.counter("remote.frames.malformed").inc()
+                        logger.warning("ledger server: %s", e)
+                        try:
+                            _send_msg(self.request, {
+                                "ok": False, "error": str(e),
+                                "error_class": "MalformedFrame",
+                            })
+                        except OSError:
+                            pass
+                        return
                     if msg is None:
                         return
                     try:
@@ -314,7 +422,7 @@ class LedgerServer:
                         "error_class": "ReplicationDisabled"}
             return repl.handle(op, msg)
         if op == "submit":
-            ev = self.network.submit(bytes.fromhex(msg["request"]))
+            ev = self.network.submit(msg["request"])
             # `transient` must cross the wire: a transient internal
             # fault is retry-safe (the ledger records no verdict), a
             # real rejection is final — remote callers need the same
@@ -332,10 +440,7 @@ class LedgerServer:
             # queue (silently committed by later traffic, or never)
             # while the client was told the batch failed. The parsed
             # requests are handed straight to the ledger (no re-parse).
-            parsed = [
-                TokenRequest.from_bytes(bytes.fromhex(h))
-                for h in msg["requests"]
-            ]
+            parsed = [TokenRequest.from_bytes(rb) for rb in msg["requests"]]
             # pad/truncate the trace list to the request list: a length
             # mismatch from a buggy client must never drop requests
             # (zip would silently truncate the batch)
@@ -358,7 +463,7 @@ class LedgerServer:
             ]}
         if op == "resolve":
             raw = self.network.resolve_input(ID(msg["tx_id"], msg["index"]))
-            return {"ok": True, "output": raw.hex()}
+            return {"ok": True, "output": raw}
         if op == "exists":
             return {"ok": True, "exists": self.network.exists(ID(msg["tx_id"], msg["index"]))}
         if op == "status":
@@ -628,7 +733,7 @@ class RemoteNetwork:
         Each wire attempt and each status-recovery probe is a child span
         of the caller's `remote.submit`, so retries are visible in the
         tx's stitched trace."""
-        msg = {"op": "submit", "request": request_bytes.hex()}
+        msg = {"op": "submit", "request": request_bytes}
         last: Optional[BaseException] = None
         for attempt in range(self.retries + 1):
             try:
@@ -718,7 +823,7 @@ class RemoteNetwork:
         with mx.span("remote.submit_many", txs=len(requests)):
             resp = self._call({
                 "op": "submit_many",
-                "requests": [rb.hex() for rb in requests_bytes],
+                "requests": list(requests_bytes),
                 "traces": [c.to_wire() for c in ctxs],
             })
         t1 = time.time()
@@ -749,7 +854,7 @@ class RemoteNetwork:
         resp = self._call_idempotent(
             {"op": "resolve", "tx_id": token_id.tx_id, "index": token_id.index}
         )
-        return bytes.fromhex(resp["output"])
+        return resp["output"]
 
     def exists(self, token_id: ID) -> bool:
         return self._call_idempotent(

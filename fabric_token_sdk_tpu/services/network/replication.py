@@ -249,16 +249,14 @@ class ReplicaState:
                         "height": self.network.height()}
         if op == "repl.bootstrap":
             self._fence(int(msg.get("epoch", 0)), op)
-            height = self.network.install_snapshot(
-                bytes.fromhex(msg["snapshot"])
-            )
+            height = self.network.install_snapshot(msg["snapshot"])
             with self._lock:
                 self.leader_height = max(self.leader_height, height)
                 self.last_heartbeat = time.monotonic()
             return {"ok": True, "height": height}
         if op == "repl.ship":
             self._fence(int(msg.get("epoch", 0)), op)
-            height = self.network.apply_delta(bytes.fromhex(msg["record"]))
+            height = self.network.apply_delta(msg["record"])
             with self._lock:
                 self.leader_height = max(self.leader_height, height)
                 self.last_heartbeat = time.monotonic()
@@ -548,7 +546,7 @@ class _FollowerLink:
         ):
             snap = self.state.network.snapshot()
             resp = self._rpc(sock, {
-                "op": "repl.bootstrap", "snapshot": snap.hex(),
+                "op": "repl.bootstrap", "snapshot": snap,
                 "epoch": self.state.epoch,
             })
             self._set_follower_height(int(resp["height"]))
@@ -558,7 +556,7 @@ class _FollowerLink:
                 if self._stop.is_set():
                     return
                 resp = self._rpc(sock, {
-                    "op": "repl.ship", "record": payload.hex(),
+                    "op": "repl.ship", "record": payload,
                     "epoch": self.state.epoch,
                 })
                 self._set_follower_height(int(resp["height"]))
@@ -575,7 +573,7 @@ class _FollowerLink:
             _height, record = item
             faults.fire("repl.ship")
             resp = self._rpc(sock, {
-                "op": "repl.ship", "record": record.hex(),
+                "op": "repl.ship", "record": record,
                 "epoch": self.state.epoch,
             })
             self._set_follower_height(int(resp["height"]))

@@ -460,6 +460,80 @@ def test_zkatdlog_block_differential_host_proof_batch(zk_pp, rng, monkeypatch):
     assert flights and flights[-1]["verified"] >= 1
 
 
+def _zk_range_corpus(pp, rng):
+    """One issue of four tokens, then four 1-in/2-out transfers (each
+    output carries `exponent` membership proofs): valid, proof-tampered,
+    a range proof one digit short for its second output, valid. Every
+    request is signed over the action it carries, so the PROOF decides."""
+    from fabric_token_sdk_tpu.crypto.rangeproof import RangeProof
+    from fabric_token_sdk_tpu.crypto.transfer import TransferProof
+
+    drv = ZKATDLogDriver(pp)
+    key = sign.keygen(random.Random(21))
+    ident = identity.pk_identity(key.public)
+    out = drv.issue(ident, "USD", [242] * 4, [ident] * 4, rng=rng)
+    req = TokenRequest(anchor="seed")
+    req.issues.append(
+        IssueRecord(action=out.action_bytes, issuer=ident,
+                    outputs_metadata=out.metadata, receivers=[ident] * 4)
+    )
+    req.issues[0].signature = key.sign(req.marshal_to_sign(), random.Random(31))
+    reqs = [req.to_bytes()]
+    for k in range(4):
+        t = drv.transfer(
+            [ID("seed", k)], [out.outputs[k]], [out.metadata[k]],
+            "USD", [200, 42], [ident, ident], rng=rng,
+        )
+        d = loads(t.action_bytes)
+        if k == 1:
+            p = bytearray(d["proof"])
+            p[len(p) // 2] ^= 1
+            d["proof"] = bytes(p)
+        if k == 2:
+            tp = TransferProof.from_bytes(d["proof"])
+            rpf = RangeProof.from_bytes(tp.range_correctness)
+            rpf.membership_proofs[1].pop()
+            rpf.digit_commitments[1].pop()
+            tp.range_correctness = rpf.to_bytes()
+            d["proof"] = tp.to_bytes()
+        tr = TokenRequest(anchor=f"r{k}")
+        tr.transfers.append(
+            TransferRecord(action=dumps(d), input_ids=[ID("seed", k)],
+                           senders=[ident], outputs_metadata=t.metadata,
+                           receivers=[ident, ident])
+        )
+        tr.transfers[0].signatures = [
+            key.sign(tr.marshal_to_sign(), random.Random(300 + k))
+        ]
+        reqs.append(tr.to_bytes())
+    return reqs
+
+
+def test_zkatdlog_block_differential_at_exponent_5(rng, monkeypatch):
+    """Five digits per output (the token sample's exponent; base 3 keeps
+    the signed table small): range-carrying shapes are never decided by
+    the batch-first pass, so statuses AND messages equal the scalar
+    path's, the wrong digit count included."""
+    pp = setup(base=3, exponent=5, rng=random.Random(0xF75))
+    reqs = _zk_range_corpus(pp, rng)
+    monkeypatch.setenv("FTS_HOST_BATCH", "0")
+    baseline = _zk_run(pp, reqs)
+    assert [st for _tx, st, _m in baseline] == [
+        TxStatus.VALID, TxStatus.VALID, TxStatus.INVALID, TxStatus.INVALID,
+        TxStatus.VALID,
+    ]
+    assert "range proof not well formed" in baseline[3][2]
+    monkeypatch.setenv("FTS_HOST_BATCH", "1")
+    request_mod.cache_clear()
+    assert _zk_run(pp, reqs) == baseline
+    # and one request per block, as the benchmark's plain reference runs
+    net = Network(
+        RequestValidator(ZKATDLogDriver(pp)),
+        policy=BlockPolicy(use_batched=False, sign_batched=False),
+    )
+    assert _outcomes([net.submit(r) for r in reqs]) == baseline
+
+
 # ===================================================================
 # Parsed-request cache
 # ===================================================================
