@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import limbs as lb, tower as tw
+from . import limbs as lb, stages as st, tower as tw
 from .field import FP
 from ..crypto import hostmath as hm
 from ..utils import devobs
@@ -339,13 +339,21 @@ def gt_is_one_host(arr) -> np.ndarray:
 # XLA compile of the same math. The staged path below splits the pipeline
 # into shape-stable tile programs compiled once and shared by every
 # verifier and batch size:
-#   * miller tile  — (MILLER_TILE, ...) pairs            (1 program, ever)
+#   * miller tile  — (tile_rows("miller_tile"), ...) pairs (1 program, ever)
 #   * row product  — (FEXP_TILE, K, ...) tree fp12 mul   (tiny, per K)
 #   * final-exp    — (FEXP_TILE, ...) GT rows            (1 program, ever)
 # Tiles pad with generator pairs / GT ones; padding is masked out before
 # the product so results are exact.
+#
+# The Miller tile's height is the backend's, as the stage tiles' is
+# (`stages.tile_rows`: 16 pairs off the chip, 128 on a TPU, where a warm
+# dispatch of 128 costs 96.5 ms against 93.0 ms for 16; the sweep and
+# the rule stand beside the number in `ops/stages.py`). Everything here
+# that needs it asks that function when it runs: the walk, the padding
+# and tile count, the ledger frame and `ops/warmup.py`'s shape. The
+# final-exp tile and the row product keep 8 rows: three readers of the
+# benchmark are tied to that height (PERF.md section 7).
 
-MILLER_TILE = 16
 FEXP_TILE = 8
 
 
@@ -374,11 +382,11 @@ def _miller_tiles(frame, Pf, Qf, start: int, stop: int):
     tile is enqueued (`frame.tile()`) and then read back
     (`frame.wait()`), one round trip at a time."""
     outs = []
-    for t in range(start * MILLER_TILE, stop * MILLER_TILE, MILLER_TILE):
+    T = st.tile_rows("miller_tile")
+    for t in range(start * T, stop * T, T):
         with frame.tile():
             f = miller_loop(
-                jnp.asarray(Pf[t : t + MILLER_TILE]),
-                jnp.asarray(Qf[t : t + MILLER_TILE]),
+                jnp.asarray(Pf[t : t + T]), jnp.asarray(Qf[t : t + T])
             )
         with frame.wait():
             outs.append(np.asarray(f))
@@ -401,8 +409,6 @@ def _sharded_tiles(fn, ntiles: int, workers: int, *args):
     """The dp x mp leg of the per-shard stage-tile dispatch: delegates
     to `stages.run_tile_spans` (the one sharded span-dispatch mechanism,
     degrade chain included) under the pairing-plane counters."""
-    from . import stages as st
-
     return st.run_tile_spans(
         fn, ntiles, workers, *args,
         calls=mx.counter("pairing.staged.sharded_calls"),
@@ -425,8 +431,6 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
     from worker threads — the host-dispatch expression of "dp over rows,
     mp over pairing legs". Zero new XLA programs; bit-identical output.
     """
-    from . import stages as st
-
     Ps = np.asarray(Ps)
     Qs = np.asarray(Qs)
     B, K = Ps.shape[0], Ps.shape[1]
@@ -441,7 +445,9 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
     mask = np.zeros(N, dtype=bool)
     if inf_mask is not None:
         mask |= np.asarray(inf_mask).reshape(N)
-    pad = (-N) % MILLER_TILE
+    T = st.tile_rows("miller_tile")
+    pad = (-N) % T
+    n_miller = (N + pad) // T
     if pad:
         Pg, Qg = _pad_pair_consts()
         Pf = np.concatenate([Pf, np.broadcast_to(Pg, (pad, 2, L))])
@@ -450,14 +456,13 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
     mx.counter("pairing.staged.calls").inc()
     mx.counter("pairing.staged.rows").inc(B)
     mx.counter("pairing.staged.legs").inc(N)
-    mx.counter("pairing.staged.miller_tiles").inc((N + pad) // MILLER_TILE)
+    mx.counter("pairing.staged.miller_tiles").inc(n_miller)
     # the two ledger frames below (utils/devobs.py) are the only timers
     # of the tile walks; `pairing.product_staged` spans the whole call
     with mx.span("pairing.product_staged", rows=B, legs_per_row=K):
         # all inter-stage glue (concat/mask/reshape/pad) stays in numpy so
         # the ONLY device programs are the three tile kernels — no
         # per-shape concatenate/select programs on the accelerator
-        n_miller = (N + pad) // MILLER_TILE
         with devobs.dispatch(
             "miller_tile", rows=N, padded_rows=pad, tiles=n_miller,
             dp=dp, mp=mp,

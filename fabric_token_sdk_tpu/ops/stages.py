@@ -13,8 +13,9 @@ the total distinct-program count is a small constant — independent of
 batch size, transfer shape, and parameter set.
 
 Stage inventory (`tile_rows(program)` flat rows each — one height per
-program per backend, see `tile_rows`; tables/keys are ARGUMENTS, not
-baked constants, so one executable serves every parameter set):
+program per backend, see `tile_rows`, which also holds the height of
+the staged pairing product's Miller tile; tables/keys are ARGUMENTS,
+not baked constants, so one executable serves every parameter set):
 
   G1:  msm tile (per nbases in {1,2,3}), variable-base scalar-mul tile,
        Jacobian add tile, Jacobian sub tile (add + neg fused),
@@ -79,6 +80,25 @@ _HOST_TILE_ROWS = 8
 # only programs worth under 50 ms of a 14 s block together have an
 # optimum of their own that differs by more than 20 % of their time.
 _TPU_TILE_ROWS = 128
+# The Miller tile of the staged pairing product (`ops/pairing.py`,
+# program `miller_tile`) has a height of its own, found the same way.
+# Off the chip 16 pairs, as ever (the CPU backend pays per row: 4.7 s
+# a tile of 16, 8.4 s one of 32). On the chip one warm dispatch
+# (transfer in, dispatch, read-back) costs, ms at 16 / 32 / 64 / 128 /
+# 256 / 512 pairs (sweep: PERF.md section 6, PR 29): 93.0 / 115.3 /
+# 96.3 / 96.5 / 177.4 / 386.6, i.e. 93.0 / 57.7 / 24.1 / 12.1 / 11.1 /
+# 12.1 per 16 pairs: flat up to 128, where the pairs fill the lanes
+# (below 128 the chip's compiler keeps the 32 limbs in the lanes, at 32
+# and 64 it mixes both layouts), linear from there. The rule: over the
+# two Miller calls that cost (1,008 rows: a 64-tx (2,2) block at base
+# 100 / exponent 2; 320 rows: an 8-tx block at base 300 / exponent 5)
+# take the T that minimises the sum of ceil(rows / T) * c(T) among the
+# T with ceil(32 / T) * c(T) <= 2 * c(16), so that the smallest device
+# block (32 rows, two dispatches of 16 = 186 ms) does not get slower.
+# 128: 772 + 290 ms; 256: 710 + 355 ms (and 177 ms for a block of 32);
+# 512 is out (387 ms for a block of 32).
+_HOST_MILLER_ROWS = 16
+_TPU_MILLER_ROWS = 128
 
 
 @functools.cache
@@ -88,8 +108,11 @@ def _on_tpu() -> bool:
 
 
 def tile_rows(program: str) -> int:
-    """Rows one dispatch of stage program `program` (a
-    `stage_programs()` name) holds on this process's backend."""
+    """Rows one dispatch of tile program `program` (a
+    `stage_programs()` name, or `miller_tile`) holds on this process's
+    backend."""
+    if program == "miller_tile":
+        return _TPU_MILLER_ROWS if _on_tpu() else _HOST_MILLER_ROWS
     return _TPU_TILE_ROWS if _on_tpu() else _HOST_TILE_ROWS
 
 

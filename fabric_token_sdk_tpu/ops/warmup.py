@@ -40,11 +40,8 @@ def pairing_programs() -> Iterable[Tuple[str, object, tuple]]:
     2 legs (Pointcheval-Sanders, and the membership GT pre-commitment on
     the prove side) and 4 legs (membership verify)."""
     L = lb.NLIMBS
-    yield (
-        "miller_tile",
-        pr.miller_loop,
-        ((pr.MILLER_TILE, 2, L), (pr.MILLER_TILE, 2, 2, L)),
-    )
+    T = st.tile_rows("miller_tile")
+    yield ("miller_tile", pr.miller_loop, ((T, 2, L), (T, 2, 2, L)))
     for k in (2, 4):
         yield (f"gt_product_k{k}_tile", pr._product_rows, ((pr.FEXP_TILE, k, 6, 2, L),))
     yield ("final_exp_tile", pr.final_exp, ((pr.FEXP_TILE, 6, 2, L),))
