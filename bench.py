@@ -66,7 +66,7 @@ GO_BASELINE_TX_S = 133.0
 _done = threading.Event()
 
 # armed-deadline bookkeeping (monotonic t0 + budget) so later phases —
-# the scaling sweep — can size themselves to the REMAINING window
+# the soak — can size themselves to the REMAINING window
 _armed = {"t0": None, "deadline": None}
 
 
@@ -239,161 +239,7 @@ def _arm_deadline(platform: str, device_kind: str) -> None:
     threading.Thread(target=watchdog, daemon=True).start()
 
 
-def _scaling_sweep(ctx, hb) -> list:
-    """Throughput-vs-devices curve: re-run the block phase under mesh
-    configs of growing device count (`FTS_BENCH_SCALING_DEVICES`,
-    default "1,2,4,8") and report per-point rate + per-device
-    efficiency. Each point is a FRESH ledger fed the SAME issue/transfer
-    corpus the block phase built, with the `BatchedTransferVerifier`
-    dispatch sharded over the point's dp x mp mesh (`Network(mesh=...)`
-    -> per-shard stage-tile dispatch; on an emulated single-chip plane
-    the mesh is the host-dispatch extent — the mechanism and curve shape
-    are what a real slice scales). When the block phase itself ran
-    UNSHARDED (no ambient mesh env), its measured rate is reused as the
-    free n_devices=1 point. Budget-aware: points are measured [min, max,
-    middles...] and the sweep stops LOUDLY when the next point would
-    blow min(`FTS_BENCH_SCALING_BUDGET_S`, 80% of the remaining
-    watchdog window) — the extremes land first, so a truncated sweep
-    still carries >= 2 device counts.
-    """
-    from fabric_token_sdk_tpu.api.validator import RequestValidator
-    from fabric_token_sdk_tpu.ops import stages as st_mod
-    from fabric_token_sdk_tpu.parallel import MeshConfig
-    from fabric_token_sdk_tpu.services.network import BlockPolicy, Network
-
-    mx = _metrics()
-    driver = ctx["driver"]
-    issue_bytes = ctx["issue_bytes"]
-    transfer_reqs = ctx["transfer_reqs"]
-    n = len(transfer_reqs)
-    try:
-        devices = sorted(
-            {
-                max(1, int(v))
-                for v in os.environ.get(
-                    "FTS_BENCH_SCALING_DEVICES", "1,2,4,8"
-                ).split(",")
-                if v.strip()
-            }
-        )
-    except ValueError:
-        devices = [1, 2, 4, 8]
-    mp = max(1, int(os.environ.get("FTS_BENCH_SCALING_MP", "1")))
-    budget = float(os.environ.get("FTS_BENCH_SCALING_BUDGET_S", "900"))
-    remaining = _remaining_budget_s()
-    if remaining is not None:
-        budget = min(budget, remaining * 0.8)
-    points, cost_max = {}, 0.0
-    # the base block run IS the n=1 point when it ran unsharded (no
-    # ambient mesh/dp env) — one free curve point, no repeat measurement
-    if (
-        1 in devices
-        and ctx.get("base_rate")
-        and st_mod.default_dp() == 1
-        and MeshConfig.from_env() is None
-    ):
-        points[1] = ctx["base_rate"]
-        cost_max = ctx.get("base_cost_s") or 0.0
-    # extremes first: a truncated sweep still yields a 2-point curve
-    todo = [d for d in devices if d not in points]
-    order = []
-    if todo:
-        order = [todo[-1]] + [d for d in reversed(todo[:-1])]
-        if not points and len(todo) > 1:
-            order = [todo[0], todo[-1]] + list(reversed(todo[1:-1]))
-    t_sweep = time.time()
-    gate_extent = max(devices)
-    for i, nd in enumerate(order):
-        elapsed = time.time() - t_sweep
-        # even-share budgeting: every unvisited point is entitled to an
-        # equal slice of the remaining budget, and a skipped point's
-        # slice redistributes to the points after it. First-come-first-
-        # served (skip nothing until the WHOLE budget is nearly gone)
-        # let the extremes starve the mid-extent counts — PR 9's curve
-        # dropped n_devices=2 exactly that way. The MAX extent is the
-        # exception: `ftstop compare --scaling` gates efficiency at the
-        # largest measured device count, so that one point gets first
-        # claim on slack (double share, capped at the whole remainder)
-        # rather than being the systematic first sacrifice of a tight
-        # budget — a truncated sweep keeps the gate point AND the
-        # small-extent points, shedding middles first.
-        share = (budget - elapsed) / (len(order) - i)
-        if nd == gate_extent:
-            share = min(budget - elapsed, 2 * share)
-        if points and cost_max * 1.2 > share:
-            print(
-                f"[fts-bench] scaling: skipping n_devices={nd} — "
-                f"predicted {cost_max * 1.2:.0f}s exceeds its even share "
-                f"{share:.0f}s of the remaining {budget - elapsed:.0f}s "
-                "budget",
-                file=sys.stderr, flush=True,
-            )
-            continue
-        hb.set_phase("block_scaling", devices=nd, txs=n)
-        cfg = MeshConfig.build(nd, mp if nd % mp == 0 else 1)
-        wal_path = None
-        if ctx.get("wal"):
-            # same durability tax as the base point: the n=1 baseline came
-            # from a WAL-journaled ledger, so every sweep point journals
-            # too — otherwise efficiency is biased upward
-            import tempfile
-
-            wal_path = os.path.join(
-                tempfile.mkdtemp(prefix=f"fts-scaling-wal-{nd}-"),
-                "ledger.wal",
-            )
-        net = Network(
-            RequestValidator(driver),
-            policy=BlockPolicy(max_block_txs=n, min_batch=1),
-            mesh=cfg,
-            wal_path=wal_path,
-        )
-        t0 = time.time()
-        ev = net.submit(issue_bytes)
-        assert ev.status.value == "Valid", (
-            f"scaling issue rejected: {ev.message}"
-        )
-        tb = time.time()
-        events = net.submit_many(transfer_reqs)
-        dt = time.time() - tb
-        bad = [e for e in events if e.status.value != "Valid"]
-        assert not bad, (
-            f"scaling block ({nd} devices) rejected {len(bad)} txs: "
-            f"{bad[0].message}"
-        )
-        points[nd] = n / dt if dt > 0 else 0.0
-        cost_max = max(cost_max, time.time() - t0)
-    if len(points) < 2:
-        # a curve needs >= 2 device counts to say anything about scaling
-        # — a lone point (budget starved the sweep) would also let
-        # `ftstop compare --scaling` gate at n=1 where efficiency is 1.0
-        # by construction; drop it LOUDLY instead
-        print(
-            f"[fts-bench] scaling: only {len(points)} device count(s) "
-            "measured within budget — no curve recorded",
-            file=sys.stderr, flush=True,
-        )
-        return []
-    curve = []
-    n_min = min(points)
-    rate_min = points[n_min]
-    for nd in sorted(points):
-        rate = points[nd]
-        eff = (
-            rate * n_min / (nd * rate_min) if rate_min > 0 and nd else 0.0
-        )
-        curve.append({
-            "n_devices": nd,
-            "block_txs_per_s": round(rate, 3),
-            "efficiency": round(eff, 3),
-        })
-    mx.gauge("bench.scaling_points").set(len(curve))
-    mx.gauge("bench.scaling_efficiency").set(curve[-1]["efficiency"])
-    return curve
-
-
-def _block_throughput(pp, rng, hb, platform: str = "cpu",
-                      scaling_ctx=None) -> dict:
+def _block_throughput(pp, rng, hb, platform: str = "cpu") -> dict:
     """Product-path benchmark: multi-tx blocks through the orderer.
 
     Builds B real 2-in/2-out zkatdlog transfer REQUESTS (owner
@@ -530,14 +376,6 @@ def _block_throughput(pp, rng, hb, platform: str = "cpu",
         frac = (wal_hist.sum - wal_s_before) / elapsed if elapsed > 0 else 0.0
         mx.gauge("bench.wal_overhead_frac").set(round(frac, 4))
         result["wal_overhead_frac"] = round(frac, 4)
-    if scaling_ctx is not None:
-        # hand the corpus to the scaling sweep (which runs AFTER the
-        # enriched block line is printed — a sweep can never cost it)
-        scaling_ctx.update(
-            driver=driver, issue_bytes=issue_req.to_bytes(),
-            transfer_reqs=transfer_reqs, base_rate=rate,
-            base_cost_s=elapsed, wal=wal_path is not None,
-        )
     return result
 
 
@@ -562,8 +400,7 @@ def _soak(hb, zk_pp=None) -> dict:
     plus batched signatures on zk blocks — `zk_pp` injects prebuilt
     params for tests, else a small `setup()` runs outside the measured
     region). Sized by FTS_BENCH_SOAK_S / _CLIENTS / _GROUP;
-    budget-aware like the scaling sweep (never outlives the armed
-    watchdog window).
+    budget-aware (never outlives the armed watchdog window).
 
     Chaos mode (`FTS_BENCH_SOAK_FAULTS=1`): a chaos-monkey thread
     randomly arms/disarms injected faults for the whole window —
@@ -699,7 +536,6 @@ def _soak(hb, zk_pp=None) -> dict:
         "ledger.block.batch_errors",      # verify plane
         "batch.sign.host_fallbacks",      # sign plane
         "batch.prove.host_fallbacks",     # prove plane
-        "sharding.fallbacks",             # stages sharded dispatch
     )
     resil_names = ("resilience.breaker.open",) + fallback_counters
     resil_before = {n: mx.REGISTRY.counter(n).value for n in resil_names}
@@ -1686,12 +1522,8 @@ def main() -> None:
     # last-line parsers (it is a strict superset of the same fields)
     failed = []  # phases that raised: reported, then the run exits non-zero
     if os.environ.get("FTS_BENCH_BLOCK", "1") != "0":
-        scaling_ctx = {}
         try:
-            result.update(
-                _block_throughput(pp, rng, hb, platform,
-                                  scaling_ctx=scaling_ctx)
-            )
+            result.update(_block_throughput(pp, rng, hb, platform))
             print(json.dumps(result), flush=True)
         except Exception as e:  # pragma: no cover
             failed.append("block_throughput")
@@ -1701,23 +1533,6 @@ def main() -> None:
                 file=sys.stderr,
                 flush=True,
             )
-        # throughput-vs-devices curve (FTS_BENCH_SCALING=0 opts out):
-        # runs AFTER the enriched line is secured; on success one final
-        # superset line carries the `scaling` list for last-line parsers
-        if scaling_ctx and os.environ.get("FTS_BENCH_SCALING", "1") != "0":
-            try:
-                curve = _scaling_sweep(scaling_ctx, hb)
-                if curve:
-                    result["scaling"] = curve
-                    print(json.dumps(result), flush=True)
-            except Exception as e:  # pragma: no cover
-                failed.append("block_scaling")
-                print(
-                    f"[fts-bench] scaling sweep failed: "
-                    f"{type(e).__name__}: {e}",
-                    file=sys.stderr,
-                    flush=True,
-                )
 
     # sustained-load soak against one pipelined, admission-controlled
     # node (FTS_BENCH_SOAK=0 opts out): steady-state tx/s, client p99

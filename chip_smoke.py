@@ -44,8 +44,6 @@ Phases, in order of cost:
           `LedgerServer` / `RemoteNetwork`
   agree   the same request bytes through the scalar host validator
           (`use_batched=False, sign_batched=False`): same verdicts
-  mesh4   only when 4 devices are visible: one block again under
-          `Network(mesh=MeshConfig.build(4))`, per-device memory stats
 
 No `FTS_*` knob is set or honoured here: on the chip the smoke refuses to
 run with any in its environment. The two cuts of size (`--blocks`,
@@ -82,16 +80,13 @@ FALLBACK_COUNTERS = (
     "ledger.block.batch_errors",
     "batch.sign.host_fallbacks",
     "batch.prove.host_fallbacks",
-    "sharding.fallbacks",
-    "sharding.breaker_skips",
     "resilience.bounded.timeouts",
     "resilience.breaker.open",
     "resilience.breaker.rejected",
     "native.selfcheck.fail",
     "jax.cache.load_failures",
 )
-FALLBACK_EVENTS = ("verify.host_fallback", "sign.host_fallback",
-                   "sharding.fallback")
+FALLBACK_EVENTS = ("verify.host_fallback", "sign.host_fallback")
 
 
 class SmokeFailure(Exception):
@@ -109,7 +104,6 @@ class Run:
     def __init__(self):
         self.t0 = time.monotonic()
         self.current = "args"
-        self.peak0 = {}  # device id -> peak_bytes_in_use at start-up
         self.result = {"ok": False, "phases": {}}
 
     @contextlib.contextmanager
@@ -176,10 +170,6 @@ def phase_device(run: Run, args) -> None:
                   f"the smoke runs the defaults; unset {knobs} and re-run")
         run.result["device"] = dev
         run.result["jax"] = jax.__version__
-        # what an untouched device reports (the runtime's own reservation):
-        # the baseline `mesh4` compares each device's peak against
-        run.peak0 = {d.id: (d.memory_stats() or {}).get("peak_bytes_in_use")
-                     for d in devs}
         info.update(dev)
 
 
@@ -396,8 +386,7 @@ def phase_tiles(run: Run, rng, pairing: bool) -> None:
             Ps = np.stack([pr.encode_g1([p for p, _ in row]) for row in legs])
             Qs = np.stack([pr.encode_g2([q for _, q in row]) for row in legs])
             got = tw.decode_fp12(twice(
-                "miller+gt_product_k2+final_exp",
-                lambda P, Q: pr.pairing_product_staged(P, Q, dp=1, mp=1),
+                "miller+gt_product_k2+final_exp", pr.pairing_product_staged,
                 Ps, Qs))
             check(got == [hm.pairing_product(row) for row in legs],
                   "miller_tile + final_exp_tile disagree with "
@@ -575,14 +564,14 @@ def _drive(net, issues: list, groups: list):
     return verdicts, clock
 
 
-def _serve(corpus: Corpus, policy, wal_path, issues, groups, mesh=None):
+def _serve(corpus: Corpus, policy, wal_path, issues, groups):
     """Stand up Network + LedgerServer, submit through RemoteNetwork;
     returns (verdicts, clock, ops_health)."""
     from fabric_token_sdk_tpu.services.network.remote import (
         LedgerServer, RemoteNetwork,
     )
 
-    net = corpus.network(policy, wal_path=wal_path, mesh=mesh)
+    net = corpus.network(policy, wal_path=wal_path)
     server = LedgerServer(network=net).start()
     client = RemoteNetwork(server.address, timeout=CLIENT_TIMEOUT_S)
     try:
@@ -721,72 +710,6 @@ def phase_agree(run: Run, corpus: Corpus, policy, issues, groups,
         info.update(run.result["agree"])
 
 
-def placement(stats: dict, peak0: dict, live: dict) -> list:
-    """Per device, whether one of the program's arrays ever lived there.
-    `stats` maps device id -> `memory_stats()` now (None where the backend
-    reports none), `peak0` -> `peak_bytes_in_use` at start-up, `live` ->
-    count of live arrays. An untouched TPU already reports a non-zero peak
-    (the runtime's own reservation: 27,136 B on a v5e), so a peak counts
-    only above the start-up reading."""
-    per_device = []
-    for dev_id in sorted(stats):
-        st = stats[dev_id] or {}
-        peak, n_live = st.get("peak_bytes_in_use"), live.get(dev_id, 0)
-        per_device.append({
-            "id": dev_id, "peak_bytes_in_use": peak,
-            "peak_bytes_at_start": peak0.get(dev_id),
-            "bytes_in_use": st.get("bytes_in_use"),
-            "live_arrays": n_live,
-            "ever_held_an_array": bool(n_live) or (
-                peak is not None and peak > (peak0.get(dev_id) or 0)),
-        })
-    return per_device
-
-
-def phase_mesh4(run: Run, corpus: Corpus, policy, out_dir, issues, groups,
-                verdicts):
-    """Four chips visible: one block again under a 4-device MeshConfig.
-    Reports where arrays lived; idle chips do not fail the smoke."""
-    with run.phase("mesh4") as info:
-        import jax
-
-        from fabric_token_sdk_tpu.parallel import MeshConfig
-
-        # the first block and the two bad ones (their inputs come from
-        # the first and the last issue)
-        n, w = corpus.n, len(groups[0])
-        sharded0 = _counter("stages.sharded_calls")
-        got, clock, _health = _serve(
-            corpus, policy, os.path.join(out_dir, "ledger.mesh4.wal"),
-            [issues[0]] + issues[1:][-1:], [groups[0], groups[-1]],
-            mesh=MeshConfig.build(4))
-        check(got == verdicts[:w] + verdicts[n:],
-              "verdicts under the 4-device mesh differ from the serve phase")
-        check(_counter("stages.sharded_calls") > sharded0,
-              "the 4-device mesh never took the sharded dispatch")
-        _no_fallbacks("mesh4")
-        live = {}
-        for a in jax.live_arrays():
-            for dv in a.devices():
-                live[dv.id] = live.get(dv.id, 0) + 1
-        per_device = placement(
-            {dv.id: dv.memory_stats() for dv in jax.devices()},
-            run.peak0, live)
-        idle = [p["id"] for p in per_device if not p["ever_held_an_array"]]
-        run.result["mesh4"] = {
-            "mesh": {"n_devices": 4, "dp": 4, "mp": 1},
-            "transfers": len(got), "per_device": per_device,
-            "idle_devices": idle, **clock,
-            "sharded_calls": _counter("stages.sharded_calls") - sharded0,
-        }
-        info.update(idle_devices=idle, **clock)
-        for p in per_device:
-            print(f"[chip-smoke]   device {p['id']}: peak_bytes_in_use="
-                  f"{p['peak_bytes_in_use']} (at start "
-                  f"{p['peak_bytes_at_start']}) live_arrays={p['live_arrays']} "
-                  f"ever_held_an_array={p['ever_held_an_array']}", flush=True)
-
-
 # -------------------------------------------------------------------- main
 
 
@@ -863,9 +786,6 @@ def main(argv=None) -> int:
         phase_prove(run, corpus, size["prove_txs"])
         issues, groups, verdicts = phase_serve(run, corpus, out_dir, policy)
         phase_agree(run, corpus, policy, issues, groups, verdicts)
-        if run.result["device"]["count"] == 4:
-            phase_mesh4(run, corpus, policy, out_dir, issues, groups,
-                        verdicts)
         final = _no_fallbacks("end of run")
     except SmokeFailure as e:
         run.result["failed"] = {"phase": run.current, "reason": str(e)}

@@ -23,9 +23,8 @@ node-side), and process/device memory. Ctrl-C exits cleanly.
 
 `devices` polls the same `ops.health` RPC and renders the device-plane
 dispatch ledger (`utils/devobs.py`) as a per-program table: dispatches,
-mean occupancy, padding waste %, p50/p99 dispatch wall, dp x mp
-placement, compiles with their wall time, persistent-cache hits/misses,
-and degrade decisions (breaker-open skips, dispatch-error fallbacks).
+mean occupancy, padding waste %, p50/p99 dispatch wall, compiles with
+their wall time, persistent-cache hits/misses, and degrade decisions.
 
 `compare` is the observatory: it diffs bench results against each other
 or against the history file `bench.py` appends every outcome to
@@ -241,7 +240,7 @@ def format_devices(health: dict) -> str:
     lines = [head]
     cols = (
         f"{'plane':<8} {'program':<20} {'disp':>6} {'occ':>7} "
-        f"{'waste':>7} {'p50':>9} {'p99':>9} {'dpxmp':>6} "
+        f"{'waste':>7} {'p50':>9} {'p99':>9} "
         f"{'compiles':>8} {'comp_s':>7} {'hit/miss':>9} {'degr':>5}"
     )
     lines.append(cols)
@@ -251,7 +250,6 @@ def format_devices(health: dict) -> str:
             f"{r.get('dispatches', 0):>6} {_pct(r.get('occupancy')):>7} "
             f"{_pct(r.get('waste_frac')):>7} {_s(r.get('p50_s')):>9} "
             f"{_s(r.get('p99_s')):>9} "
-            f"{r.get('dp', 1)}x{r.get('mp', 1):<3} "
             f"{r.get('compiles', 0):>8} {r.get('compile_s', 0):>7g} "
             f"{r.get('cache_hits', 0)}/{r.get('cache_misses', 0):<4} "
             f"{r.get('degrades', 0):>5}"

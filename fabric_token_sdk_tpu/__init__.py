@@ -12,7 +12,6 @@ Layers (see SURVEY.md):
   api/       token management service facade (TMS, wallets, validator)
   drivers/   fabtoken + zkatdlog driver implementations
   services/  ttx, vault, selector, ttxdb, auditor, network, ...
-  parallel/  mesh sharding of batched proof generation/verification
   utils/     serialization, hashing, tracing, errors
 """
 

@@ -138,18 +138,16 @@ class Driver(abc.ABC):
         None to route this action through the host path (default)."""
         return None
 
-    def batch_verifier(self, mesh=None):
+    def batch_verifier(self):
         """The driver's block-batched transfer-proof verifier (an object
         with `verify(rows) -> bool array`), or None when the driver has
-        no batched plane (default). `mesh` is an optional
-        `parallel.sharding.MeshConfig` the verifier's dispatch should
-        shard over (dp x mp); drivers without a device plane ignore it."""
+        no batched plane (default)."""
         return None
 
-    def batch_prover(self, mesh=None):
+    def batch_prover(self):
         """The driver's batched transfer-proof GENERATOR (the prove-side
         twin of `batch_verifier`), or None when the driver proves on the
-        host only (default). `mesh` as in `batch_verifier`."""
+        host only (default)."""
         return None
 
     def transfer_sign_plan(self, action_bytes: bytes):

@@ -34,30 +34,8 @@ from .transfer import TransferProof
 from .wellformedness import TransferWF, challenge_transfer_wf
 from ..ops import curve as cv, curve2 as cv2, limbs as lb, pairing as pr, \
     stages as st, tower as tw
-from ..parallel.sharding import MeshConfig
 from ..utils import devobs
 from ..utils import metrics as mx, resilience
-
-class _MeshBound:
-    """Mixin: a verifier bound to an optional `MeshConfig` — its stage
-    dispatches shard over dp and its pairing products over dp x mp (the
-    per-shard stage-tile dispatch of `parallel/sharding.py`; None falls
-    back to the ambient `FTS_MESH_DEVICES`/`FTS_DP_SHARDS` env inside
-    the runners). Sharding never changes results — only dispatch."""
-
-    mesh: Optional[MeshConfig] = None
-
-    def set_mesh(self, mesh) -> None:
-        self.mesh = MeshConfig.of(mesh)
-
-    @property
-    def _dp(self) -> Optional[int]:
-        return None if self.mesh is None else self.mesh.dp
-
-    @property
-    def _mp(self) -> Optional[int]:
-        return None if self.mesh is None else self.mesh.mp
-
 
 def _spanned(name):
     """Wrap a verify method in a metrics span (no-op when disabled) and
@@ -87,13 +65,12 @@ def _spanned(name):
 # ===================================================================
 
 
-class BatchedPSVerifier(_MeshBound):
+class BatchedPSVerifier:
     """Verifies B signatures on l-message vectors via the stage tiles."""
 
-    def __init__(self, pk, Q, mesh=None):
+    def __init__(self, pk, Q):
         self.pk_host = list(pk)
         self.Q_host = Q
-        self.set_mesh(mesh)
         self.pk_np = np.asarray(cv2.encode_points(self.pk_host))  # (l+2,3,2,L)
         self.Q_np = np.asarray(pr.encode_g2([Q]))[0]  # (2,2,L)
 
@@ -128,19 +105,15 @@ class BatchedPSVerifier(_MeshBound):
         bases = np.broadcast_to(
             self.pk_np[1:], (B, k) + self.pk_np.shape[1:]
         ).reshape((B * k,) + self.pk_np.shape[1:])
-        terms = st.g2_mul_rows(bases, scal.reshape(B * k, lb.NLIMBS), dp=self._dp)
-        acc = st.g2_tree_sum_rows(
-            terms.reshape((B, k) + terms.shape[1:]), dp=self._dp
-        )
-        acc = st.g2_add_rows(
-            acc, np.broadcast_to(self.pk_np[0], acc.shape), dp=self._dp
-        )
-        H_aff = st.g2_to_affine_rows(acc, dp=self._dp)  # (B, 2, 2, L)
+        terms = st.g2_mul_rows(bases, scal.reshape(B * k, lb.NLIMBS))
+        acc = st.g2_tree_sum_rows(terms.reshape((B, k) + terms.shape[1:]))
+        acc = st.g2_add_rows(acc, np.broadcast_to(self.pk_np[0], acc.shape))
+        H_aff = st.g2_to_affine_rows(acc)  # (B, 2, 2, L)
         Ps = np.stack([P1, P2], axis=1)  # (B, 2, 2, L) G1 affine
         Qs = np.stack(
             [np.broadcast_to(self.Q_np, H_aff.shape), H_aff], axis=1
         )  # (B, 2, 2, 2, L)
-        gt = pr.pairing_product_staged(Ps, Qs, dp=self._dp, mp=self._mp)
+        gt = pr.pairing_product_staged(Ps, Qs)
         out = pr.gt_is_one_host(gt)
         out[malformed] = False
         return out
@@ -151,13 +124,12 @@ class BatchedPSVerifier(_MeshBound):
 # ===================================================================
 
 
-class BatchedWFVerifier(_MeshBound):
+class BatchedWFVerifier:
     """Recomputes all Schnorr commitments of B same-shape transfer WF
     proofs via the stage tiles, then re-derives challenges on host."""
 
-    def __init__(self, pp: PublicParams, mesh=None):
+    def __init__(self, pp: PublicParams):
         self.pp = pp
-        self.set_mesh(mesh)
         self.table = cv.FixedBaseTable(pp.ped_params)
 
     @_spanned("batch.wf.verify")
@@ -224,13 +196,12 @@ class BatchedWFVerifier(_MeshBound):
         )
         # com_j = prod ped_i^{resp_ji} - stmt_j^challenge over B*n flat rows
         fixed = st.g1_msm_rows(
-            self.table.flat, resp.reshape(B * n, 3, lb.NLIMBS), dp=self._dp
+            self.table.flat, resp.reshape(B * n, 3, lb.NLIMBS)
         )
         sc = st.g1_mul_rows(
             stmt_np.reshape(B * n, 3, lb.NLIMBS), np.repeat(chals, n, axis=0),
-            dp=self._dp,
         )
-        coms = st.g1_sub_rows(fixed, sc, dp=self._dp)
+        coms = st.g1_sub_rows(fixed, sc)
         com_pts = cv.decode_points(coms)  # B*n host points
         out = np.zeros(B, dtype=bool)
         for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
@@ -251,7 +222,7 @@ class BatchedWFVerifier(_MeshBound):
 # ===================================================================
 
 
-class BatchedMembershipVerifier(_MeshBound):
+class BatchedMembershipVerifier:
     """Verifies B membership proofs (the per-digit unit of range proofs).
 
     Device: GT commitment via 4-pairing products + G1 commitment via
@@ -259,9 +230,8 @@ class BatchedMembershipVerifier(_MeshBound):
     Host: per-proof Fiat-Shamir challenge.
     """
 
-    def __init__(self, pp: PublicParams, mesh=None):
+    def __init__(self, pp: PublicParams):
         self.pp = pp
-        self.set_mesh(mesh)
         rp = pp.range_params
         self.pk = rp.sign_pk
         self.Q = rp.Q
@@ -303,11 +273,9 @@ class BatchedMembershipVerifier(_MeshBound):
         bases = np.broadcast_to(
             self.pk_np[1:3], (B, 2) + self.pk_np.shape[1:]
         ).reshape((2 * B,) + self.pk_np.shape[1:])
-        terms = st.g2_mul_rows(bases, z[:, 0:2].reshape(2 * B, L), dp=self._dp)
+        terms = st.g2_mul_rows(bases, z[:, 0:2].reshape(2 * B, L))
         terms = terms.reshape((B, 2) + terms.shape[1:])
-        t_aff = st.g2_to_affine_rows(
-            st.g2_add_rows(terms[:, 0], terms[:, 1], dp=self._dp), dp=self._dp
-        )
+        t_aff = st.g2_to_affine_rows(st.g2_add_rows(terms[:, 0], terms[:, 1]))
 
         # G1 sides: -S^c as S^{r-c} (scalar negation — no extra neg
         # program), R^c, and P^{z_bf}; one fused to-affine pass for all
@@ -315,16 +283,15 @@ class BatchedMembershipVerifier(_MeshBound):
         Rj = st.affine_to_jac_np(R_np)
         powc = st.g1_mul_rows(
             np.concatenate([Sj, Rj]), np.concatenate([neg_chal, z[:, 3]]),
-            dp=self._dp,
         )
-        Pz_j = st.g1_msm_rows(self.tableP.flat, z[:, 2:3], dp=self._dp)
-        aff = st.g1_to_affine_rows(np.concatenate([powc, Pz_j]), dp=self._dp)
+        Pz_j = st.g1_msm_rows(self.tableP.flat, z[:, 2:3])
+        aff = st.g1_to_affine_rows(np.concatenate([powc, Pz_j]))
         negSc, Rc, Pz = aff[:B], aff[B : 2 * B], aff[2 * B :]
 
         # G1 commitment: ped0^{z_v} ped1^{z_cb} - com^c
-        fixed = st.g1_msm_rows(self.table2.flat, com_resp, dp=self._dp)
-        comc = st.g1_mul_rows(com_jac, z[:, 3], dp=self._dp)
-        com_val = st.g1_sub_rows(fixed, comc, dp=self._dp)
+        fixed = st.g1_msm_rows(self.table2.flat, com_resp)
+        comc = st.g1_mul_rows(com_jac, z[:, 3])
+        com_val = st.g1_sub_rows(fixed, comc)
 
         # 4-leg pairing product via the compile-once staged tile programs
         Ps = np.stack([negSc, Rc, R_np, Pz], axis=1)  # (B, 4, 2, L)
@@ -335,7 +302,7 @@ class BatchedMembershipVerifier(_MeshBound):
              np.broadcast_to(self.Q_np, t_aff.shape)],
             axis=1,
         )  # (B, 4, 2, 2, L)
-        gt = pr.pairing_product_staged(Ps, Qs, dp=self._dp, mp=self._mp)
+        gt = pr.pairing_product_staged(Ps, Qs)
         gt_host = tw.decode_fp12(gt)
         com_host = cv.decode_points(com_val)
         out = np.zeros(B, dtype=bool)
@@ -353,32 +320,21 @@ class BatchedMembershipVerifier(_MeshBound):
 # ===================================================================
 
 
-class BatchedTransferVerifier(_MeshBound):
+class BatchedTransferVerifier:
     """Verifies whole blocks of same-shape zkatdlog transfer proofs.
 
     Composition mirrors `transfer.TransferVerifier` but the group/pairing
     work of ALL transactions runs through the fixed-shape stage tiles —
     the total distinct-program count is constant in `(n_in, n_out)`,
-    batch size, and parameter set. An optional `MeshConfig` shards the
-    dispatch over dp (stage rows) x mp (pairing legs) — same
-    executables, bit-identical verdicts.
+    batch size, and parameter set.
     """
 
-    def __init__(self, pp: PublicParams, mesh=None):
+    def __init__(self, pp: PublicParams):
         self.pp = pp
-        self.wf = BatchedWFVerifier(pp, mesh=mesh)
-        self.membership = BatchedMembershipVerifier(pp, mesh=mesh)
-        self.set_mesh(mesh)
+        self.wf = BatchedWFVerifier(pp)
+        self.membership = BatchedMembershipVerifier(pp)
         self.table3 = self.wf.table  # ped 3-base table
         self.table2 = self.membership.table2  # ped[:2]
-
-    def set_mesh(self, mesh) -> None:
-        super().set_mesh(mesh)
-        # tolerate set_mesh during __init__ (sub-verifiers not built yet)
-        if getattr(self, "wf", None) is not None:
-            self.wf.set_mesh(mesh)
-        if getattr(self, "membership", None) is not None:
-            self.membership.set_mesh(mesh)
 
     @_spanned("batch.transfer.verify")
     def verify(self, txs: Sequence[Tuple[list, list, bytes]]) -> np.ndarray:
@@ -491,22 +447,18 @@ class BatchedTransferVerifier(_MeshBound):
         com_tok = st.g1_sub_rows(
             st.g1_msm_rows(
                 self.table3.flat, tok_resp.reshape(nl * n_out, 3, L),
-                dp=self._dp,
             ),
             st.g1_mul_rows(
-                tok_stmt.reshape(nl * n_out, 3, L), chal_rep, dp=self._dp
+                tok_stmt.reshape(nl * n_out, 3, L), chal_rep
             ),
-            dp=self._dp,
         )
         com_val = st.g1_sub_rows(
             st.g1_msm_rows(
                 self.table2.flat, agg_resp.reshape(nl * n_out, 2, L),
-                dp=self._dp,
             ),
             st.g1_mul_rows(
-                agg_stmt.reshape(nl * n_out, 3, L), chal_rep, dp=self._dp
+                agg_stmt.reshape(nl * n_out, 3, L), chal_rep
             ),
-            dp=self._dp,
         )
         com_tok_h = cv.decode_points(com_tok)
         com_val_h = cv.decode_points(com_val)

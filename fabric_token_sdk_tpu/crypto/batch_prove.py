@@ -34,7 +34,6 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import hostmath as hm, pssign, rangeproof, sigproof, wellformedness as wf
-from .batch import _MeshBound
 from .pedersen import BatchedPedersen
 from .setup import PublicParams
 from .transfer import TransferProof, _skip_range
@@ -44,21 +43,17 @@ from ..utils import devobs
 from ..utils import metrics as mx, resilience
 
 
-class BatchedTransferProver(_MeshBound):
+class BatchedTransferProver:
     """Generates whole batches of same-shape zkatdlog transfer proofs.
 
     One instance caches the fixed-base window tables (Pedersen 3-base and
     2-base, PedGen) and the encoded G2 public keys — constructing it is
     the expensive part; `prove` calls are cheap and reusable across
-    shapes and batch sizes (the stage tiles are shape-invariant). An
-    optional `MeshConfig` shards the commit-phase dispatch over dp
-    (stage rows) x mp (pairing legs) — same compile-once executables,
-    byte-identical proofs.
+    shapes and batch sizes (the stage tiles are shape-invariant).
     """
 
-    def __init__(self, pp: PublicParams, mesh=None):
+    def __init__(self, pp: PublicParams):
         self.pp = pp
-        self.set_mesh(mesh)
         self.ped3 = BatchedPedersen(pp.ped_params)
         self.ped2 = BatchedPedersen(pp.ped_params[:2])
         rp = pp.range_params
@@ -109,7 +104,7 @@ class BatchedTransferProver(_MeshBound):
         rows: List[List[int]] = []
         for d in draws:
             rows += d.commit_rows(n_in, n_out)
-        coms, _ = self.ped3.commit_ints(rows, dp=self._dp)
+        coms, _ = self.ped3.commit_ints(rows)
         out = []
         for i, (p, d) in enumerate(zip(provers, draws)):
             row = coms[i * n : (i + 1) * n]
@@ -163,7 +158,7 @@ class BatchedTransferProver(_MeshBound):
         )
         for d in draws:
             rows2 += d.equality_value_rows()
-        coms2, _ = self.ped2.commit_ints(rows2, dp=self._dp)
+        coms2, _ = self.ped2.commit_ints(rows2)
         digit_coms = coms2[:M]
         mem_com_vals = coms2[M : 2 * M]
         eq_com_values = coms2[2 * M :]  # B*n_out
@@ -172,7 +167,7 @@ class BatchedTransferProver(_MeshBound):
         rows3: List[List[int]] = []
         for d in draws:
             rows3 += d.equality_token_rows()
-        eq_com_tokens, _ = self.ped3.commit_ints(rows3, dp=self._dp)
+        eq_com_tokens, _ = self.ped3.commit_ints(rows3)
 
         # ---- signature randomization: (R^r, S^r) variable-base, then
         # obfuscation S'' = S^r + P^sig_bf (fixed-base + Jacobian add)
@@ -181,7 +176,6 @@ class BatchedTransferProver(_MeshBound):
         sig_S = self.sig_S_np[digits]
         rnd = st.g1_mul_rows(
             np.concatenate([sig_R, sig_S]), np.concatenate([r_enc, r_enc]),
-            dp=self._dp,
         )
         rnd_R_jac, rnd_S_jac = rnd[:M], rnd[M:]
         pbf_scal = cv.encode_scalars(
@@ -189,8 +183,8 @@ class BatchedTransferProver(_MeshBound):
         )
         # decode-free commit path: P^sig_bf feeds the Jacobian add and
         # P^rho_bf is decoded once below with the other transcript points
-        pbf_jac = self.pedP.commit_rows(pbf_scal[:, None, :], dp=self._dp)
-        obf_S_jac = st.g1_add_rows(rnd_S_jac, pbf_jac[:M], dp=self._dp)
+        pbf_jac = self.pedP.commit_rows(pbf_scal[:, None, :])
+        obf_S_jac = st.g1_add_rows(rnd_S_jac, pbf_jac[:M])
 
         # one host decode pass for everything that enters a transcript
         host_pts = cv.decode_points(
@@ -211,10 +205,8 @@ class BatchedTransferProver(_MeshBound):
         g2_scal = cv.encode_scalars(
             [m.rho_v for m in mems] + [m.rho_h for m in mems]
         )
-        terms = st.g2_mul_rows(g2_bases, g2_scal, dp=self._dp)
-        t_aff = st.g2_to_affine_rows(
-            st.g2_add_rows(terms[:M], terms[M:], dp=self._dp), dp=self._dp
-        )
+        terms = st.g2_mul_rows(g2_bases, g2_scal)
+        t_aff = st.g2_to_affine_rows(st.g2_add_rows(terms[:M], terms[M:]))
         Ps = np.stack(
             [np.asarray(pr.encode_g1(rnd_R)), np.asarray(pr.encode_g1(p_rho))],
             axis=1,
@@ -222,9 +214,7 @@ class BatchedTransferProver(_MeshBound):
         Qs = np.stack(
             [t_aff, np.broadcast_to(self.Q_np, t_aff.shape)], axis=1
         )  # (M, 2, 2, 2, L)
-        gts = tw.decode_fp12(
-            pr.pairing_product_staged(Ps, Qs, dp=self._dp, mp=self._mp)
-        )
+        gts = tw.decode_fp12(pr.pairing_product_staged(Ps, Qs))
 
         # ---- host Fiat-Shamir + responses (shared with the host prover)
         mem_proofs_flat: List[sigproof.MembershipProof] = []
@@ -306,16 +296,11 @@ _CACHE: List[Tuple[PublicParams, BatchedTransferProver]] = []
 _CACHE_CAP = 4
 
 
-def prover_for(pp: PublicParams, mesh=None) -> BatchedTransferProver:
+def prover_for(pp: PublicParams) -> BatchedTransferProver:
     for cached_pp, prover in _CACHE:
         if cached_pp is pp:
-            # the cache reuses TABLES; the mesh is per-caller dispatch
-            # state and re-binds on every hit (None = ambient/unsharded)
-            # so the host `TransferProver.batch` path can never inherit
-            # a mesh left over from a mesh-aware caller
-            prover.set_mesh(mesh)
             return prover
-    prover = BatchedTransferProver(pp, mesh=mesh)
+    prover = BatchedTransferProver(pp)
     _CACHE.append((pp, prover))
     if len(_CACHE) > _CACHE_CAP:
         _CACHE.pop(0)

@@ -11,8 +11,8 @@ existing stage tiles:
 
 i.e. fixed-base msm for `g^z` (the 1-base `g1_msm1_tile`, same program
 the membership verifier's `P^{z_bf}` term rides), variable-base
-`g1_mul` for `pk^c`, and the Jacobian sub tile — EXACTLY the composition
-`parallel/sharding.py:sharded_schnorr_rows` dispatches, so the plane
+`g1_mul` for `pk^c`, and the Jacobian sub tile — the composition
+`BatchedWFVerifier` runs for its sigma commitments, so the plane
 adds ZERO new XLA program shapes and the post-warmup zero-cache-miss
 guarantee extends to signatures. The Fiat-Shamir re-hash (challenge
 rebind per row) stays on host, like every other batched verifier.
@@ -34,14 +34,13 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import hostmath as hm, sign
-from .batch import _MeshBound, _spanned
+from .batch import _spanned
 from .serialization import loads
-from ..ops import curve as cv
-from ..parallel.sharding import sharded_schnorr_rows
+from ..ops import curve as cv, stages as st
 from ..utils import metrics as mx, resilience
 
 
-class BatchedSchnorrVerifier(_MeshBound):
+class BatchedSchnorrVerifier:
     """Verifies B long-term Schnorr signatures via the stage tiles.
 
     Rows are `(pk_point, message, sig_raw)` — the public-key POINT (from
@@ -52,8 +51,7 @@ class BatchedSchnorrVerifier(_MeshBound):
     block regardless of how many txs/records contributed obligations.
     """
 
-    def __init__(self, mesh=None):
-        self.set_mesh(mesh)
+    def __init__(self):
         # windowed multiples of the generator (process-wide lru cache —
         # every verifier shares one table build); the 1-base msm PROGRAM
         # shape already exists (warmup's g1_msm1_tile) — tables are
@@ -92,8 +90,9 @@ class BatchedSchnorrVerifier(_MeshBound):
         resp_np = cv.encode_scalars([parsed[i][1] for i in live])[:, None, :]
         chal_np = cv.encode_scalars([parsed[i][0] for i in live])
         pk_np = np.stack([cv.encode_point(rows[i][0]) for i in live])
-        coms = sharded_schnorr_rows(
-            self.table, resp_np, pk_np, chal_np, mesh=self.mesh
+        coms = st.g1_sub_rows(
+            st.g1_msm_rows(self.table.flat, resp_np),
+            st.g1_mul_rows(pk_np, chal_np),
         )
         com_pts = cv.decode_points(coms)
         # counted on COMPLETION only (PR-9 precedent): a device failure

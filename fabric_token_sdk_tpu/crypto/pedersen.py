@@ -35,20 +35,18 @@ class BatchedPedersen:
         self.bases = list(bases)
         self.table = cv.FixedBaseTable(self.bases)
 
-    def commit_rows(self, scalars: np.ndarray, dp=None) -> np.ndarray:
+    def commit_rows(self, scalars: np.ndarray) -> np.ndarray:
         """Canonical limb scalars (N, nbases, NLIMBS) -> (N, 3, NLIMBS)
-        Jacobian numpy, via the shape-invariant msm stage tile. `dp`
-        shards the tile dispatch (per-shard stage-tile dispatch — zero
-        new programs, bit-identical output)."""
-        return st.g1_msm_rows(self.table.flat, scalars, dp=dp)
+        Jacobian numpy, via the shape-invariant msm stage tile."""
+        return st.g1_msm_rows(self.table.flat, scalars)
 
-    def commit_ints(self, openings_rows: Sequence[Sequence[int]], dp=None):
+    def commit_ints(self, openings_rows: Sequence[Sequence[int]]):
         """Host int rows -> (host points, device Jacobian): one flat limb
         encode, one tiled msm pass, one host decode."""
         rows = list(openings_rows)
         flat = cv.encode_scalars([s for row in rows for s in row])
         jac = self.commit_rows(
-            flat.reshape(len(rows), len(self.bases), lb.NLIMBS), dp=dp
+            flat.reshape(len(rows), len(self.bases), lb.NLIMBS)
         )
         return cv.decode_points(jac), jac
 

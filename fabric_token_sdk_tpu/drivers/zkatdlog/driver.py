@@ -288,34 +288,24 @@ class ZKATDLogDriver(Driver):
         except Exception:
             return None
 
-    def batch_verifier(self, mesh=None):
+    def batch_verifier(self):
         """Cached `BatchedTransferVerifier` (imports the jax-backed ops
         stack lazily — constructing a driver must stay light). The cache
-        holds the expensive tables; `mesh` is re-bound on EVERY call —
-        including `mesh=None`, which unbinds back to the ambient
-        env/unsharded dispatch — so each caller (e.g. each block
-        pipeline sharing this driver) gets exactly the dp x mp dispatch
-        it configured, never a mesh left over from a previous caller."""
+        holds the expensive tables."""
         if self._batch_verifier is None:
             from ...crypto.batch import BatchedTransferVerifier
 
-            self._batch_verifier = BatchedTransferVerifier(self.pp, mesh=mesh)
-        else:
-            self._batch_verifier.set_mesh(mesh)
+            self._batch_verifier = BatchedTransferVerifier(self.pp)
         return self._batch_verifier
 
-    def batch_prover(self, mesh=None):
+    def batch_prover(self):
         """Cached `BatchedTransferProver` — the prove-side twin of
         `batch_verifier` (lazy import for the same reason; shares the
-        module-level `prover_for` cache with `TransferProver.batch`).
-        `mesh` re-binds on every call, `None` unbinds — same contract as
-        `batch_verifier`."""
+        module-level `prover_for` cache with `TransferProver.batch`)."""
         if self._batch_prover is None:
             from ...crypto.batch_prove import prover_for
 
-            self._batch_prover = prover_for(self.pp, mesh=mesh)
-        else:
-            self._batch_prover.set_mesh(mesh)
+            self._batch_prover = prover_for(self.pp)
         return self._batch_prover
 
     # ------------------------------------------------------------ tokens

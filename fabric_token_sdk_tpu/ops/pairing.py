@@ -293,30 +293,6 @@ def final_exp(f):
     return tw.fp12_mul(r01, r23)
 
 
-@jax.jit
-def pairing_product(Ps, Qs, inf_mask=None):
-    """prod_k e(P_k, Q_k) for each batch row.
-
-    Ps: (..., K, 2, L), Qs: (..., K, 2, 2, L), inf_mask: (..., K) bool —
-    True entries contribute the identity (point at infinity).
-    Returns GT elements (..., 6, 2, L).
-    """
-    f = miller_loop(Ps, Qs)  # (..., K, 6, 2, L)
-    if inf_mask is not None:
-        one = jnp.broadcast_to(tw.fp12_ones(), f.shape).astype(jnp.int32)
-        f = jnp.where(inf_mask[..., None, None, None], one, f)
-    # multiply the K miller values per row (tree)
-    k = f.shape[-4]
-    while k > 1:
-        half = k // 2
-        rest = f[..., 2 * half :, :, :, :]
-        f = tw.fp12_mul(f[..., :half, :, :, :], f[..., half : 2 * half, :, :, :])
-        if rest.shape[-4]:
-            f = jnp.concatenate([f, rest], axis=-4)
-        k = f.shape[-4]
-    return final_exp(f[..., 0, :, :, :])
-
-
 def gt_is_one(e):
     return tw.fp12_is_one(e)
 
@@ -334,11 +310,8 @@ def gt_is_one_host(arr) -> np.ndarray:
 
 # ------------------------------------------------- staged tiled execution
 #
-# `pairing_product` fuses miller + product + final-exp into ONE program per
-# caller shape; every verifier that inlines it pays a separate multi-minute
-# XLA compile of the same math. The staged path below splits the pipeline
-# into shape-stable tile programs compiled once and shared by every
-# verifier and batch size:
+# The pairing product runs as shape-stable tile programs compiled once
+# and shared by every verifier and batch size:
 #   * miller tile  — (tile_rows("miller_tile"), ...) pairs (1 program, ever)
 #   * row product  — (FEXP_TILE, K, ...) tree fp12 mul   (tiny, per K)
 #   * final-exp    — (FEXP_TILE, ...) GT rows            (1 program, ever)
@@ -405,31 +378,15 @@ def _fexp_tiles(frame, f, start: int, stop: int):
     return outs
 
 
-def _sharded_tiles(fn, ntiles: int, workers: int, *args):
-    """The dp x mp leg of the per-shard stage-tile dispatch: delegates
-    to `stages.run_tile_spans` (the one sharded span-dispatch mechanism,
-    degrade chain included) under the pairing-plane counters."""
-    return st.run_tile_spans(
-        fn, ntiles, workers, *args,
-        calls=mx.counter("pairing.staged.sharded_calls"),
-        shards=mx.counter("pairing.staged.shards"),
-        what="pairing.staged",
-    )
-
-
-def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
+def pairing_product_staged(Ps, Qs, inf_mask=None):
     """prod_k e(P_k, Q_k) per row via the compile-once tile programs.
 
     Ps: (B, K, 2, L), Qs: (B, K, 2, 2, L) Montgomery affine; inf_mask
     (B, K) True legs contribute the identity. Returns (B, 6, 2, L) GT as
     a host numpy array.
 
-    `dp` x `mp` (default: the ambient mesh env, `FTS_MESH_DEVICES` /
-    `FTS_MESH_MP`) shard the dispatch: the flat (row, leg) miller-tile
-    stream splits into dp*mp contiguous spans and the final-exp tile
-    stream into dp spans, each walked through the SAME tile executables
-    from worker threads — the host-dispatch expression of "dp over rows,
-    mp over pairing legs". Zero new XLA programs; bit-identical output.
+    Both tile walks run in order on the calling thread, one round trip
+    (enqueue, read back) per tile.
     """
     Ps = np.asarray(Ps)
     Qs = np.asarray(Qs)
@@ -437,8 +394,6 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
     L = Ps.shape[-1]
     if B == 0:
         return np.zeros((0, 6, 2, L), dtype=np.int32)
-    dp = st.default_dp() if dp is None else max(1, int(dp))
-    mp = st.default_mp() if mp is None else max(1, int(mp))
     N = B * K
     Pf = Ps.reshape(N, 2, L)
     Qf = Qs.reshape(N, 2, 2, L)
@@ -465,13 +420,9 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
         # per-shape concatenate/select programs on the accelerator
         with devobs.dispatch(
             "miller_tile", rows=N, padded_rows=pad, tiles=n_miller,
-            dp=dp, mp=mp,
         ) as frame:
             f = np.concatenate(
-                _sharded_tiles(
-                    _miller_tiles, n_miller, dp * mp, frame, Pf, Qf
-                ),
-                axis=0,
+                _miller_tiles(frame, Pf, Qf, 0, n_miller), axis=0
             )
         # numpy constant (not tw.fp12_ones()): keeps the mask/pad glue off
         # the device so no per-shape broadcast program ever compiles
@@ -488,9 +439,9 @@ def pairing_product_staged(Ps, Qs, inf_mask=None, dp=None, mp=None):
         n_fexp = (B + padB) // FEXP_TILE
         mx.counter("pairing.staged.fexp_tiles").inc(n_fexp)
         with devobs.dispatch(
-            "fexp_tile", rows=B, padded_rows=padB, tiles=n_fexp, dp=dp
+            "fexp_tile", rows=B, padded_rows=padB, tiles=n_fexp
         ) as frame:
-            gts = _sharded_tiles(_fexp_tiles, n_fexp, dp, frame, f)
+            gts = _fexp_tiles(frame, f, 0, n_fexp)
     return np.concatenate(gts, axis=0)[:B]
 
 

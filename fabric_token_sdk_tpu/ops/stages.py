@@ -38,8 +38,6 @@ persistent cache ahead of time.
 from __future__ import annotations
 
 import functools
-import os
-from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +48,7 @@ from . import curve as cv, curve2 as cv2, limbs as lb
 from .field import FP
 from ..utils import devobs
 from ..utils import metrics as mx
-from ..utils import resilience, sysmon
-from ..utils.tracing import logger
+from ..utils import sysmon
 
 # Tile height: every dispatch of a stage program sees exactly
 # `tile_rows(program)` flat rows (batches are flattened over (B, n) and
@@ -188,69 +185,6 @@ def _g2_add_tile(a, b):
 
 # ------------------------------------------------------------ tile runner
 
-_env_clamp_seen = None
-
-
-def mesh_env() -> tuple:
-    """(n_devices, mp) from the ambient mesh env (`FTS_MESH_DEVICES`,
-    `FTS_MESH_MP`). n_devices == 0 means no mesh is configured; mp is
-    clamped to the largest divisor of n_devices so a bad pairing never
-    knocks dispatch off the sharded path. A clamp counts under
-    `sharding.clamped` — once per distinct (n, mp) misconfiguration,
-    not per dispatch (this runs on every `run_rows` call)."""
-    global _env_clamp_seen
-    try:
-        n = int(os.environ.get("FTS_MESH_DEVICES", "0") or 0)
-    except ValueError:
-        n = 0
-    try:
-        mp = int(os.environ.get("FTS_MESH_MP", "1") or 1)
-    except ValueError:
-        mp = 1
-    mp = max(1, mp)
-    if n > 0:
-        want = mp
-        while n % mp:
-            mp -= 1
-        if mp != want and _env_clamp_seen != (n, want):
-            _env_clamp_seen = (n, want)
-            mx.counter("sharding.clamped").inc()
-            mx.counter("sharding.clamped.env").inc()
-            mx.flight(
-                "sharding.clamped", where="env", want=want, got=mp,
-                n_devices=n,
-            )
-            logger.warning(
-                "sharding: ambient mesh env clamped mp %d -> %d "
-                "(FTS_MESH_DEVICES=%d)", want, mp, n,
-            )
-    return max(0, n), mp
-
-
-def default_dp() -> int:
-    """Data-parallel shard count for the stage runner: FTS_DP_SHARDS
-    when set, else the dp extent of the ambient mesh env
-    (`FTS_MESH_DEVICES` // `FTS_MESH_MP`), else 1 = unsharded. Both the
-    batched verify plane (`crypto/batch.py`) and the batched prover
-    (`crypto/batch_prove.py`) flow through `run_rows`, so one knob
-    shards both."""
-    v = os.environ.get("FTS_DP_SHARDS")
-    if v:
-        try:
-            return max(1, int(v))
-        except ValueError:
-            return 1
-    n, mp = mesh_env()
-    return max(1, n // mp) if n > 0 else 1
-
-
-def default_mp() -> int:
-    """Model-parallel worker count of the staged pairing product (legs
-    axis), from the ambient mesh env; 1 = unsharded."""
-    n, mp = mesh_env()
-    return mp if n > 0 else 1
-
-
 def _run_span(frame, kernel, consts, arrays, rows, start, stop):
     """Sequentially ENQUEUE the tile kernel over the `rows`-high slabs
     [start, stop) (JAX dispatch is asynchronous: nothing here waits for
@@ -263,79 +197,6 @@ def _run_span(frame, kernel, consts, arrays, rows, start, stop):
                 *consts, *(jnp.asarray(a[t : t + rows]) for a in arrays)
             ))
     return outs
-
-
-def run_tile_spans(fn, ntiles: int, workers: int, *args, calls, shards,
-                   what="stages"):
-    """The ONE sharded span-dispatch mechanism: `fn(*args, start, stop)`
-    over contiguous tile-index spans from worker threads — ridden by
-    both the row runner (`run_rows`) and the staged pairing product
-    (`ops/pairing.py`). Outputs come back in span order, so the
-    concatenated result is bit-identical to one sequential
-    `fn(*args, 0, ntiles)` walk.
-
-    Degrade chain, first link: any dispatch failure (thread-pool
-    exhaustion, a worker crash) falls back to the sequential walk
-    (`sharding.fallbacks`) — same executables, same results; the
-    verifier/pipeline host fallback remains the second link, so
-    accept/reject can never depend on sharding. `calls`/`shards` are
-    incremented on COMPLETION only: a degraded dispatch must never
-    report as sharded (tests and the observatory both read these as
-    "the sharded path actually ran").
-
-    The `stages` circuit breaker (utils/resilience.py) guards this
-    seam: repeated dispatch failures OPEN it and later calls skip
-    straight to the sequential walk (no thread pool spun up, no
-    re-failure paid) until a half-open probe heals it — the plane
-    degrades AND recovers without operator action."""
-    if workers <= 1 or ntiles <= 1:
-        return fn(*args, 0, ntiles)
-    brk = resilience.breaker("stages")
-    if not brk.allow():
-        # breaker-open skip: the open/close TRANSITIONS are already
-        # reasoned `breaker` flight events (utils/resilience.py); here
-        # we only count the skipped dispatches and charge the degrade
-        # to the active program's ledger entry
-        mx.counter("sharding.breaker_skips").inc()
-        devobs.note_degrade("breaker_open")
-        return fn(*args, 0, ntiles)
-    try:
-        spans = dp_spans(ntiles, workers)
-        with ThreadPoolExecutor(max_workers=len(spans)) as pool:
-            futs = [pool.submit(fn, *args, a, b) for a, b in spans]
-            outs = [o for f in futs for o in f.result()]
-        calls.inc()
-        shards.inc(len(spans))
-        brk.record_success()
-        return outs
-    except Exception as e:
-        brk.record_failure()
-        mx.counter("sharding.fallbacks").inc()
-        mx.flight(
-            "sharding.fallback", what=what, workers=workers,
-            reason="dispatch_error", error=type(e).__name__,
-            program=devobs.current_program(),
-        )
-        devobs.note_degrade("dispatch_error")
-        logger.exception(
-            "%s: sharded dispatch failed (workers=%d); re-running "
-            "unsharded", what, workers,
-        )
-        return fn(*args, 0, ntiles)
-
-
-def dp_spans(ntiles: int, dp: int):
-    """Split `ntiles` tile slabs into at most `dp` contiguous,
-    tile-aligned (start_tile, stop_tile) spans — the row partition of the
-    per-shard stage-tile dispatch (`parallel/sharding.py`)."""
-    dp = max(1, min(dp, ntiles))
-    per, extra = divmod(ntiles, dp)
-    spans, at = [], 0
-    for s in range(dp):
-        n = per + (1 if s < extra else 0)
-        spans.append((at, at + n))
-        at += n
-    return spans
 
 
 _PROGRAM_NAMES = None
@@ -360,7 +221,7 @@ def _program_of(kernel, arrays) -> str:
     )
 
 
-def run_rows(kernel, *arrays, consts=(), dp=None):
+def run_rows(kernel, *arrays, consts=()):
     """Run `kernel(*consts, *tiles)` over `tile_rows(program)`-high
     slabs of flat-row numpy arrays -> numpy. The staged successor of
     the old `crypto.batch._run_tiled`.
@@ -374,12 +235,10 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
       host-side copy at most, only when padding is needed); the only
       host->device transfers are the per-tile `jnp.asarray` calls,
       counted in `batch.tiled.transfers`.
-    * `dp` > 1 (default `FTS_DP_SHARDS`) splits the tile range into
-      contiguous spans dispatched from worker threads — same executable,
-      same results, overlapping host glue with device work. Device
-      placement is intentionally NOT pinned per shard: per-device
-      executables have distinct compile-cache keys, which would break
-      the compile-once/warm-cache guarantees (see ARCHITECTURE.md).
+    * One walk, on the calling thread: every tile is enqueued in order
+      (JAX dispatch is asynchronous), then every result is read back.
+      A tile lands on the default device; the per-tile `jnp.asarray`
+      in `_run_span` is the one line where a row slab meets a device.
     """
     N = arrays[0].shape[0]
     if N == 0:
@@ -388,14 +247,13 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
     rows = tile_rows(program)
     pad = (-N) % rows
     ntiles = (N + pad) // rows
-    dp = default_dp() if dp is None else max(1, dp)
     # ONE timer per dispatch: the ledger frame (utils/devobs.py), from
     # the padding until the last tile's result is back on the host. It
     # names the canonical program, splits its wall into enqueue and
     # read-back time, and is the per-kernel span a critical-path trace
     # (cmd/ftstrace.py) renders under the block's device verify.
     with devobs.dispatch(
-        program, rows=N, padded_rows=pad, tiles=ntiles, dp=dp,
+        program, rows=N, padded_rows=pad, tiles=ntiles,
     ) as frame:
         if pad:
             padded = []
@@ -412,12 +270,7 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
         mx.counter("stages.tiles").inc(ntiles)
         mx.counter("batch.tiled.transfers").inc(ntiles * len(arrays))
         # every tile is enqueued before the first read-back below
-        outs = run_tile_spans(
-            lambda a, b: _run_span(frame, kernel, consts, arrays, rows, a, b),
-            ntiles, dp,
-            calls=mx.counter("stages.sharded_calls"),
-            shards=mx.counter("stages.shards"),
-        )
+        outs = _run_span(frame, kernel, consts, arrays, rows, 0, ntiles)
         # device/host memory high-water of the data plane (throttled;
         # never compiles anything — see utils/sysmon.py), sampled while
         # the device works on the tiles
@@ -446,42 +299,42 @@ def run_rows(kernel, *arrays, consts=(), dp=None):
 # takes/returns HOST numpy (flat rows); `consts` device residency is the
 # caller's choice (jnp tables stay resident, numpy is transferred).
 
-def g1_msm_rows(table_flat, scalars: np.ndarray, dp=None) -> np.ndarray:
+def g1_msm_rows(table_flat, scalars: np.ndarray) -> np.ndarray:
     """(N, nbases, L) canonical scalars x fixed-base table -> (N, 3, L)."""
-    return run_rows(_g1_msm_tile, scalars, consts=(table_flat,), dp=dp)
+    return run_rows(_g1_msm_tile, scalars, consts=(table_flat,))
 
 
-def g1_mul_rows(points: np.ndarray, scalars: np.ndarray, dp=None) -> np.ndarray:
+def g1_mul_rows(points: np.ndarray, scalars: np.ndarray) -> np.ndarray:
     """Variable-base scalar mul: (N, 3, L) x (N, L) -> (N, 3, L)."""
-    return run_rows(_g1_mul_tile, points, scalars, dp=dp)
+    return run_rows(_g1_mul_tile, points, scalars)
 
 
-def g1_add_rows(a: np.ndarray, b: np.ndarray, dp=None) -> np.ndarray:
-    return run_rows(_g1_add_tile, a, b, dp=dp)
+def g1_add_rows(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    return run_rows(_g1_add_tile, a, b)
 
 
-def g1_sub_rows(a: np.ndarray, b: np.ndarray, dp=None) -> np.ndarray:
-    return run_rows(_g1_sub_tile, a, b, dp=dp)
+def g1_sub_rows(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    return run_rows(_g1_sub_tile, a, b)
 
 
-def g1_to_affine_rows(p: np.ndarray, dp=None) -> np.ndarray:
-    return run_rows(_g1_to_affine_tile, p, dp=dp)
+def g1_to_affine_rows(p: np.ndarray) -> np.ndarray:
+    return run_rows(_g1_to_affine_tile, p)
 
 
-def g2_mul_rows(points: np.ndarray, scalars: np.ndarray, dp=None) -> np.ndarray:
+def g2_mul_rows(points: np.ndarray, scalars: np.ndarray) -> np.ndarray:
     """(N, 3, 2, L) x (N, L) -> (N, 3, 2, L)."""
-    return run_rows(_g2_mul_tile, points, scalars, dp=dp)
+    return run_rows(_g2_mul_tile, points, scalars)
 
 
-def g2_add_rows(a: np.ndarray, b: np.ndarray, dp=None) -> np.ndarray:
-    return run_rows(_g2_add_tile, a, b, dp=dp)
+def g2_add_rows(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    return run_rows(_g2_add_tile, a, b)
 
 
-def g2_to_affine_rows(p: np.ndarray, dp=None) -> np.ndarray:
-    return run_rows(_g2_to_affine_tile, p, dp=dp)
+def g2_to_affine_rows(p: np.ndarray) -> np.ndarray:
+    return run_rows(_g2_to_affine_tile, p)
 
 
-def g2_tree_sum_rows(terms: np.ndarray, dp=None) -> np.ndarray:
+def g2_tree_sum_rows(terms: np.ndarray) -> np.ndarray:
     """Per-row sum of k G2 terms: (N, k, 3, 2, L) -> (N, 3, 2, L).
 
     Host-side log-depth fold — each level is ONE tiled add over the
@@ -493,7 +346,7 @@ def g2_tree_sum_rows(terms: np.ndarray, dp=None) -> np.ndarray:
         rest = terms[:, 2 * half :]
         flat_a = terms[:, :half].reshape((-1,) + terms.shape[2:])
         flat_b = terms[:, half : 2 * half].reshape((-1,) + terms.shape[2:])
-        summed = g2_add_rows(flat_a, flat_b, dp=dp).reshape(
+        summed = g2_add_rows(flat_a, flat_b).reshape(
             (terms.shape[0], half) + terms.shape[2:]
         )
         terms = np.concatenate([summed, rest], axis=1) if rest.shape[1] else summed

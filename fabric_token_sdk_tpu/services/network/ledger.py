@@ -122,8 +122,7 @@ class Network:
     def __init__(self, validator: RequestValidator,
                  policy: Optional[BlockPolicy] = None,
                  wal_path: Optional[str] = None,
-                 snapshot_every: Optional[int] = None,
-                 mesh=None):
+                 snapshot_every: Optional[int] = None):
         self.validator = validator
         self.policy = policy or BlockPolicy.from_env()
         self._state: Dict[str, bytes] = {}  # token key -> output bytes
@@ -132,11 +131,7 @@ class Network:
         self._status: Dict[str, FinalityEvent] = {}
         self._listeners: List[Callable[[FinalityEvent, TokenRequest], None]] = []
         self._lock = threading.Lock()
-        # `mesh` (parallel.sharding.MeshConfig) shards the block-batched
-        # proof plane's dispatch over dp x mp; None = ambient env
-        # (FTS_MESH_DEVICES / FTS_DP_SHARDS), resolved in the runners
-        self._pipeline = BlockValidationPipeline(validator, self.policy,
-                                                 mesh=mesh)
+        self._pipeline = BlockValidationPipeline(validator, self.policy)
         self._orderer = Orderer(self._commit_block, self.policy)
         # pipelined block engine: overlap block N+1's batched device
         # verify with block N's host-validate/WAL/merge (pipeline.py).

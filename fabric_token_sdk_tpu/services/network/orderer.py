@@ -428,13 +428,8 @@ class BlockValidationPipeline:
     host-verified; any device error degrades every row back to the host
     loop (`batch.sign.host_fallbacks`).
 
-    `mesh` (a `parallel.sharding.MeshConfig`, default: the ambient
-    `FTS_MESH_DEVICES`/`FTS_MESH_MP` env via the verifier's own
-    resolution) shards each group's stage-tile composition over dp and
-    its pairing products over dp x mp — the per-shard stage-tile
-    dispatch. The degrade chain is sharded -> unsharded (inside the
-    runners, `sharding.fallbacks`) -> host (here, `ledger.block.
-    batch_errors`): accept/reject never depends on the mesh.
+    The degrade chain is device -> host (here, `ledger.block.
+    batch_errors`): accept/reject never depends on where a proof ran.
 
     Resilience (utils/resilience.py): each device dispatch runs under
     `bounded_call` with the plane's `FTS_DEVICE_DEADLINE_S` wall budget
@@ -445,11 +440,9 @@ class BlockValidationPipeline:
     probe after cooldown re-engages the device plane by itself.
     """
 
-    def __init__(self, validator: RequestValidator, policy: BlockPolicy,
-                 mesh=None):
+    def __init__(self, validator: RequestValidator, policy: BlockPolicy):
         self.validator = validator
         self.policy = policy
-        self.mesh = mesh
         # batched signature plane state: the verifier is built lazily on
         # first use (jax import); `sign_batched=None` (auto) resolves
         # once against the live backend. A construction failure records
@@ -540,12 +533,7 @@ class BlockValidationPipeline:
                 continue
             if verifier is None:
                 try:
-                    try:
-                        verifier = driver.batch_verifier(mesh=self.mesh)
-                    except TypeError:
-                        # SPI compat: a custom driver predating the mesh
-                        # kwarg still serves the unsharded plane
-                        verifier = driver.batch_verifier()
+                    verifier = driver.batch_verifier()
                 except Exception:
                     # construction failures (device stack unavailable,
                     # OOM building tables) degrade to host validation,
@@ -807,7 +795,7 @@ class BlockValidationPipeline:
             try:
                 from ...crypto.batch_sign import BatchedSchnorrVerifier
 
-                self._sign_verifier = BatchedSchnorrVerifier(mesh=self.mesh)
+                self._sign_verifier = BatchedSchnorrVerifier()
             except Exception:
                 # one strike, like the latch this breaker replaced: a
                 # construction failure is structural (import/OOM) and

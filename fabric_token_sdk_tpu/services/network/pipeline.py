@@ -30,7 +30,7 @@ in `tests/test_pipeline.py`):
 * **Height order** — stage B is a single consumer of a FIFO queue fed
   under `stage_lock`; merges happen in exactly cut order.
 * **Degrade chain** — stage A is `BlockValidationPipeline.proof_verdicts`
-  unchanged: sharded -> unsharded -> host per block, with each device
+  unchanged: device -> host per block, with each device
   dispatch bounded by the plane's `FTS_DEVICE_DEADLINE_S` wall budget
   and guarded by its circuit breaker (utils/resilience.py) — a hung
   XLA call is abandoned at the deadline inside stage A itself, so it

@@ -10,10 +10,10 @@ those numbers are recorded. Every device entry point
 them the batched verifiers/signer/prover) opens a `dispatch(...)`
 frame naming the canonical XLA program it is about to run. A frame
 lasts until the program's results are back on the host and is the ONLY
-timer at that boundary: it records requested vs padded rows, dp/mp
-placement, wall time and how that wall divides into `stage_s` (the host
-preparing and enqueueing tiles) and `wait_s` (the host blocked on a
-read-back), and feeds the metrics registry:
+timer at that boundary: it records requested vs padded rows, wall time
+and how that wall divides into `stage_s` (the host preparing and
+enqueueing tiles) and `wait_s` (the host blocked on a read-back), and
+feeds the metrics registry:
 
   * ``device.dispatch.seconds``            — all dispatches, one histogram
   * ``device.dispatch.<program>.seconds``  — per-program wall time
@@ -39,8 +39,7 @@ Frames are thread-local, so the `jax.monitoring` compile/cache
 listeners (ops/__init__) can attribute backend compile wall time and
 persistent-cache hits to the program that triggered them — the join
 between XLA's anonymous compile events and `stages.stage_programs()`.
-Degrade decisions (breaker-open skips, dispatch-error fallbacks,
-fused-pairing shape bailouts) land in the same per-program ledger via
+Degrade decisions land in the same per-program ledger via
 `note_degrade`, so "this program ran slow because it ran on the host"
 is visible next to its occupancy.
 
@@ -94,8 +93,8 @@ _lock = threading.Lock()
 _programs: Dict[Tuple[str, str], dict] = {}
 # plane -> aggregate of its plane spans (see `plane_snapshot`)
 _planes: Dict[str, dict] = {}
-# best-effort fallback for compile events fired on sharding worker
-# threads (the dispatch frame lives on the caller's thread)
+# best-effort fallback for compile events fired on a thread that has
+# no frame open (the dispatch frame lives on the caller's thread)
 _last_frame: Optional[Tuple[str, str]] = None
 
 _NULL = contextlib.nullcontext()
@@ -142,8 +141,6 @@ def _entry(frame: Tuple[str, str]) -> dict:
             "wall_s": 0.0,
             "stage_s": 0.0,
             "wait_s": 0.0,
-            "dp": 1,
-            "mp": 1,
             "compiles": 0,
             "compile_s": 0.0,
             "cache_hits": 0,
@@ -264,8 +261,7 @@ class _Frame:
     def __init__(self, pl: str, program: str):
         self.tile_name = f"fts:{pl}:{program}"
         self.wait_name = f"fts:wait:{pl}:{program}"
-        # seconds of every read-back; appended from whichever thread
-        # walks the tiles (list.append is atomic)
+        # seconds of every read-back
         self.waits: list = []
 
     def tile(self):
@@ -297,25 +293,20 @@ def dispatch(
     rows: int,
     padded_rows: int = 0,
     tiles: int = 0,
-    dp: int = 1,
-    mp: int = 1,
     plane: Optional[str] = None,
 ):
     """Record one device dispatch of `program`, from the first byte of
     host preparation until its results are on the host: requested vs
     padded rows, the height of its tiles (`tile_rows` = dispatched rows
     / `tiles`, of the newest dispatch: it says which shape of the
-    program ran), dp/mp placement, and `wall_s = stage_s + wait_s`.
+    program ran), and `wall_s = stage_s + wait_s`.
 
     `wait_s` is the host blocked on a device result — what the caller
     wrapped in `frame.wait()`; `stage_s` is the rest of the frame — the
     host padding, transferring, enqueueing and reassembling. Where each
     tile is enqueue-then-read-back (the pairing walks) both are summed
-    over the tiles. Walked from worker threads (`dp * mp > 1`) the
-    read-backs of different threads overlap: their seconds are summed
-    over the threads and capped at the frame's wall, so `wait_s` is
-    thread-time and `stage_s` what is left of the caller's wall; with
-    `dp = mp = 1` (the chip cells) both are exact.
+    over the tiles. A frame is walked on the thread that opened it, so
+    the read-backs never overlap and both are exact.
 
     When span recording is on (`FTS_METRICS=1`) the frame records
     itself as the `device.dispatch` span — no second timer. Yields the
@@ -336,7 +327,7 @@ def dispatch(
     finally:
         t1 = time.monotonic()
         wall = t1 - t0
-        wait = min(sum(fr.waits), wall)
+        wait = sum(fr.waits)
         total = rows + padded_rows
         height = total // tiles if tiles else 0
         _tl.frame = prev
@@ -350,8 +341,6 @@ def dispatch(
             e["wall_s"] += wall
             e["stage_s"] += wall - wait
             e["wait_s"] += wait
-            e["dp"] = dp
-            e["mp"] = mp
         span = getattr(_tl, "span", None)
         if span is not None:
             span[0] += wall
@@ -420,9 +409,8 @@ def note_degrade(
     program: Optional[str] = None,
     plane: Optional[str] = None,
 ) -> None:
-    """Record a degrade decision (breaker-open skip, dispatch-error
-    fallback, fused-pairing shape bailout) against the active — or
-    explicitly named — program."""
+    """Record a degrade decision against the active — or explicitly
+    named — program."""
     if not enabled():
         return
     if program is not None:
@@ -498,8 +486,6 @@ def health_section() -> dict:
             "wait_s": round(e["wait_s"], 6),
             "p50_s": round(p50, 6) if p50 is not None else None,
             "p99_s": round(p99, 6) if p99 is not None else None,
-            "dp": e["dp"],
-            "mp": e["mp"],
             "compiles": e["compiles"],
             "compile_s": round(e["compile_s"], 3),
             "cache_hits": e["cache_hits"],

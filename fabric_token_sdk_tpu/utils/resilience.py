@@ -1,11 +1,9 @@
 """Resilience layer: bounded device dispatch + per-plane circuit breakers.
 
-The degrade chains built so far (sharded -> unsharded -> host, device ->
-host) only handle device calls that *fail fast*: an exception falls
-through to the host path and the block commits with identical verdicts.
-A call that HANGS — a wedged device backend, which bench/multichip guard
-with deadline watchdogs but the product commit path did not — blocks the
-commit worker forever.
+The degrade chains built so far (device -> host) only handle device
+calls that *fail fast*: an exception falls through to the host path and
+the block commits with identical verdicts. A call that HANGS — a wedged
+device backend — blocks the commit worker forever.
 This module closes that gap with the two primitives every serving stack
 pairs:
 
@@ -43,8 +41,6 @@ Planes wired (one breaker each, registered lazily by name):
               permanent construction-failure latch: a transient OOM now
               heals via the half-open probe)
     prove   — `TransferProver.batch` group routing
-    stages  — `stages.run_tile_spans` sharded dispatch (breaker only:
-              an open breaker skips straight to the sequential walk)
 
 Deadlines resolve per plane via ``device_deadline_s(plane)``:
 ``FTS_DEVICE_DEADLINE_<PLANE>_S`` wins, else ``FTS_DEVICE_DEADLINE_S``,
@@ -52,8 +48,8 @@ else the default — commit-path planes (verify/sign) are bounded at
 ``ACCEL_DEADLINE_S`` (120s) when the live jax backend is a real
 accelerator and UNBOUNDED on the CPU-emulated plane (where a legitimate
 cold compile or big-block verify takes minutes and a tight default would
-open the breaker against a healthy backend); client-side planes
-(prove/stages) default unbounded. ``0`` always means unbounded, and an
+open the breaker against a healthy backend); the client-side plane
+(prove) defaults unbounded. ``0`` always means unbounded, and an
 unbounded call runs inline (no supervisor thread).
 
 Observability: counters ``resilience.breaker.{open,close,probe,
@@ -317,7 +313,7 @@ def device_deadline_s(plane: str) -> float:
     on a real accelerator and unbounded on the CPU-emulated plane —
     there a cold compile or big-block verify legitimately takes minutes,
     and a tight default would open the breaker against a healthy
-    backend. Client-side planes (prove/stages) default unbounded."""
+    backend. The client-side plane (prove) defaults unbounded."""
     v = os.environ.get(f"FTS_DEVICE_DEADLINE_{plane.upper()}_S")
     if v is None:
         v = os.environ.get("FTS_DEVICE_DEADLINE_S")

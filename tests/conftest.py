@@ -1,8 +1,7 @@
-"""Test configuration: force JAX onto a virtual 8-device CPU platform.
+"""Test configuration: force JAX onto the CPU platform.
 
-Multi-chip TPU hardware is unavailable in CI; all sharding tests run on a
-virtual CPU mesh (`--xla_force_host_platform_device_count=8`). Kernels are
-written for TPU; CPU execution exercises identical XLA programs.
+Kernels are written for TPU; CPU execution exercises identical XLA
+programs.
 """
 import os
 import sys
@@ -12,9 +11,12 @@ import sys
 # importable from any pytest invocation directory.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# Tests always run on the CPU with an 8-device virtual mesh (the sharding
-# tests need one). Both must be in the environment before the first jax
-# import; an XLA_FLAGS device count the caller already set is kept.
+# Tests always run on the CPU, with 8 virtual devices. No test needs a
+# second device any more (a named debt, ROADMAP.md): XLA's options are
+# part of the compile-cache key, so dropping the flag would make the
+# next tier-1 run recompile every tile. Both must be in the environment
+# before the first jax import; an XLA_FLAGS device count the caller
+# already set is kept.
 os.environ["JAX_PLATFORMS"] = "cpu"
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (

@@ -8,6 +8,7 @@ passthrough (no ledger state, no registry writes, no threads), and on
 or off the accept/reject verdicts of an identical workload must not
 change.
 """
+import functools
 import os
 import random
 import threading
@@ -19,7 +20,7 @@ import pytest
 from fabric_token_sdk_tpu.api.validator import RequestValidator
 from fabric_token_sdk_tpu.crypto import hostmath as hm
 from fabric_token_sdk_tpu.drivers.fabtoken import FabTokenDriver, FabTokenPublicParams
-from fabric_token_sdk_tpu.ops import curve as cv, stages as st
+from fabric_token_sdk_tpu.ops import curve as cv, pairing as pr, stages as st
 from fabric_token_sdk_tpu.services.network import BlockPolicy, Network, TxStatus
 from fabric_token_sdk_tpu.services.ttx import Party, Transaction
 from fabric_token_sdk_tpu.utils import benchschema, devobs
@@ -77,16 +78,16 @@ def test_off_is_passthrough(monkeypatch):
 # ===================================================================
 
 
-def test_dispatch_records_occupancy_waste_and_placement():
+def test_dispatch_records_occupancy_and_waste():
     agg_before = _hist_count("device.dispatch.seconds")
     with devobs.plane("verify"):
-        with devobs.dispatch("ledger_prog", rows=5, padded_rows=3, dp=2):
+        with devobs.dispatch("ledger_prog", rows=5, padded_rows=3):
             pass
     snap = devobs.snapshot()
     assert set(snap) == {("verify", "ledger_prog")}
     e = snap[("verify", "ledger_prog")]
     assert e["dispatches"] == 1
-    assert (e["rows"], e["padded_rows"], e["dp"], e["mp"]) == (5, 3, 2, 1)
+    assert (e["rows"], e["padded_rows"]) == (5, 3)
     assert e["wall_s"] >= 0
 
     h = devobs.health_section()
@@ -115,8 +116,8 @@ def test_compile_and_cache_attribution():
         devobs.note_cache("/jax/compilation_cache/cache_hits")
         devobs.note_cache("/jax/compilation_cache/cache_misses")
         assert devobs.current_program() == "attr_prog"
-    # the frame outlives the block as the process-wide fallback (compiles
-    # fired on sharding worker threads land on the last program)
+    # the frame outlives the block as the process-wide fallback (a
+    # compile fired with no frame open lands on the last program)
     devobs.note_compile(0.25)
     e = devobs.snapshot()[(devobs.DEFAULT_PLANE, "attr_prog")]
     assert e["compiles"] == 2
@@ -174,31 +175,6 @@ def test_msm_dispatch_ledgered_with_canonical_program_name():
     prog = devobs.health_section()["programs"]["stages:g1_msm1_tile"]
     assert prog["occupancy"] == pytest.approx(5 / (5 + (-5) % T))
     assert prog["tile_rows"] == T
-
-
-# ===================================================================
-# clamp-site attribution (satellite: _clamp_mp no longer drops `where`)
-# ===================================================================
-
-
-def test_clamp_site_is_attributed():
-    from fabric_token_sdk_tpu.parallel import sharding
-
-    before = mx.REGISTRY.snapshot().get("counters", {})
-    cfg = sharding.MeshConfig.build(6, 4)
-    assert cfg.mp == 3  # largest divisor of 6 that fits
-    after = mx.REGISTRY.snapshot()["counters"]
-
-    def delta(name):
-        return after.get(name, 0) - before.get(name, 0)
-
-    # the aggregate stays (tests/test_parallel.py pins its delta), the
-    # site now rides a per-site counter AND a reasoned flight event
-    assert delta("sharding.clamped") == 1
-    assert delta("sharding.clamped.meshconfig") == 1
-    evt = [e for e in mx.FLIGHT.tail(50) if e["kind"] == "sharding.clamped"][-1]
-    assert evt["where"] == "MeshConfig"
-    assert (evt["want"], evt["got"], evt["n_devices"]) == (4, 3, 6)
 
 
 # ===================================================================
@@ -367,17 +343,6 @@ def test_plane_span_splits_into_stage_wait_and_glue():
     assert benchschema.validate_device(devobs.section()) == []
 
 
-def test_worker_thread_walk_keeps_the_identity():
-    """dp > 1: tiles are enqueued from worker threads, read back on the
-    caller's; `wall_s = stage_s + wait_s` still holds."""
-    rows = np.arange(32, dtype=np.int32).reshape(32, 1)
-    out = st.run_rows(_slow_tile(0.01), rows, dp=2)
-    assert (out == rows + 1).all()
-    e = devobs.snapshot()[("stages", "slow_tile")]
-    assert e["wait_s"] >= 0.04
-    assert e["wall_s"] == pytest.approx(e["stage_s"] + e["wait_s"])
-
-
 def test_frame_records_itself_as_the_span():
     """With span recording on, the frame is the `device.dispatch` span
     under the caller's open span (same start and end, no second timer,
@@ -398,6 +363,106 @@ def test_frame_records_itself_as_the_span():
         assert _hist_count("device.dispatch.seconds") == agg + 1
     finally:
         mx.enable(was)
+
+
+# ===================================================================
+# every dispatch goes through one of the two seams the benchmark wraps
+# ===================================================================
+
+
+def _toy_pairing(monkeypatch):
+    """The row-wise stand-ins of tests/test_pairing_tiles.py for the
+    three pairing tile programs: the walk, its frames and its padding
+    are the real ones, and a tile takes milliseconds, not seconds."""
+    import test_pairing_tiles as toys
+
+    monkeypatch.setattr(pr, "miller_loop", toys._toy_miller)
+    monkeypatch.setattr(pr, "_product_rows", toys._toy_product)
+    monkeypatch.setattr(pr, "final_exp", toys._toy_final_exp)
+
+
+def _frames():
+    return sum(e["dispatches"] for e in devobs.snapshot().values())
+
+
+def _wrap_the_seams(monkeypatch):
+    """Wrap `stages.run_rows` and `pairing.pairing_product_staged` on
+    their modules, as `benchmark/run.py:install_spans` does; each
+    wrapper counts its calls and the frames the ledger gained inside."""
+    seen = {"frames": 0}
+
+    def wrap(owner, attr):
+        inner = getattr(owner, attr)
+
+        @functools.wraps(inner)
+        def wrapped(*a, **kw):
+            before = _frames()
+            try:
+                return inner(*a, **kw)
+            finally:
+                seen["frames"] += _frames() - before
+                seen[attr] = seen.get(attr, 0) + 1
+
+        monkeypatch.setattr(owner, attr, wrapped)
+
+    wrap(st, "run_rows")
+    wrap(pr, "pairing_product_staged")
+    return seen
+
+
+def _ps_batch(rng):
+    from fabric_token_sdk_tpu.crypto import batch, pssign
+
+    signer = pssign.keygen(1, rng)
+    msgs = [[3], [1]]
+    sigs = [signer.sign(m, rng) for m in msgs]
+    return lambda: batch.BatchedPSVerifier(signer.pk, signer.Q).verify(
+        msgs, sigs)
+
+
+def _schnorr_batch(rng):
+    from fabric_token_sdk_tpu.crypto import sign
+    from fabric_token_sdk_tpu.crypto.batch_sign import BatchedSchnorrVerifier
+
+    keys = [sign.keygen(rng) for _ in range(2)]
+    rows = [(k.public.point, b"pay", k.sign(b"pay", rng)) for k in keys]
+    return lambda: BatchedSchnorrVerifier().verify(rows)
+
+
+def _prove_batch(rng):
+    from fabric_token_sdk_tpu.crypto import token as tok, transfer as tr
+    from fabric_token_sdk_tpu.crypto.setup import setup
+
+    pp = setup(base=4, exponent=2, rng=random.Random(0xF75))
+    in_toks, in_w = tok.tokens_with_witness([5, 10], "USD", pp.ped_params, rng)
+    out_toks, out_w = tok.tokens_with_witness([7, 8], "USD", pp.ped_params, rng)
+    reqs = [(in_w, out_w, in_toks, out_toks)]
+    return lambda: tr.TransferProver.batch(reqs, pp, rng=rng, min_batch=1)
+
+
+@pytest.mark.parametrize("plane,batch_of", [
+    ("verify", _ps_batch), ("sign", _schnorr_batch), ("prove", _prove_batch),
+])
+def test_no_dispatch_goes_round_the_two_seams(monkeypatch, rng, plane,
+                                              batch_of):
+    """The benchmark builds its trace breakdown from wrappers it puts on
+    `stages.run_rows` and `pairing.pairing_product_staged` at run time:
+    every frame a plane's ledger gains must be opened inside one of the
+    two, reached through the module attribute."""
+    _toy_pairing(monkeypatch)
+    run = batch_of(rng)
+    seen = _wrap_the_seams(monkeypatch)
+    fallbacks = _counters("batch.prove.host_fallbacks", "batch.prove.host")
+    run()
+    assert fallbacks == _counters("batch.prove.host_fallbacks",
+                                  "batch.prove.host")
+    assert {pl for pl, _prog in devobs.snapshot()} == {plane}
+    assert seen["frames"] == _frames() > 0
+    assert seen["run_rows"] > 0
+    if plane != "sign":  # Schnorr rows have no pairing
+        assert seen["pairing_product_staged"] > 0
+        assert {"miller_tile", "fexp_tile"} <= {
+            prog for _pl, prog in devobs.snapshot()}
 
 
 # ===================================================================
@@ -469,7 +534,7 @@ class _StubDriver:
     def transfer_batch_plan(self, action):
         return ((1, 1), (action,))
 
-    def batch_verifier(self, mesh=None):
+    def batch_verifier(self):
         return _StubVerifier()
 
 

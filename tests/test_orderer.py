@@ -309,53 +309,6 @@ def test_zk_mixed_block_host_and_batched(zk_pp):
     assert alice_p.balance("USD") == 4  # 2 change + 2 fresh issue
 
 
-def test_zk_block_through_sharded_pipeline(zk_pp):
-    """Virtual-device smoke (satellite acceptance): one batched zk block
-    commits through the mesh-sharded pipeline — `Network(mesh=...)` on
-    the 8-virtual-device plane routes every same-shape group's
-    stage-tile composition through the dp x mp per-shard dispatch, with
-    identical verdicts and per-tx finality."""
-    import jax
-
-    from fabric_token_sdk_tpu.parallel import MeshConfig
-
-    assert len(jax.devices()) == 8  # ensure_virtual_devices(8) in conftest
-    pp = zk_pp
-    network = Network(
-        RequestValidator(ZKATDLogDriver(pp)),
-        policy=BlockPolicy(max_block_txs=8, min_batch=2),
-        mesh=MeshConfig.build(8, 2),
-    )
-    parties = {
-        name: Party(name, ZKATDLogDriver(pp), network)
-        for name in ("issuer-node", "alice-node", "bob-node")
-    }
-    issuer = parties["issuer-node"].new_issuer_wallet("issuer")
-    alice = parties["alice-node"].new_owner_wallet("alice", anonymous=False)
-    bob = parties["bob-node"].new_owner_wallet("bob", anonymous=False)
-    pp.add_issuer(issuer.identity)
-    issue_to(parties, alice, [5] * 4, "sh-seed")
-
-    txs = []
-    for i in range(4):
-        t = Transaction(parties["alice-node"], f"sh-{i}")
-        t.transfer("alice", "USD", [5], [bob.recipient_identity()])  # (1,1)
-        t.collect_endorsements(None)
-        txs.append(t)
-
-    before_bt = _counter("batch.transfer.txs")
-    before_sharded = _counter("stages.sharded_calls")
-    for t in txs:
-        t.submit_async()
-    network.flush()
-    events = [t.wait() for t in txs]
-    assert all(e.status == TxStatus.VALID for e in events)
-    # all 4 proofs rode ONE batched call, and the call rode the mesh
-    assert _counter("batch.transfer.txs") - before_bt == 4
-    assert _counter("stages.sharded_calls") > before_sharded
-    assert parties["bob-node"].balance("USD") == 20
-
-
 def test_zk_batched_group_rejects_tampered_proof(zk_pp):
     """A tampered proof inside a batched group must invalidate ONLY its
     own tx: the device verdict (False) reaches the driver as a

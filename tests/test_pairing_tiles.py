@@ -108,13 +108,13 @@ def test_staged_product_does_not_depend_on_the_miller_height(
         monkeypatch.setattr(pr, "_product_rows", _toy_product)
         monkeypatch.setattr(pr, "final_exp", _toy_final_exp)
 
-    at16 = pr.pairing_product_staged(Ps, Qs, inf_mask=mask, dp=1, mp=1)
+    at16 = pr.pairing_product_staged(Ps, Qs, inf_mask=mask)
 
     monkeypatch.setattr(st, "_HOST_MILLER_ROWS", _T)
     tiles = mx.counter("pairing.staged.miller_tiles")
     frame = (devobs.current_plane(), "miller_tile")
     t0, e0 = tiles.value, dict(devobs.snapshot().get(frame, {}))
-    got = pr.pairing_product_staged(Ps, Qs, inf_mask=mask, dp=1, mp=1)
+    got = pr.pairing_product_staged(Ps, Qs, inf_mask=mask)
     e1 = devobs.snapshot()[frame]
 
     assert np.array_equal(got, at16)

@@ -609,9 +609,9 @@ def test_ftstop_renders_breaker_column():
               "breakers": {"verify": "closed", "sign": "closed"}}
     assert "brk=ok" in ftstop.format_row(health, {}, None, None)
     health["breakers"]["sign"] = "open"
-    health["breakers"]["stages"] = "half-open"
+    health["breakers"]["prove"] = "half-open"
     row = ftstop.format_row(health, {}, None, None)
-    assert "brk=sign:open,stages:half-open" in row
+    assert "brk=prove:half-open,sign:open" in row
     # nodes predating the field render no column at all
     row_old = ftstop.format_row({"uptime_s": 1.0, "height": 3}, {}, None, None)
     assert "brk=" not in row_old

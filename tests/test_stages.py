@@ -157,32 +157,91 @@ def test_run_rows_counts_transfers(rng):
     assert mx.REGISTRY.counter("batch.tiled.transfers").value - before == 4
 
 
-def test_run_rows_dp_edge_cases_match_host(rng):
-    """Sharded runner edges at the stage level: ntiles < dp, dp == 1
-    no-op (no sharded counters), dp == ntiles, and a consts-carrying
-    kernel (msm) — all bit-identical to the unsharded walk and correct
-    vs host math."""
-    pts = [hm.g1_mul(hm.G1_GEN, 5 + i) for i in range(9)]  # 2 ragged tiles
-    ks = _scalars(rng, 9)
-    expected = _g1_jac([hm.g1_mul(p, k) for p, k in zip(pts, ks)])
-    base = st.g1_mul_rows(_g1_jac(pts), cv.encode_scalars(ks))
-    sharded_before = mx.REGISTRY.counter("stages.sharded_calls").value
-    one = st.g1_mul_rows(_g1_jac(pts), cv.encode_scalars(ks), dp=1)
-    assert (
-        mx.REGISTRY.counter("stages.sharded_calls").value == sharded_before
-    ), "dp=1 must stay on the unsharded walk"
-    got = st.g1_mul_rows(_g1_jac(pts), cv.encode_scalars(ks), dp=8)
-    assert np.array_equal(got, base)  # dp > ntiles: one tile per shard
-    assert np.array_equal(one, base)
-    assert cv.decode_points(base) == cv.decode_points(expected)
-    # consts (window table) reach every shard of an msm dispatch
-    bases = [hm.g1_mul(hm.G1_GEN, 7 + i) for i in range(2)]
-    from fabric_token_sdk_tpu.crypto.pedersen import BatchedPedersen
+_T = 8  # the CPU backend's stage-tile height (`st.tile_rows`)
 
-    ped = BatchedPedersen(bases)
-    rows = [[rng.randrange(hm.R), rng.randrange(hm.R)] for _ in range(9)]
-    host = [hm.g1_multiexp(bases, r) for r in rows]
-    assert ped.commit_ints(rows, dp=4)[0] == host
+
+@pytest.mark.parametrize("program", ["g1_add_tile", "g1_msm1_tile"])
+@pytest.mark.parametrize("N", [1, _T - 1, _T, _T + 1, 2 * _T + 1])
+def test_run_rows_edge_cases_match_host(rng, N, program):
+    """The one walk at its edges — a single row, one short of a tile, a
+    full tile, one over, two tiles and one — for a kernel of two row
+    arrays and no consts (add) and one with consts (msm): the answers
+    are hostmath's, and the ledger frame says what was dispatched as
+    `tiles.padding_share` reads it."""
+    from fabric_token_sdk_tpu.utils import devobs
+
+    assert st.tile_rows(program) == _T
+    devobs.reset()
+    tiles_before = mx.REGISTRY.counter("stages.tiles").value
+    pts = [hm.g1_mul(hm.G1_GEN, 5 + i) for i in range(3)]
+    idx = [i % 3 for i in range(N)]
+    if program == "g1_add_tile":
+        a, b = _g1_jac(pts)[idx], _g1_jac(pts[::-1])[idx]
+        got = cv.decode_points(st.g1_add_rows(a, b))
+        want = [hm.g1_add(pts[i], pts[2 - i]) for i in idx]
+    else:
+        ks = _scalars(rng, 3)
+        table = cv.FixedBaseTable(pts[:1])
+        got = cv.decode_points(
+            st.g1_msm_rows(table.flat, cv.encode_scalars(ks)[idx][:, None, :])
+        )
+        want = [hm.g1_mul(pts[0], ks[i]) for i in idx]
+    assert got == want
+    e = devobs.health_section()["programs"][f"stages:{program}"]
+    pad = (-N) % _T
+    assert (e["dispatches"], e["rows"], e["padded_rows"]) == (1, N, pad)
+    assert e["tile_rows"] == _T
+    ntiles = (N + pad) // _T
+    assert mx.REGISTRY.counter("stages.tiles").value - tiles_before == ntiles
+    assert e["waste_frac"] == round(pad / (N + pad), 4)
+    devobs.reset()
+
+
+@pytest.mark.parametrize("where", [
+    "ops.stages:run_rows", "ops.pairing:pairing_product_staged",
+    "services.network:Network", "api.driver:Driver.batch_verifier"])
+def test_no_placement_parameter_on_the_way_to_a_tile(where):
+    """One walk from a block to a tile: nothing on the way takes a
+    mesh or a shard count (placement, when it comes, enters at
+    `_run_span`'s host-to-device transfer)."""
+    import functools
+    import importlib
+    import inspect
+
+    module, _, attr = where.partition(":")
+    subject = functools.reduce(
+        getattr, attr.split("."),
+        importlib.import_module("fabric_token_sdk_tpu." + module))
+    assert not set(inspect.signature(subject).parameters) & {
+        "dp", "mp", "mesh"}
+
+
+def test_no_mesh_knob_or_module_is_left():
+    import importlib
+    import os
+    import re
+
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    # spelled apart so that this file passes its own scan
+    knobs = ["FTS_" + k for k in ("MESH_", "DP_SHARDS", "SHARDED_PAIRING_FUSED",
+                                  "BENCH_SCALING")]
+    files = [os.path.join(root, f) for f in os.listdir(root)
+             if f.endswith(".py")]
+    for top in ("fabric_token_sdk_tpu", "cmd", "tests", "benchmark"):
+        for d, _dirs, names in os.walk(os.path.join(root, top)):
+            files += [os.path.join(d, f) for f in names if f.endswith(".py")]
+    assert len(files) > 100
+    # ... and no call site still passes one of the three keywords
+    keyword = re.compile(r"\b(?:dp|mp|mesh)" + "=")
+    hits = []
+    for path in files:
+        with open(path, encoding="utf-8") as fh:
+            text = fh.read()
+        hits += [(os.path.relpath(path, root), k) for k in knobs if k in text]
+        hits += [(os.path.relpath(path, root), m) for m in keyword.findall(text)]
+    assert hits == []
+    with pytest.raises(ImportError):
+        importlib.import_module("fabric_token_sdk_tpu." + "parallel")
 
 
 def test_gt_is_one_host():
