@@ -11,7 +11,8 @@ assembly; we target the VPU/MXU instead):
   column convolutions; carries use signed arithmetic-shift passes under
   ``lax.while_loop``.
 * Group ops are batched Jacobian formulas with select-based (branch-free)
-  edge handling; scalar multiplication is a ``lax.scan`` over bits.
+  edge handling; variable-base scalar multiplication is a ``lax.scan``
+  over four-bit digits against a per-row table built on the device.
 * Hot multiexps use fixed-base window tables contracted with one-hot digit
   matrices — dense matmuls that ride the MXU.
 """

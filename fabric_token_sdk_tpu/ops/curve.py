@@ -119,22 +119,67 @@ def scalar_bits(k_canon, nbits: int = 256):
     return flat[..., :nbits][..., ::-1]  # MSB first
 
 
+def scalar_digits(k_canon, w: int):
+    """Canonical limb scalars (..., NLIMBS) -> base-2^w digits
+    (..., ceil(256 / w)), most significant first. A width that does not
+    divide 256 gets a short leading digit."""
+    bits = scalar_bits(k_canon)
+    lead = (-bits.shape[-1]) % w
+    bits = jnp.pad(bits, [(0, 0)] * (bits.ndim - 1) + [(lead, 0)])
+    bits = bits.reshape(bits.shape[:-1] + (-1, w))
+    return jnp.sum(bits << jnp.arange(w - 1, -1, -1, dtype=jnp.int32), axis=-1)
+
+
+# Bits a digit of the window `scalar_mul` walks a scalar in: the least
+# by the count of Fp multiplications a scalar (4,805 / 3,945 / 3,609 /
+# 3,729 at 2 / 3 / 4 / 5 bits; bit-serial 7,680) and on the chip: one
+# warm dispatch of 128 rows on a TPU v5e, ms at 2 / 3 / 4 / 5 bits,
+# 47.5 / 36.3 / 33.2 / 34.5 (bit-serial 62.6; sweep: PERF.md section 6,
+# PR 31).
+MUL_WINDOW_BITS = 4
+
+
+def windowed_mul(p, k_canon, w: int, add, double, infinity):
+    """k * p by fixed-window double-and-add, for either group (`add`,
+    `double`, `infinity` are the group's): a per-row table
+    [O, P, 2P, ..., (2^w - 1)P] built on the device, then one step per
+    base-2^w digit, most significant first: w doublings, a one-hot
+    select of the digit's entry, ONE complete addition. Digit 0 selects
+    the point at infinity, which `add` passes through, and the complete
+    `add` also covers a row whose accumulator meets the selected entry
+    or its negative. The table build and the doublings are loops of one
+    point operation each: the program stays small."""
+    point_ndim = infinity().ndim
+    acc0 = infinity(p.shape[: p.ndim - point_ndim])
+
+    def entry(prev, _):
+        nxt = add(prev, p)
+        return nxt, nxt
+
+    _, multiples = lax.scan(entry, acc0, None, length=(1 << w) - 1)
+    table = jnp.concatenate([acc0[None], multiples])  # (2^w, ..., point)
+    digits = jnp.moveaxis(scalar_digits(k_canon, w), -1, 0)
+    entries = jnp.arange(1 << w, dtype=jnp.int32).reshape(
+        (-1,) + (1,) * acc0.ndim
+    )
+
+    def step(acc, digit):
+        acc = lax.fori_loop(0, w, lambda _, a: double(a), acc)
+        pick = jnp.expand_dims(digit, tuple(range(-point_ndim, 0))) == entries
+        return add(acc, jnp.sum(jnp.where(pick, table, 0), axis=0)), None
+
+    out, _ = lax.scan(step, acc0, digits)
+    return out
+
+
 @jax.jit
 def scalar_mul(p, k_canon):
-    """Batched double-and-add: (..., 3, L) x (..., L) -> (..., 3, L).
+    """Batched variable-base k * P: (..., 3, L) x (..., L) -> (..., 3, L).
 
-    k_canon is a canonical (non-Montgomery) limb scalar. 256 scan steps.
+    k_canon is a canonical (non-Montgomery) limb scalar, any 256-bit
+    value. 64 four-bit digits over a per-row table (`windowed_mul`).
     """
-    bits = scalar_bits(k_canon)  # (..., 256) MSB first
-    bits_t = jnp.moveaxis(bits, -1, 0)  # (256, ...)
-
-    def step(acc, bit):
-        acc = double(acc)
-        acc = jnp.where(bit[..., None, None] > 0, add(acc, p), acc)
-        return acc, None
-
-    out, _ = lax.scan(step, infinity(p.shape[:-2]), bits_t)
-    return out
+    return windowed_mul(p, k_canon, MUL_WINDOW_BITS, add, double, infinity)
 
 
 def tree_sum(points, axis: int = -3):

@@ -11,9 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from . import limbs as lb, tower as tw
+from . import curve as cv, limbs as lb, tower as tw
 from .field import FP
 from ..crypto import hostmath as hm
 
@@ -98,21 +97,18 @@ def add(p, q):
     return out
 
 
+# Bits a digit of `scalar_mul`'s window, chosen as in G1
+# (`curve.MUL_WINDOW_BITS`) by the same sweep: one warm dispatch of 128
+# rows on a TPU v5e, ms at 2 / 3 / 4 / 5 bits, 65.4 / 53.7 / 49.6 /
+# 53.9 (bit-serial 98.9).
+MUL_WINDOW_BITS = 4
+
+
 @jax.jit
 def scalar_mul(p, k_canon):
-    """(..., 3, 2, L) x (..., L) canonical scalars -> double-and-add scan."""
-    from .curve import scalar_bits
-
-    bits = scalar_bits(k_canon)
-    bits_t = jnp.moveaxis(bits, -1, 0)
-
-    def step(acc, bit):
-        acc = double(acc)
-        acc = jnp.where(bit[..., None, None, None] > 0, add(acc, p), acc)
-        return acc, None
-
-    out, _ = lax.scan(step, infinity(p.shape[:-3]), bits_t)
-    return out
+    """(..., 3, 2, L) x (..., L) canonical scalars -> k * P, by the
+    fixed-window walk both groups share (`curve.windowed_mul`)."""
+    return cv.windowed_mul(p, k_canon, MUL_WINDOW_BITS, add, double, infinity)
 
 
 def tree_sum(points, axis: int = -4):

@@ -23,6 +23,11 @@ not baked constants, so one executable serves every parameter set):
   G2:  variable-base scalar-mul tile, Jacobian add tile,
        batch to-affine tile
 
+The msm tiles and the two scalar-mul tiles walk their scalars in
+windows (`window_bits(program)` bits a digit): the msm tiles over fixed
+tables passed in, the scalar-mul tiles over a table of the row's own
+point built on the device (`curve.windowed_mul`).
+
 Program-size discipline: one inlined Jacobian point-op costs ~40s of XLA
 CPU compile on a small host, so every stage keeps at most ~2 point-ops in
 its traced body. In particular the msm point reduction is a `lax.scan`
@@ -111,6 +116,20 @@ def tile_rows(program: str) -> int:
     if program == "miller_tile":
         return _TPU_MILLER_ROWS if _on_tpu() else _HOST_MILLER_ROWS
     return _TPU_TILE_ROWS if _on_tpu() else _HOST_TILE_ROWS
+
+
+_WINDOW_BITS = {
+    "g1_mul_tile": cv.MUL_WINDOW_BITS,
+    "g2_mul_tile": cv2.MUL_WINDOW_BITS,
+    **{f"g1_msm{n}_tile": cv.WINDOW_BITS for n in (1, 2, 3)},
+}
+
+
+def window_bits(program: str) -> int:
+    """Bits a digit of the scalar window tile program `program` walks:
+    the form of its arithmetic, as `tile_rows` is its shape. 0 for a
+    program that walks no scalar. The same on every backend."""
+    return _WINDOW_BITS.get(program, 0)
 
 
 # ------------------------------------------------------------ tile kernels
@@ -255,6 +274,7 @@ def run_rows(kernel, *arrays, consts=()):
     with devobs.dispatch(
         program, rows=N, padded_rows=pad, tiles=ntiles,
     ) as frame:
+        frame.form(window_bits(program))
         if pad:
             padded = []
             for a in arrays:

@@ -138,6 +138,7 @@ def _entry(frame: Tuple[str, str]) -> dict:
             "rows": 0,
             "padded_rows": 0,
             "tile_rows": 0,
+            "window_bits": 0,
             "wall_s": 0.0,
             "stage_s": 0.0,
             "wait_s": 0.0,
@@ -256,13 +257,19 @@ class _Frame:
     """What `dispatch(...)` yields: the caller marks each tile's enqueue
     with `tile()` and wraps each blocking read-back in `wait()`."""
 
-    __slots__ = ("tile_name", "wait_name", "waits")
+    __slots__ = ("tile_name", "wait_name", "waits", "window_bits")
 
     def __init__(self, pl: str, program: str):
         self.tile_name = f"fts:{pl}:{program}"
         self.wait_name = f"fts:wait:{pl}:{program}"
         # seconds of every read-back
         self.waits: list = []
+        self.window_bits = 0
+
+    def form(self, window_bits: int):
+        """Which form of the program's arithmetic this dispatch runs:
+        the bits a digit of its scalar window (0: it walks none)."""
+        self.window_bits = window_bits
 
     def tile(self):
         return _annotation(self.tile_name)
@@ -275,6 +282,9 @@ class _OffFrame:
     """`dispatch(...)` with the ledger off: nothing timed or marked."""
 
     __slots__ = ()
+
+    def form(self, window_bits: int):
+        pass
 
     def tile(self):
         return _NULL
@@ -299,7 +309,9 @@ def dispatch(
     host preparation until its results are on the host: requested vs
     padded rows, the height of its tiles (`tile_rows` = dispatched rows
     / `tiles`, of the newest dispatch: it says which shape of the
-    program ran), and `wall_s = stage_s + wait_s`.
+    program ran), the form of its arithmetic where the caller names it
+    (`frame.form(...)`: `window_bits`, of the newest dispatch), and
+    `wall_s = stage_s + wait_s`.
 
     `wait_s` is the host blocked on a device result — what the caller
     wrapped in `frame.wait()`; `stage_s` is the rest of the frame — the
@@ -338,6 +350,7 @@ def dispatch(
             e["padded_rows"] += padded_rows
             if height:
                 e["tile_rows"] = height
+            e["window_bits"] = fr.window_bits
             e["wall_s"] += wall
             e["stage_s"] += wall - wait
             e["wait_s"] += wait
@@ -479,6 +492,7 @@ def health_section() -> dict:
             "rows": e["rows"],
             "padded_rows": e["padded_rows"],
             "tile_rows": e["tile_rows"],
+            "window_bits": e["window_bits"],
             "occupancy": _occ(e["rows"], e["padded_rows"]),
             "waste_frac": _waste(e["rows"], e["padded_rows"]),
             "wall_s": round(e["wall_s"], 6),

@@ -6,6 +6,8 @@ sizes that are not multiples of the tile height, at the host's height
 and at the chip's) and the host-glue helpers.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -134,6 +136,189 @@ def test_scalar_mul_rows_match_host_at_the_chips_tile_height(monkeypatch, rng):
     got = st.g2_mul_rows(np.asarray(cv2.encode_points(pts2))[idx], k)
     want = [hm.g2_mul(p, x) for p, x in zip(pts2, ks)]
     assert cv2.decode_points(got) == [want[i] for i in idx]
+    # a mix of G2 points and scalars in one ragged call at that height:
+    # in the subgroup and outside it, the point at infinity, scalars
+    # below r and above, rows whose accumulator meets a table entry
+    twist = _twist_point_outside_g2()
+    meets, negated = _meeting_scalars(
+        _TWIST_ORDER, 1 << st.window_bits("g2_mul_tile"))
+    mix = [(pts2[0], 0), (pts2[1], 1), (pts2[2], hm.R - 1), (pts2[3], hm.R),
+           (pts2[4], (1 << 256) - 1), (None, ks[0]), (twist, meets),
+           (twist, negated), (twist, ks[1]), (pts2[0], 1 << 252)]
+    idx = [i % len(mix) for i in range(N)]
+    got = st.g2_mul_rows(
+        np.asarray(cv2.encode_points([p for p, _ in mix]))[idx],
+        lb.ints_to_limbs([x for _, x in mix])[idx],
+    )
+    want = [hm._g2_mul_raw(p, x) if p else None for p, x in mix]
+    assert cv2.decode_points(got) == [want[i] for i in idx]
+
+
+# ---------------------------------------- the windowed scalar-mul tiles
+#
+# `g1_mul_tile` / `g2_mul_tile` walk a scalar in `st.window_bits` wide
+# digits over a per-row table (`curve.windowed_mul`). One ragged call a
+# group holds every case below; each case is a test of its own.
+
+# any value 32 eight-bit limbs hold is a scalar to the tile (only
+# `cv.encode_scalars` reduces): below r, and at or above it
+_MUL_SCALARS = {
+    "0": 0, "1": 1, "2": 2, "15": 15, "16": 16, "17": 17,
+    "2^252": 1 << 252, "r-1": hm.R - 1, "r": hm.R, "r+1": hm.R + 1,
+    "2r": 2 * hm.R, "5r": 5 * hm.R, "2^254": 1 << 254, "2^255": 1 << 255,
+    "2^256-1": (1 << 256) - 1,
+}
+_MUL_CASES = list(_MUL_SCALARS) + [
+    "acc_meets_entry", "acc_meets_negated_entry", "point_at_infinity"]
+_TWIST_CASES = [
+    "twist_acc_meets_entry", "twist_acc_meets_negated_entry",
+    "twist_meets_mid_walk", "twist_scalar_above_its_order"]
+_TWIST_ORDER = 10069  # a prime factor of G2's cofactor 2p - r
+
+
+def _twist_point_outside_g2():
+    """A point of the twist of order 10069: on the curve, not in G2
+    (what `g2_mul_tile` meets before a subgroup check has run)."""
+    x = (1, 0)
+    while True:
+        y = hm.fp2_sqrt(hm.fp2_add(hm.fp2_mul(hm.fp2_sqr(x), x), hm.B2))
+        if y is not None:
+            pt = hm._g2_mul_raw(
+                (x, y), (2 * hm.P - hm.R) * hm.R // _TWIST_ORDER)
+            if pt is not None:
+                assert hm._g2_mul_raw(pt, _TWIST_ORDER) is None
+                assert not hm.g2_in_subgroup(pt)
+                return pt
+        x = (x[0] + 1, 0)
+
+
+def _meeting_scalars(order, W, limit=1 << 256):
+    """Scalars under `limit` whose walk, for a point of order `order`,
+    ends with the accumulator ON the last digit's table entry (the
+    complete addition's P == Q row) and on its negative (P == -Q)."""
+    for m in range(1, limit // order + 1):
+        d = -m * order % W
+        if d and m * order + 2 * d < limit:
+            # W * prefix = m * order + d, last digit d: acc = d * P
+            meets = m * order + 2 * d
+            break
+    for m in range(1, limit // order + 1):
+        if m * order % W:
+            # last digit d = m * order mod W, acc = (m * order - d) * P
+            negated = m * order
+            break
+    return meets, negated
+
+
+@functools.cache
+def _windowed_mul_rows(group):
+    """{case: (what the tile gave, what hostmath gives)} for `group`,
+    from ONE ragged `run_rows` call of its scalar-mul program."""
+    import random
+
+    rng = random.Random(0x31)
+    W = 1 << st.window_bits(f"{group}_mul_tile")
+    assert W > 1
+    if group == "g1":
+        base = hm.g1_mul(hm.G1_GEN, rng.randrange(1, hm.R))
+        host_mul, encode, decode, rows = (
+            hm.g1_mul, _g1_jac, cv.decode_points, st.g1_mul_rows)
+    else:
+        base = hm.g2_mul(hm.G2_GEN, rng.randrange(1, hm.R))
+        host_mul, decode, rows = (
+            hm._g2_mul_raw, cv2.decode_points, st.g2_mul_rows)
+        encode = lambda pts: np.asarray(cv2.encode_points(pts))
+    cases = {name: (base, k) for name, k in _MUL_SCALARS.items()}
+    meets, negated = _meeting_scalars(hm.R, W)
+    cases["acc_meets_entry"] = (base, meets)
+    cases["acc_meets_negated_entry"] = (base, negated)
+    cases["point_at_infinity"] = (None, rng.randrange(1, hm.R))
+    if group == "g2":
+        twist = _twist_point_outside_g2()
+        meets, negated = _meeting_scalars(_TWIST_ORDER, W)
+        cases["twist_acc_meets_entry"] = (twist, meets)
+        cases["twist_acc_meets_negated_entry"] = (twist, negated)
+        # the meeting two digits before the end, then two more digits
+        cases["twist_meets_mid_walk"] = (twist, meets * W * W + W + 5)
+        cases["twist_scalar_above_its_order"] = (twist, rng.randrange(hm.R))
+    # the rows built to meet do meet, by hostmath: before the last
+    # addition the accumulator is W * (k // W) times the point
+    for name, sign in (("acc_meets_entry", 1), ("acc_meets_negated_entry", -1)):
+        for prefix in ("", "twist_") if group == "g2" else ("",):
+            pt, k = cases[prefix + name]
+            entry = host_mul(pt, k % W)
+            assert entry is not None
+            if sign < 0:
+                entry = (entry[0], hm.fp2_neg(entry[1]) if group == "g2"
+                         else -entry[1] % hm.P)
+            assert host_mul(pt, k - k % W) == entry
+    names = list(cases)
+    got = decode(rows(
+        encode([cases[n][0] for n in names]),
+        lb.ints_to_limbs([cases[n][1] for n in names]),
+    ))
+    return {
+        n: (g, host_mul(*cases[n]) if cases[n][0] else None)
+        for n, g in zip(names, got)
+    }
+
+
+@pytest.mark.parametrize("case", _MUL_CASES)
+@pytest.mark.parametrize("group", ["g1", "g2"])
+def test_windowed_scalar_mul_rows_match_host(group, case):
+    """Scalars at the window's edges (a zero digit, the first entry,
+    the last, a carry into the next digit), every kind of value the
+    limbs admit at or above r, the point at infinity, and rows whose
+    accumulator meets the selected entry or its negative: the complete
+    addition gives hostmath's answer in each."""
+    got, want = _windowed_mul_rows(group)[case]
+    assert got == want
+    if case in ("0", "r", "2r", "5r", "acc_meets_negated_entry",
+                "point_at_infinity"):
+        assert got is None
+
+
+@pytest.mark.parametrize("case", _TWIST_CASES)
+def test_windowed_g2_mul_of_a_point_outside_the_subgroup(case):
+    """A point of the twist of order 10069 (not in G2): small scalars
+    put the accumulator on a table entry and on its negative, at the
+    end of the walk and in the middle of it."""
+    got, want = _windowed_mul_rows("g2")[case]
+    assert got == want
+    assert (got is None) == (case == "twist_acc_meets_negated_entry")
+
+
+def test_every_windowed_case_is_a_test():
+    assert set(_windowed_mul_rows("g1")) == set(_MUL_CASES)
+    assert set(_windowed_mul_rows("g2")) == set(_MUL_CASES + _TWIST_CASES)
+
+
+@pytest.mark.parametrize("program,bits", [
+    ("g1_mul_tile", cv.MUL_WINDOW_BITS), ("g2_mul_tile", cv2.MUL_WINDOW_BITS),
+    ("g1_msm3_tile", cv.WINDOW_BITS), ("g1_add_tile", 0)])
+def test_health_names_the_form_that_ran(program, bits):
+    """`ops.health()["device"]["programs"]` (the ledger's section of
+    `Network.health()`) says which form of a program's arithmetic ran,
+    beside the height of its tiles: `window_bits`."""
+    from fabric_token_sdk_tpu.utils import devobs
+
+    assert st.window_bits(program) == bits
+    devobs.reset()
+    pts = _g1_jac([hm.g1_mul(hm.G1_GEN, 9)])
+    k = cv.encode_scalars([5])
+    if program == "g1_mul_tile":
+        st.g1_mul_rows(pts, k)
+    elif program == "g2_mul_tile":
+        st.g2_mul_rows(np.asarray(cv2.encode_points([hm.G2_GEN])), k)
+    elif program == "g1_msm3_tile":
+        table = cv.FixedBaseTable([hm.G1_GEN] * 3)
+        st.g1_msm_rows(table.flat, np.repeat(k[:, None, :], 3, axis=1))
+    else:
+        st.g1_add_rows(pts, pts)
+    e = devobs.health_section()["programs"][f"stages:{program}"]
+    assert e["window_bits"] == bits
+    assert e["tile_rows"] == st.tile_rows(program)
+    devobs.reset()
 
 
 def test_affine_to_jac_np_round_trips():
