@@ -37,7 +37,6 @@ from ...utils import devobs, faults, profiler, resilience, slo
 from ...utils import metrics as mx
 from ...utils.tracing import logger, tracer
 from .orderer import (
-    Backpressure,
     BlockPolicy,
     BlockValidationPipeline,
     Orderer,
@@ -295,54 +294,87 @@ class Network:
     def submit_async(self, request_bytes: bytes) -> Submission:
         """Enqueue a request into ordering; returns a Submission handle
         whose `result()` waits for (and, if needed, drives) block commit."""
-        return self.submit_request(TokenRequest.from_bytes(request_bytes))
+        return self.submit_request(
+            TokenRequest.from_bytes(request_bytes), len(request_bytes)
+        )
 
-    def submit_request(self, request: TokenRequest) -> Submission:
-        """`submit_async` for an already-parsed request (the remote
-        node's batched submit path decodes up front — no double parse).
-        The active trace context (or a fresh one, minted only when the
-        request actually enters ordering — dedup'd resubmissions never
-        mint orphan traces) is captured into the Submission so
-        block-commit spans land in this tx's trace."""
+    def _known(self, request: TokenRequest) -> Optional[Submission]:
+        """An already-recorded anchor resolves from the record at once
+        (idempotent resubmission), never entering ordering again."""
         with self._lock:
             known = self._status.get(request.anchor)
-        if known is not None:  # idempotent resubmission
-            mx.counter("network.submit.resubmissions").inc()
-            mx.flight("submit", tx=request.anchor, dedup=True)
-            sub = Submission(None, request)
-            sub._resolve(known)
+        if known is None:
+            return None
+        mx.counter("network.submit.resubmissions").inc()
+        mx.flight("submit", tx=request.anchor, dedup=True)
+        sub = Submission(None, request)
+        sub._resolve(known)
+        return sub
+
+    def submit_request(self, request: TokenRequest,
+                       size: Optional[int] = None) -> Submission:
+        """`submit_async` for an already-parsed request (the remote
+        node's submit path decodes up front — no double parse); `size`
+        is its wire length, what the cutter's byte rules count (computed
+        when not given). The active trace context (or a fresh one, minted
+        only when the request actually enters ordering — dedup'd
+        resubmissions never mint orphan traces) is captured into the
+        Submission so block-commit spans land in this tx's trace."""
+        sub = self._known(request)
+        if sub is not None:
             return sub
         ctx = mx.current_trace() or mx.new_trace()
         with mx.use_trace(ctx):
-            return self._orderer.enqueue(request)
+            return self._orderer.enqueue(request, size)
 
-    def submit_request_cooperative(self, request: TokenRequest) -> Submission:
-        """`submit_request` for BATCH submitters under a bounded ordering
-        queue: instead of surfacing `Backpressure` mid-batch (stranding
-        the already enqueued prefix), drain the queue with a flush and
-        retry — admission control sheds load from OTHER submitters while
-        a deterministic batch still lands whole. Shared by the local and
-        the remote-server `submit_many` paths."""
-        while True:
-            try:
-                return self.submit_request(request)
-            except Backpressure:
+    def submit_requests(self, items: List[tuple]) -> List[Submission]:
+        """A BATCH of `(request, wire size or None, trace context or
+        None)` into ordering, in order, under one hold of the orderer's
+        mutex: no concurrent driver can cut between two of them, so the
+        cut rules meet the hand-over whole. Cooperative under a bounded
+        ordering queue: instead of surfacing `Backpressure` mid-batch
+        (stranding the already enqueued prefix), drain the queue with a
+        flush and hand the rest over — admission control sheds load from
+        OTHER submitters while a batch still lands whole. A request over
+        `absolute_max_bytes` raises `MessageTooLarge` before any of the
+        batch is ordered. Shared by the local and the remote-server
+        `submit_many` paths."""
+        subs: List[Optional[Submission]] = [
+            self._known(request) for request, _size, _trace in items
+        ]
+        todo = [k for k, sub in enumerate(subs) if sub is None]
+        fresh = [
+            (items[k][0], items[k][1],
+             items[k][2] or mx.current_trace() or mx.new_trace())
+            for k in todo
+        ]
+        while fresh:
+            admitted = self._orderer.enqueue_many(fresh)
+            for k, sub in zip(todo, admitted):
+                subs[k] = sub
+            todo, fresh = todo[len(admitted):], fresh[len(admitted):]
+            if fresh:
                 mx.counter("orderer.backpressure.flushes").inc()
-                self._orderer.flush()
+                self._orderer.flush(wait=True)
+        return subs
 
     def submit_many(self, requests_bytes: List[bytes]) -> List[FinalityEvent]:
-        """Deterministic multi-tx blocks: enqueue everything (cooperating
-        with admission control), then cut + commit in arrival order
-        (`max_block_txs` txs per block)."""
-        subs = [
-            self.submit_request_cooperative(TokenRequest.from_bytes(rb))
+        """A hand-over of several requests: all of them enter ordering
+        together (cooperating with admission control), then the blocks
+        the policy cuts from them commit in arrival order. With
+        `BlockPolicy()` defaults that is deterministic multi-tx blocks,
+        `max_block_txs` txs each; under a batch timer the channel's rules
+        decide the blocks, not this call."""
+        subs = self.submit_requests([
+            (TokenRequest.from_bytes(rb), len(rb), None)
             for rb in requests_bytes
-        ]
+        ])
         self._orderer.flush()
         return [s.result() for s in subs]
 
     def flush(self) -> None:
-        """Force-commit everything pending in the ordering queue."""
+        """Commit every block the cutter has (`Orderer.flush`): without a
+        batch timer, everything pending in the ordering queue."""
         self._orderer.flush()
 
     # ------------------------------------------------------------ commit

@@ -13,13 +13,41 @@ block invalidates the LATER tx, never the block — and commits the block
 atomically with per-tx finality events.
 
 Concurrency model: **group commit without a dedicated thread.**
-Submitters enqueue, then race for the commit lock; the winner cuts a
-block from everything pending (up to `max_block_txs`) and commits it;
-losers either find their submission finalized by the winner's block or
-cut the next block themselves. Sequential callers therefore see one-tx
-blocks with zero added latency, while concurrent load batches naturally
-— and deterministic multi-tx blocks are available via
-`Network.submit_many` / `Orderer.flush`.
+Submitters enqueue, then race for the commit lock; the winner takes the
+next block the cutter has for it and commits it; losers either find
+their submission finalized by the winner's block or drive the next
+block themselves. A waiter drives until the block that holds its own
+submission is taken (by itself or another driver) and from then on
+waits on that submission's event alone: the blocks cut behind it are
+their own waiters' to drive, so a reply is never held back by a later
+block's verification.
+
+The cutter is Fabric's `orderer/common/blockcutter` `Ordered()` plus
+the chain's batch timer, applied message by message in enqueue order
+(`Orderer._order`; plain reference
+`benchmark/reference/fabric_blockcutter.py`):
+
+1. a message over `absolute_max_bytes` is refused before ordering
+   (`MessageTooLarge`, nothing enqueued);
+2. a message over `preferred_max_bytes` cuts the open batch, if any,
+   and is then cut alone as its own block;
+3. a message that would carry the open batch past
+   `preferred_max_bytes` cuts that batch first and opens the next one;
+4. an open batch that reaches `max_block_txs` messages is cut;
+5. the batch timer starts with the first message of an open batch and,
+   `linger_s` later, cuts whatever the batch holds.
+
+A byte rule set to 0 is off. `linger_s` 0 (the default) means there is
+no batch timer: a driver takes whatever is open when it comes by, so
+sequential callers see one-tx blocks with zero added latency, concurrent
+load batches naturally, and `Network.submit_many` / `Orderer.flush` cut
+"everything pending, `max_block_txs` at a time". With a timer, a client
+decides no block: `flush` drives the blocks the rules have cut and
+leaves an open batch to its timer. No thread owns the timer: a driver
+whose transaction sits in the open batch sleeps until the timer runs
+out (a condition wait, woken early by a cut), and every enqueue first
+closes a batch whose timer ran out before it, so which messages share a
+block depends on their arrival times alone.
 
 Pipelined mode (`pipeline.PipelinedBlockEngine`, default on, opt-out
 `FTS_BLOCK_PIPELINE=0`): the driving thread runs only the CUT + batched
@@ -64,6 +92,15 @@ def host_batch_enabled() -> bool:
     return os.environ.get("FTS_HOST_BATCH", "1") != "0"
 
 
+class MessageTooLarge(ValueError):
+    """The request is longer than `BlockPolicy.absolute_max_bytes`
+    (Fabric's `AbsoluteMaxBytes`): refused BEFORE ordering, nothing was
+    enqueued — and, unlike `Backpressure`, no retry can succeed. The
+    remote server maps it to a typed wire error
+    (`error_class: "MessageTooLarge"`) and the remote client raises it
+    back as this same type."""
+
+
 class Backpressure(RuntimeError):
     """The ordering queue is at `BlockPolicy.queue_max` capacity: the
     submission was rejected BEFORE entering ordering, so a retry (with
@@ -77,9 +114,24 @@ class Backpressure(RuntimeError):
 class BlockPolicy:
     """Block-cut + batched-validation policy.
 
-    `max_block_txs`  — hard cap on txs per block.
-    `linger_s`       — how long a driving submitter waits for stragglers
-                       before cutting (0 = cut whatever is pending now).
+    The cut rules (module docstring; Fabric's names in brackets):
+    `max_block_txs`  — an open batch is cut when it holds this many
+                       messages [BatchSize.MaxMessageCount].
+    `linger_s`       — the batch timer [BatchTimeout]: it starts when a
+                       message enters an empty batch and cuts whatever
+                       the batch holds when it runs out; a cut by count
+                       or bytes does not wait for it, and no caller's
+                       `flush()` / `submit_many` cuts before it. 0 = no
+                       timer: a driver cuts whatever is pending when it
+                       comes by.
+    `preferred_max_bytes` — a message that would carry the open batch
+                       past this many bytes cuts it first; a message
+                       longer than this is cut alone
+                       [BatchSize.PreferredMaxBytes]. 0 = off.
+    `absolute_max_bytes` — a longer message is refused before ordering
+                       with `MessageTooLarge`
+                       [BatchSize.AbsoluteMaxBytes]. 0 = off.
+                       A message's size is its request's wire length.
     `min_batch`      — smallest same-shape transfer group worth a device
                        batch call; smaller groups take the host path.
     `use_batched`    — master switch for the batched proof plane.
@@ -110,6 +162,8 @@ class BlockPolicy:
     pipeline: bool = True
     sign_batched: Optional[bool] = None
     sign_min_batch: int = 4
+    preferred_max_bytes: int = 0
+    absolute_max_bytes: int = 0
 
     @classmethod
     def from_env(cls) -> "BlockPolicy":
@@ -136,7 +190,8 @@ class Submission:
     commit race still lands in the submitting tx's trace."""
 
     __slots__ = ("request", "event", "_done", "_orderer", "trace",
-                 "enqueued_at", "enqueued_unix", "_commit_error")
+                 "enqueued_at", "enqueued_unix", "_commit_error", "size",
+                 "_taken")
 
     def __init__(self, orderer: Optional["Orderer"], request: TokenRequest):
         self.request = request
@@ -146,6 +201,10 @@ class Submission:
         self.trace = None  # TraceContext captured at enqueue
         self.enqueued_at = 0.0  # monotonic, for queue-wait timing
         self.enqueued_unix = 0.0
+        self.size = 0  # wire bytes, what the cutter's byte rules count
+        # a driver has taken the block that holds it (set under the
+        # orderer's mutex): from then on its own event is what to wait for
+        self._taken = False
         # pipelined mode: a commit exception from the worker thread is
         # attached here (alongside the transient stranded event) so
         # `result()` re-raises it on the waiter's own stack — the same
@@ -191,20 +250,48 @@ class Submission:
         return self.event
 
 
+# `block.cut` reasons that have a counter of their own; "drain" (no batch
+# timer: a driver took what was open) counts in blocks and bytes alone
+_CUT_COUNTERS = {
+    "count": "orderer.cut.by_count",
+    "bytes": "orderer.cut.by_bytes",
+    "timeout": "orderer.cut.by_timeout",
+    "oversize": "orderer.cut.oversize",
+}
+# first message of a batch -> its cut: nothing under a millisecond, fine
+# around the second or two a channel's BatchTimeout is
+_BATCH_WAIT_BUCKETS = (
+    0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 0.75, 1.0, 1.25, 1.5, 1.75, 1.9, 2.0,
+    2.1, 2.5, 3.0, 5.0, 10.0, 30.0, 60.0,
+)
+
+
 class Orderer:
-    """Ordering queue + group-commit block cutter.
+    """Ordering queue + block cutter + group-commit driving.
 
     `commit_block` is the ledger's callback: it takes the cut list of
     Submissions, validates + commits them as ONE block, and resolves each
-    submission with its per-tx finality event.
+    submission with its per-tx finality event. `clock` is the cutter's
+    monotonic clock (tests pass a fake one: which messages share a block
+    depends on their arrival times on it alone).
     """
 
     def __init__(self, commit_block: Callable[[List[Submission]], None],
-                 policy: Optional[BlockPolicy] = None):
+                 policy: Optional[BlockPolicy] = None,
+                 clock: Callable[[], float] = time.monotonic):
         self._commit_block = commit_block
         self.policy = policy or BlockPolicy()
-        self._pending: collections.deque = collections.deque()
-        self._mutex = threading.Lock()  # guards _pending + _inflight
+        self._clock = clock
+        # the open batch (messages ordered, block not cut yet) and the
+        # cut batches no driver has taken yet, oldest first
+        self._pending: List[Submission] = []
+        self._pending_bytes = 0
+        self._ready: collections.deque = collections.deque()
+        self._queued = 0  # messages in `_pending` + `_ready`
+        self._mutex = threading.Lock()  # guards the queues + _inflight
+        # a driver whose tx sits in the open batch waits here for the
+        # batch timer; a cut by count or bytes wakes it early
+        self._cut_cond = threading.Condition(self._mutex)
         # submissions enqueued but not yet resolved (queued OR inside a
         # block being committed) — the instantaneous signal `ops.health`
         # serves; queue-wait histograms only exist after commit
@@ -220,36 +307,76 @@ class Orderer:
 
     # ------------------------------------------------------------ queue
 
-    def enqueue(self, request: TokenRequest) -> Submission:
-        sub = Submission(self, request)
-        sub.trace = mx.current_trace()
-        sub.enqueued_at = time.monotonic()
-        sub.enqueued_unix = time.time()
-        with self._mutex:
-            qmax = self.policy.queue_max
-            if qmax > 0 and len(self._pending) >= qmax:
-                # admission control: reject BEFORE ordering, so a retry
-                # is always safe — nothing enqueued, nothing can commit
-                depth = len(self._pending)
-                mx.counter("orderer.backpressure.rejects").inc()
-                mx.flight("backpressure", trace=sub.trace,
-                          tx=request.anchor, depth=depth, max=qmax)
-                raise Backpressure(
-                    f"ordering queue at capacity ({depth}/{qmax}); "
-                    f"tx {request.anchor} rejected before ordering — "
-                    "retry with backoff"
+    def enqueue(self, request: TokenRequest,
+                size: Optional[int] = None) -> Submission:
+        """Order one request (`size`: its wire length, computed when not
+        given). Raises `MessageTooLarge` or `Backpressure` BEFORE
+        ordering: nothing was enqueued."""
+        return self.enqueue_many([(request, size, mx.current_trace())])[0]
+
+    def enqueue_many(self, items: Sequence[tuple]) -> List[Submission]:
+        """Order `(request, size, trace)` triples under ONE hold of the
+        queue mutex, so no driver can cut between two of them (a
+        `submit_many` hand-over meets the cut rules whole). Returns the
+        Submissions of the prefix the bounded queue admitted (all of them
+        with `queue_max` 0); the caller drains and hands the rest over
+        again. A lone request the queue refuses raises `Backpressure`. A
+        message over `absolute_max_bytes` anywhere in the batch raises
+        `MessageTooLarge` before any of it is ordered."""
+        sized = [
+            (request, len(request.wire_bytes()) if size is None else size,
+             trace)
+            for request, size, trace in items
+        ]
+        amax = self.policy.absolute_max_bytes
+        for request, size, trace in sized:
+            if 0 < amax < size:
+                mx.counter("orderer.reject.too_large").inc()
+                mx.flight("reject.too_large", trace=trace,
+                          tx=request.anchor, bytes=size, max=amax)
+                raise MessageTooLarge(
+                    f"tx {request.anchor} is {size} bytes, over the "
+                    f"channel's absolute_max_bytes {amax}; rejected "
+                    "before ordering"
                 )
-            self._pending.append(sub)
-            self._inflight += 1
-            mx.gauge("orderer.queue.depth").set(len(self._pending))
+        subs: List[Submission] = []
+        qmax = self.policy.queue_max
+        with self._mutex:
+            depth = self._queued
+            for request, size, trace in sized:
+                if qmax > 0 and self._queued >= qmax:
+                    break
+                sub = Submission(self, request)
+                sub.trace, sub.size = trace, size
+                sub.enqueued_at = self._clock()
+                sub.enqueued_unix = time.time()
+                self._queued += 1
+                self._order(sub, sub.enqueued_at)
+                subs.append(sub)
+            self._inflight += len(subs)
+            mx.gauge("orderer.queue.depth").set(self._queued)
             mx.gauge("ledger.inflight").set(self._inflight)
-        mx.counter("ledger.ordering.enqueued").inc()
-        mx.flight("submit", trace=sub.trace, tx=request.anchor)
-        return sub
+        if not subs and len(sized) == 1:
+            # admission control: rejected BEFORE ordering, so a retry is
+            # always safe — nothing enqueued, nothing can commit
+            request, _size, trace = sized[0]
+            mx.counter("orderer.backpressure.rejects").inc()
+            mx.flight("backpressure", trace=trace,
+                      tx=request.anchor, depth=depth, max=qmax)
+            raise Backpressure(
+                f"ordering queue at capacity ({depth}/{qmax}); "
+                f"tx {request.anchor} rejected before ordering — "
+                "retry with backoff"
+            )
+        mx.counter("ledger.ordering.enqueued").inc(len(subs))
+        for sub in subs:
+            mx.flight("submit", trace=sub.trace, tx=sub.request.anchor)
+        return subs
 
     def pending(self) -> int:
+        """Messages ordered and not yet taken by a driver."""
         with self._mutex:
-            return len(self._pending)
+            return self._queued
 
     def inflight(self) -> int:
         """Submissions enqueued but not yet resolved (includes the block
@@ -262,16 +389,77 @@ class Orderer:
             self._inflight -= 1
             mx.gauge("ledger.inflight").set(self._inflight)
 
+    # ------------------------------------------------------------ cutter
+    # (all three under `_mutex`)
+
+    def _timer_left(self, now: float) -> float:
+        """Seconds until the open batch's timer runs out (<= 0: it has,
+        or the policy has none)."""
+        return self._pending[0].enqueued_at + self.policy.linger_s - now
+
+    def _order(self, sub: Submission, now: float) -> None:
+        """Fabric's `blockcutter.Ordered()` for one message arriving at
+        `now`, after the chain's timer: rules 2-5 of the module
+        docstring, in its order."""
+        pol = self.policy
+        if (pol.linger_s > 0 and self._pending
+                and self._timer_left(now) <= 0):
+            # the timer ran out before this message arrived: it cannot
+            # join that batch, whenever a driver gets to look
+            self._close("timeout", now)
+        pref = pol.preferred_max_bytes
+        if self._pending and 0 < pref < self._pending_bytes + sub.size:
+            # rules 2 and 3: the message does not fit the open batch
+            self._close("bytes", now)
+        self._pending.append(sub)
+        self._pending_bytes += sub.size
+        if 0 < pref < sub.size:
+            self._close("oversize", now)  # rule 2: alone, at once
+        elif len(self._pending) >= max(1, pol.max_block_txs):
+            self._close("count", now)
+
+    def _close(self, reason: str, now: float) -> None:
+        """Cut the open batch: it joins the blocks awaiting a driver."""
+        batch, nbytes = self._pending, self._pending_bytes
+        # first message -> cut; a timer's cut is dated when it ran out,
+        # not when a thread came to look
+        waited_s = (self.policy.linger_s if reason == "timeout"
+                    else max(0.0, now - batch[0].enqueued_at))
+        self._pending, self._pending_bytes = [], 0
+        self._ready.append(batch)
+        mx.counter("orderer.cut.blocks").inc()
+        mx.counter("orderer.cut.bytes").inc(nbytes)
+        if reason in _CUT_COUNTERS:
+            mx.counter(_CUT_COUNTERS[reason]).inc()
+        mx.histogram(
+            "orderer.batch.wait.seconds", _BATCH_WAIT_BUCKETS
+        ).observe(waited_s)
+        mx.flight("block.cut", txs=len(batch), bytes=nbytes, reason=reason,
+                  waited_s=round(waited_s, 6))
+        self._cut_cond.notify_all()
+
     def _cut(self) -> List[Submission]:
+        """The next block for a driver: the oldest cut batch, else the
+        open one if the policy has no timer or its timer has run out,
+        else nothing."""
         # fault point BEFORE the pop: an injected cut failure strands
         # nothing — every pending submission survives for the next drive
         faults.fire("orderer.cut")
         with self._mutex:
-            n = min(len(self._pending), max(1, self.policy.max_block_txs))
-            batch = [self._pending.popleft() for _ in range(n)]
-            mx.gauge("orderer.queue.depth").set(len(self._pending))
-        if batch:
-            mx.flight("block.cut", txs=len(batch))
+            if self._pending:
+                now = self._clock()
+                if self.policy.linger_s > 0:
+                    if self._timer_left(now) <= 0:
+                        self._close("timeout", now)
+                elif not self._ready:
+                    self._close("drain", now)
+            if not self._ready:
+                return []
+            batch = self._ready.popleft()
+            for sub in batch:
+                sub._taken = True
+            self._queued -= len(batch)
+            mx.gauge("orderer.queue.depth").set(self._queued)
         return batch
 
     # ------------------------------------------------------------ drive
@@ -283,99 +471,100 @@ class Orderer:
         would deadlock waiting on itself) — it drives inline instead."""
         return self._engine is not None and not self._engine.on_worker_thread()
 
-    def flush(self) -> None:
-        """Cut + commit blocks until the ordering queue is empty (and, in
-        pipelined mode, every in-flight block has committed)."""
+    def _stage(self) -> tuple:
+        """(the lock that serializes cut + hand-off, what runs a cut
+        block under it): the engine's stage A — device verify of this
+        cut overlaps the worker's commit of the previous block — or the
+        whole sequential commit."""
         if self._pipelining():
-            engine = self._engine
-            while True:
-                with engine.stage_lock:
-                    batch = self._cut()
-                    if batch:
-                        engine.submit(batch)
-                if not batch:
-                    break
-            engine.drain()
-            return
+            return self._engine.stage_lock, self._engine.submit
+        return self._commit_lock, self._commit_block
+
+    def flush(self, wait: bool = False) -> None:
+        """Drive every block the cutter has until it has none left (and,
+        in pipelined mode, every in-flight block has committed). Without
+        a batch timer that empties the ordering queue; with one, an open
+        batch whose timer still runs is not the caller's to cut: it is
+        left to it, or with `wait` (a batch submitter making room in a
+        bounded queue) slept out and then driven."""
+        lock, run = self._stage()
         while True:
-            with self._commit_lock:
+            with lock:
                 batch = self._cut()
-                if not batch:
-                    return
-                self._commit_block(batch)
+                if batch:
+                    run(batch)
+            if not batch and not (wait and self._await_cut()):
+                break
+        if self._pipelining():
+            self._engine.drain()
+
+    def _sleep_out_timer(self, limit: Optional[float]) -> None:
+        """Under `_cut_cond`: wait for the open batch's timer (`limit`
+        seconds at the most), woken early by a cut."""
+        left = self._timer_left(self._clock())
+        if left > 0:
+            self._cut_cond.wait(left if limit is None else min(left, limit))
+
+    def _await_cut(self) -> bool:
+        """`flush(wait=True)` found nothing to cut: sleep out the open
+        batch's timer. -> whether there may be a block to drive now."""
+        with self._cut_cond:
+            if not self._ready and self._pending:
+                self._sleep_out_timer(None)
+            return bool(self._ready or self._pending)
+
+    def _park(self, sub: Submission, remaining: Optional[float]) -> None:
+        """Wait for what `sub` waits for, never spinning on a lock. Still
+        with the cutter: a cut block awaits a driver (return and drive:
+        blocks go oldest first), or `sub` sits in the open batch (sleep
+        out its timer). Taken by a driver, this one or another: its block
+        is being verified or committed, and its own event says when: the
+        blocks cut after it are their own waiters' to drive, so `sub` is
+        answered at its commit and not a verification or two later."""
+        with self._cut_cond:
+            if not sub._taken:
+                if not self._ready and self._pending:
+                    self._sleep_out_timer(remaining)
+                return
+        sub._done.wait(remaining)
 
     def drive(self, sub: Submission, timeout: Optional[float] = None):
-        """Commit blocks until `sub` resolves; returns its finality event.
+        """Commit blocks until the one that holds `sub` is taken, then
+        wait for `sub` to resolve; returns its finality event.
 
         The timeout is honored even while another thread holds the commit
-        lock mid-block (timed acquire), not just between commit attempts.
-        Waiters whose submission is in flight elsewhere (the pipelined
-        worker, or another driver's block) park on the submission's event
-        — a condition wait, never a spin on the commit lock.
+        lock mid-block (timed acquire), not just between commit attempts,
+        and while `sub` waits in an open batch for its timer.
         """
         deadline = None if timeout is None else time.monotonic() + timeout
 
         def _remaining() -> Optional[float]:
             return None if deadline is None else deadline - time.monotonic()
 
-        def _expired() -> bool:
-            return deadline is not None and time.monotonic() > deadline
-
-        def _timeout_check() -> None:
-            if not sub._done.is_set() and _expired():
-                raise TimeoutError(
-                    f"tx {sub.request.anchor} not ordered within {timeout}s"
-                )
-
         while not sub._done.is_set():
-            if self.policy.linger_s > 0:
-                # a window for concurrent submitters to join this block
-                sub._done.wait(self.policy.linger_s)
-            if self._pipelining():
-                engine = self._engine
+            if not sub._taken:
+                lock, run = self._stage()
                 remaining = _remaining()
                 if remaining is None:
-                    acquired = engine.stage_lock.acquire()
+                    acquired = lock.acquire()
                 else:
-                    acquired = remaining > 0 and engine.stage_lock.acquire(
-                        timeout=remaining
-                    )
-                batch = None
+                    acquired = remaining > 0 and lock.acquire(timeout=remaining)
                 if acquired:
                     try:
                         if sub._done.is_set():
                             break
                         batch = self._cut()
                         if batch:
-                            # stage A: device verify of this cut overlaps
-                            # the worker's commit of the previous block
-                            engine.submit(batch)
+                            run(batch)
                     finally:
-                        engine.stage_lock.release()
-                if not batch and not sub._done.is_set():
-                    # nothing left to cut: the sub is in flight in the
-                    # engine (or another driver's block) — park on its
-                    # event instead of re-racing the lock
-                    sub._done.wait(_remaining())
-                _timeout_check()
-                continue
-            if deadline is None:
-                acquired = self._commit_lock.acquire()
-            else:
-                remaining = deadline - time.monotonic()
-                acquired = remaining > 0 and self._commit_lock.acquire(
-                    timeout=remaining
+                        lock.release()
+            if not sub._done.is_set():
+                self._park(sub, _remaining())
+            if (not sub._done.is_set() and deadline is not None
+                    and time.monotonic() > deadline):
+                raise TimeoutError(
+                    f"tx {sub.request.anchor} not ordered within {timeout}s"
                 )
-            if acquired:
-                try:
-                    if sub._done.is_set():
-                        break
-                    batch = self._cut()
-                    if batch:
-                        self._commit_block(batch)
-                finally:
-                    self._commit_lock.release()
-            _timeout_check()
         return sub.event
 
 

@@ -82,7 +82,7 @@ from ...utils import devobs, faults, profiler
 from ...utils import metrics as mx
 from ...utils.tracing import logger
 from .ledger import FinalityEvent, Network, TxStatus
-from .orderer import Backpressure, Submission
+from .orderer import Backpressure, MessageTooLarge, Submission
 from .replication import NotLeader, StaleEpoch
 
 DEFAULT_MAX_FRAME = 16 * 1024 * 1024  # 16 MiB
@@ -374,6 +374,13 @@ class LedgerServer:
             mx.counter("remote.dispatch.backpressure").inc()
             return {"ok": False, "error": str(e),
                     "error_class": "Backpressure"}
+        except MessageTooLarge as e:
+            # the channel's AbsoluteMaxBytes, not a server fault: typed so
+            # the client raises the same error (nothing entered ordering,
+            # and no retry can succeed)
+            mx.counter("remote.dispatch.too_large").inc()
+            return {"ok": False, "error": str(e),
+                    "error_class": "MessageTooLarge"}
         except NotLeader as e:
             # expected replication answer, not a server fault: the client
             # fails over to the current leader (`_rediscover`)
@@ -430,9 +437,9 @@ class LedgerServer:
             return {"ok": True, "status": ev.status.value, "message": ev.message,
                     "tx_id": ev.tx_id, "transient": ev.transient}
         if op == "submit_many":
-            # deterministic multi-tx blocks over the wire: enqueue every
-            # request (each under ITS OWN extracted trace context), then
-            # cut + commit in arrival order — server half of
+            # a hand-over of several requests over the wire: all of
+            # them enter ordering together, then the blocks the policy
+            # cuts commit in arrival order — server half of
             # `RemoteNetwork.submit_many`
             # decode EVERY request before enqueuing ANY: a malformed
             # entry must fail the whole batch up front — enqueue-then-
@@ -446,14 +453,15 @@ class LedgerServer:
             # (zip would silently truncate the batch)
             traces = list(msg.get("traces") or ())[: len(parsed)]
             traces += [None] * (len(parsed) - len(traces))
-            subs = []
-            for request, wire in zip(parsed, traces):
-                with mx.use_trace(mx.TraceContext.from_wire(wire)):
-                    # cooperative under a bounded ordering queue — same
-                    # contract (and helper) as Network.submit_many
-                    subs.append(
-                        self.network.submit_request_cooperative(request)
-                    )
+            # each request under ITS OWN extracted trace context and
+            # with its raw segment's length (what the cut rules count);
+            # all of them enter ordering under one hold of the orderer's
+            # mutex, cooperative under a bounded queue — same contract
+            # (and helper) as Network.submit_many
+            subs = self.network.submit_requests([
+                (request, len(rb), mx.TraceContext.from_wire(wire))
+                for request, rb, wire in zip(parsed, msg["requests"], traces)
+            ])
             self.network.flush()
             events = [s.result() for s in subs]
             return {"ok": True, "events": [
@@ -608,6 +616,8 @@ class RemoteNetwork:
                 # the server's admission control rejected the submission
                 # BEFORE ordering: typed, retry-safe, exactly-once intact
                 raise Backpressure(resp.get("error", "ordering queue full"))
+            if resp.get("error_class") == "MessageTooLarge":
+                raise MessageTooLarge(resp.get("error", "message too large"))
             raise RemoteError(resp.get("error", "remote error"),
                               error_class=resp.get("error_class"))
         return resp
@@ -807,8 +817,11 @@ class RemoteNetwork:
 
     def submit_many(self, requests_bytes: List[bytes]) -> List[FinalityEvent]:
         """API parity with `Network.submit_many`: ship the whole batch in
-        ONE wire call; the server enqueues everything and cuts
-        deterministic blocks (`max_block_txs` txs each). Every request
+        ONE wire call; the server orders all of it together and its
+        `BlockPolicy` cuts the blocks (with the defaults, `max_block_txs`
+        txs each; under a batch timer, the channel's rules). A request
+        over the node's `absolute_max_bytes` fails the whole call with
+        `MessageTooLarge` before anything is ordered. Every request
         gets its OWN trace context, injected alongside the batch
         (`traces` field), so each tx's client leg, server orderer leg,
         batched verify, WAL append and finality stitch into one

@@ -14,6 +14,7 @@ import random
 import socket
 import struct
 import threading
+import time
 
 import pytest
 
@@ -138,6 +139,50 @@ def test_b300e5_served_verdicts_equal_the_scalar_reference(b300e5):
         # an output read back over the wire is the ledger's own bytes
         assert client.resolve_input(ID("pay0", 0)) == \
             server.network.resolve_input(ID("pay0", 0))
+    finally:
+        client.close()
+        server.stop()
+
+
+def test_b300e5_handover_is_cut_by_the_test_network_s_channel(b300e5):
+    """The same four transfers under the test network's channel values
+    (`zkatdlog-b300e5-testnet`: BatchTimeout 2 s, MaxMessageCount 10,
+    PreferredMaxBytes 512 KB, AbsoluteMaxBytes 99 MB): three are 500.7 KB
+    and the fourth overflows 524,288 B, so one `submit_many` is cut 3 + 1
+    by the ordering rules, the last at the timer, and every verdict is
+    still the scalar reference's."""
+    pp, raws = b300e5
+    ref_net = Network(RequestValidator(ZKATDLogDriver(pp)), policy=HOST_ONLY)
+    ref = [ref_net.submit(r) for r in raws]
+    sizes = [len(r) for r in raws[1:]]
+    assert sum(sizes[:3]) <= 524288 < sum(sizes)
+
+    policy = dataclasses.replace(
+        HOST_ONLY, linger_s=2.0, max_block_txs=10,
+        preferred_max_bytes=524288, absolute_max_bytes=103809024)
+    server = LedgerServer(
+        RequestValidator(ZKATDLogDriver(pp)), policy=policy).start()
+    client = RemoteNetwork(server.address, timeout=120)
+    try:
+        assert client.submit(raws[0]).status == ref[0].status  # a block of 1
+        wall0 = time.time()  # the flight ring is bounded: select by time
+        by_bytes0 = _counter("orderer.cut.by_bytes")
+        by_timeout0 = _counter("orderer.cut.by_timeout")
+        t0 = time.monotonic()
+        got = client.submit_many(raws[1:])
+        took = time.monotonic() - t0
+        assert [(e.tx_id, e.status, e.message) for e in got] == [
+            (e.tx_id, e.status, e.message) for e in ref[1:]]
+        cuts = [e for e in mx.FLIGHT.tail()
+                if e["kind"] == "block.cut" and e["ts"] >= wall0]
+        assert [(c["txs"], c["bytes"], c["reason"]) for c in cuts] == [
+            (3, sum(sizes[:3]), "bytes"), (1, sizes[3], "timeout")]
+        assert cuts[1]["waited_s"] == 2.0 <= took
+        assert _counter("orderer.cut.by_bytes") - by_bytes0 == 1
+        assert _counter("orderer.cut.by_timeout") - by_timeout0 == 1
+        net = server.network
+        assert [net.block(net.height() - k).txs for k in (2, 1)] == [
+            ["pay0", "pay1", "pay2"], ["pay3"]]
     finally:
         client.close()
         server.stop()

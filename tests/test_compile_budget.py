@@ -108,7 +108,8 @@ def test_stage_program_names_are_the_ten():
 
 
 @pytest.mark.parametrize("config", [
-    "fabtoken-fungible", "zkatdlog-b300e5", "zkatdlog-fungible"])
+    "fabtoken-fungible", "zkatdlog-b300e5", "zkatdlog-b300e5-testnet",
+    "zkatdlog-fungible"])
 def test_benchmark_configs_warm_only_registered_programs(config):
     """A name in a configuration's `warm_programs` that the registry
     does not have is a program the benchmark cannot compile ahead: it
