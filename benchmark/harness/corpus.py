@@ -138,19 +138,17 @@ def _tamper(raw: bytes) -> bytes:
     return bytes(bad)
 
 
-def build_group(dep: Deployment, seed: int, group: str, slots: list,
-                transfer: dict) -> tuple:
-    """`slots` is the group's plan: one entry per request, in the order in
-    which it will be sent, `{"kind": "ok" | <bad kind>, "of": <slot>}`
-    (`of`: the earlier slot a double spend re-spends). Returns (meta, blobs)."""
-    from fabric_token_sdk_tpu.api.request import (
-        IssueRecord, TokenRequest, TransferRecord,
-    )
-    from fabric_token_sdk_tpu.crypto.serialization import dumps, loads
-    from fabric_token_sdk_tpu.models.token import ID
+def build_issue(dep: Deployment, seed: int, group: str, slots: list,
+                transfer: dict) -> dict:
+    """The group's issue request, which the node needs before anything
+    else of the group: built first, so that the parent can hand it over
+    while the transfers are still being proved. -> what `build_group` goes
+    on from (the group's own random stream among it: the bytes of a group
+    do not depend on when the rest is built)."""
+    from fabric_token_sdk_tpu.api.request import IssueRecord, TokenRequest
 
     rng = random.Random(f"{seed}/{group}")
-    ins, outs = transfer["in_values"], transfer["out_values"]
+    ins = transfer["in_values"]
     k = len(ins)
     n = len(slots)
     anchor = f"bench-{group}"
@@ -165,8 +163,28 @@ def build_group(dep: Deployment, seed: int, group: str, slots: list,
         outputs_metadata=issue.metadata, receivers=[dep.owner_id] * (k * n)))
     req.issues[0].signature = dep.issuer_key.sign(req.marshal_to_sign(), rng)
     req.auditor_signature = dep.auditor_key.sign(req.marshal_to_audit(), rng)
-    blobs = [req.to_bytes()]
-    issue_s = time.monotonic() - t0
+    return {"group": group, "slots": slots, "transfer": transfer, "rng": rng,
+            "issue": issue, "blob": req.to_bytes(),
+            "issue_s": time.monotonic() - t0}
+
+
+def build_group(dep: Deployment, started: dict) -> tuple:
+    """The transfers that spend the issue of `build_issue`. `slots` is the
+    group's plan: one entry per request, in the order in which it will be
+    sent, `{"kind": "ok" | <bad kind>, "of": <slot>}` (`of`: the earlier
+    slot a double spend re-spends). Returns (meta, blobs)."""
+    from fabric_token_sdk_tpu.api.request import TokenRequest, TransferRecord
+    from fabric_token_sdk_tpu.crypto.serialization import dumps, loads
+    from fabric_token_sdk_tpu.models.token import ID
+
+    group, slots, rng, issue = (started[f] for f in
+                                ("group", "slots", "rng", "issue"))
+    ins, outs = (started["transfer"][f] for f in ("in_values", "out_values"))
+    k = len(ins)
+    n = len(slots)
+    anchor = f"bench-{group}"
+    blobs = [started["blob"]]
+    issue_s = started["issue_s"]
 
     def inputs(i):
         ids = [ID(f"{anchor}-issue", k * i + j) for j in range(k)]
@@ -243,13 +261,20 @@ def main(argv) -> int:
         raise RuntimeError("the corpus worker must be pinned to the CPU backend")
     sys.path.insert(0, ROOT)
     dep = Deployment(spec["config"], spec["art_dir"])
+    # every issue first: the parent hands them to the node meanwhile
+    started = []
     for g in spec["groups"]:
-        meta, blobs = build_group(dep, spec["seed"], g["group"], g["slots"],
-                                  spec["transfer"])
+        s = build_issue(dep, spec["seed"], g["group"], g["slots"],
+                        spec["transfer"])
+        write_group(os.path.join(spec["out_dir"], f"issue-{g['group']}.bin"),
+                    {"group": g["group"]}, [s["blob"]])
+        started.append(s)
+    for s in started:
+        meta, blobs = build_group(dep, s)
         t0 = time.monotonic()
         meta["ref"] = reference_verdicts(dep, blobs)
         meta["build"]["reference_s"] = round(time.monotonic() - t0, 3)
-        write_group(os.path.join(spec["out_dir"], f"group-{g['group']}.bin"),
+        write_group(os.path.join(spec["out_dir"], f"group-{s['group']}.bin"),
                     meta, blobs)
     return 0
 

@@ -166,6 +166,10 @@ class Generator:
             if h == height:
                 continue
             height, at = h, self.now()
+            if at >= end:
+                # noticed after the close: still queued as far as the
+                # window goes, not an attempt (and never a late answer)
+                return
             for e in self.entries:
                 if self.events[e["i"]]["done"] is None:
                     fin = watcher.status(e["tx_id"])

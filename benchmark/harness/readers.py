@@ -143,20 +143,34 @@ def padding_share(src, planes=None):
 
 @reader("dispatch_ms")
 def dispatch_ms(src, programs, rows_per_tile):
-    """Host-clock milliseconds per tile from the program's dispatch ledger:
-    a "plane:program" frame spans all the tiles of one call, transfers and
+    """Host-clock milliseconds per `rows_per_tile` dispatched rows from the
+    program's dispatch ledger, whatever height a dispatch has: a
+    "plane:program" frame spans all the tiles of one call, transfers and
     read-back included, and counts their rows."""
     hit = [e for k, e in src.dispatch.items() if k in programs]
     return _ratio(1e3 * sum(e["wall_s"] for e in hit),
                   sum(e["rows"] + e["padded_rows"] for e in hit) / rows_per_tile)
 
 
+def _whole_tiles(src, program, height_of, rows_per_tile):
+    """(device seconds, `rows_per_tile`-row tiles) of the whole dispatches
+    of `program` in the traced slice. A dispatch holds as many rows as the
+    ledger entry `height_of` says its newest dispatch had (`tile_rows`),
+    so the result does not depend on the height the program runs at."""
+    p = src.trace.get("programs", {}).get(program)
+    height = src.dispatch.get(height_of, {}).get("tile_rows", 0)
+    if not p or not p["dispatches"] or not height:
+        return 0.0, 0.0
+    return p["seconds"], p["dispatches"] * height / rows_per_tile
+
+
 @reader("trace_program_ms")
-def trace_program_ms(src, programs):
-    """Device milliseconds per dispatch of the named programs."""
-    hit = [v for k, v in src.trace.get("programs", {}).items() if k in programs]
-    return _ratio(1e3 * sum(v["seconds"] for v in hit),
-                  sum(v["dispatches"] for v in hit))
+def trace_program_ms(src, programs, height_of, rows_per_tile):
+    """Device milliseconds per `rows_per_tile` rows of the named programs:
+    the device time of their whole dispatches in the traced slice over the
+    rows those dispatches held."""
+    hit = [_whole_tiles(src, p, height_of, rows_per_tile) for p in programs]
+    return _ratio(1e3 * sum(s for s, _t in hit), sum(t for _s, t in hit))
 
 
 @reader("trace_idle_share")
@@ -176,18 +190,17 @@ def peak(device_kind: str, key: str) -> float:
 @reader("trace_roofline")
 def trace_roofline(src, work, peak_key):
     """Share of the compute roofline: needed operations / peak / kernel
-    time. `work` maps a program to the ops file's key for one row of it
-    and the rows one dispatch holds; needed = dispatches x rows x that
+    time on the device. `work` maps a program of the traced slice to the
+    ops file's key for one row of it and to the ledger entry that says how
+    many rows a dispatch holds; needed = whole dispatches x rows x that
     count x limb operations per field multiplication."""
     with open(os.path.join(HERE, "pairing_ops.json")) as fh:
         ops = json.load(fh)
     needed = seconds = 0.0
     for program, w in work.items():
-        p = src.trace.get("programs", {}).get(program)
-        if p:
-            needed += (p["dispatches"] * w["rows_per_dispatch"] * ops[w["per_row"]]
-                       * ops["limb_ops_per_fp_mul"])
-            seconds += p["seconds"]
+        s, rows = _whole_tiles(src, program, w["height_of"], 1)
+        needed += rows * ops[w["per_row"]] * ops["limb_ops_per_fp_mul"]
+        seconds += s
     if not seconds:
         return None
     return 100.0 * needed / peak(src.device_kind, peak_key) / seconds

@@ -47,22 +47,64 @@ def _spread(gaps: list, start: float, rng) -> list:
     return out
 
 
-def arrivals(mix: dict, seconds: float, seed: int) -> list:
+def arrivals(mix: dict, seconds: float, seed: int, draw: int = 0) -> list:
     """Due times, relative to the window's opening, sorted. The window
-    holds the same number of arrivals whatever the seed."""
+    holds the same number of arrivals whatever the seed. `draw` > 0 is the
+    seed's next order of the same gaps (`joint_layout`)."""
     proc = mix["arrivals"]
     if proc == "at_open":
         return [0.0] * int(mix["backlog_txs"])
     if proc != "poisson":
         raise ValueError(f"unknown arrival process {proc!r}")
     rate, warm = float(mix["rate_tps"]), float(mix.get("warm_s", 0.0))
-    rng = random.Random(f"{seed}/arrivals")
+    rng = random.Random(f"{seed}/arrivals" + (f"/{draw}" if draw else ""))
     out = _spread(_gaps(rate, warm), -warm, rng) if warm > 0 else []
     return out + _spread(_gaps(rate, seconds), 0.0, rng)
 
 
+DRAWS = 1000  # orders of the gaps tried for a `joint_layout`
+
+
 def plan(mix: dict, bad_kinds: list, seconds: float, seed: int) -> list:
-    due = arrivals(mix, seconds, seed)
+    """The seed's plan. Where the mix fixes a `joint_layout`, the first of
+    the seed's orders of the gaps that has it: the layout is part of the
+    work (how many hand-overs arrive whole, how many find a single arrival
+    just ahead of them), and every seed gets the same work in another
+    order."""
+    layout = mix.get("joint_layout")
+    for draw in range(DRAWS if layout else 1):
+        entries = _ordered(mix, seconds, seed, draw)
+        if not layout or _has_layout(entries, mix, layout, seconds):
+            break
+    else:
+        raise ValueError(f"no order of seed {seed}'s gaps in {DRAWS} has the "
+                         f"joint_layout {layout}")
+    rng = random.Random(f"{seed}/bad")
+    _place_bad(entries, bad_kinds, mix, seconds, rng)
+    return entries
+
+
+def _has_layout(entries, mix, layout, seconds) -> bool:
+    """Every hand-over holds its `txs`, and exactly `meetings` of them have
+    an arrival of their own (not in a hand-over) due in the
+    `meet_within_s` before them."""
+    joint = mix.get("joint", [])
+    sizes = [0] * len(joint)
+    for e in entries:
+        if "joint" in e:
+            sizes[e["joint"]] += 1
+    if sizes != [int(j["txs"]) for j in joint]:
+        return False
+    within = float(layout["meet_within_s"])
+    alone = [e["due_s"] for e in entries if "joint" not in e]
+    met = sum(1 for j in joint
+              if any(0.0 <= float(j["at_share"]) * seconds - t < within
+                     for t in alone))
+    return met == int(layout["meetings"])
+
+
+def _ordered(mix: dict, seconds: float, seed: int, draw: int) -> list:
+    due = arrivals(mix, seconds, seed, draw)
     n = len(due)
     entries = [{"i": i, "due_s": due[i], "group": f"g{i // GROUP_TXS}",
                 "slot": i % GROUP_TXS, "kind": "ok", "client": 0}
@@ -84,8 +126,6 @@ def plan(mix: dict, bad_kinds: list, seconds: float, seed: int) -> list:
         start = max(0, min(start, n - k_txs))  # the window's last ones at the latest
         for e in entries[start:start + k_txs]:
             e["joint"], e["due_s"] = k, at
-    rng = random.Random(f"{seed}/bad")
-    _place_bad(entries, bad_kinds, mix, seconds, rng)
     return entries
 
 

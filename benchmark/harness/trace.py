@@ -102,12 +102,17 @@ def reduce(trace: dict, window_ns: tuple = None, host: list = ()) -> dict:
     whatever `start` says: device tracing comes up some tens of
     milliseconds after the trace starts, and what ran before that is not
     in the trace at all, so that stretch is unknown, not idle. A program
-    that the slice's end cut short, or that was already running when the
-    device tracer came up (its event then begins with the trace, short of
-    its head), counts as busy time but not as a dispatch of its program: a
-    slice has to span two dispatches of a program to be sure of a whole
-    one. `host`: the benchmark's spans, (name, start_ns, end_ns) on the
-    same clock."""
+    that was already running when the device tracer came up (its event then
+    begins with the trace, short of its head), or that the trace's stop cut
+    short, counts as busy time but not as a dispatch of its program. The
+    first program event of a trace is taken for the former, whole or not.
+    One cut by the stop is the last event of all and ends with the trace,
+    which is stopped no earlier than the slice's `end`: the operation it
+    was in is never written, so its event runs past the last operation's.
+    A last event that ended before `end` ran to its end. (With no
+    `window_ns` the trace is taken to stop with its last operation.)
+    `host`: the benchmark's spans, (name, start_ns, end_ns) on the same
+    clock."""
     devices = [p for p in trace["planes"] if is_device_plane(p["name"])]
     if not devices:
         raise ValueError("the trace holds no device plane")
@@ -126,12 +131,16 @@ def reduce(trace: dict, window_ns: tuple = None, host: list = ()) -> dict:
         lo = min(ev[1] for ev in base)
         last = max(ev[1] + ev[2] for ev in base)
         hi = last if window_ns is None else max(window_ns[1], lo)
+        stopped = max([last] + [ev[1] + ev[2] for ev in mod_events])
         merged = union([[max(ev[1], lo), min(ev[1] + ev[2], hi)] for ev in base
                         if ev[1] < hi and ev[1] + ev[2] > lo])
         busy_s.append(sum(e - s for s, e in merged) / 1e9)
         window_s.append((hi - lo) / 1e9)
         for name, start, dur in mod_events:
-            if start - lo <= CUT_NS or abs(start + dur - last) <= CUT_NS:
+            end = start + dur
+            by_stop = (abs(end - last) <= CUT_NS if window_ns is None
+                       else stopped - end <= CUT_NS and end >= hi)
+            if start - lo <= CUT_NS or by_stop:
                 continue  # running when the trace came up, or when it stopped
             p = programs.setdefault(program_of(name), [0, 0.0])
             p[0] += 1

@@ -25,6 +25,7 @@ T_START = time.monotonic()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -49,21 +50,21 @@ OUT_DIR = os.path.join(ROOT, "benchmark_out")
 os.environ.setdefault("FTS_FLIGHT_EVENTS", "400000")
 
 # counters that move only when a device plane gave its work to the host
-# (chip_smoke.py's list)
+# (chip_smoke.py's list, less the names nothing emits since PR 30)
 FALLBACK_COUNTERS = (
     "ledger.block.batch_errors", "batch.sign.host_fallbacks",
-    "batch.prove.host_fallbacks", "sharding.fallbacks",
-    "sharding.breaker_skips", "resilience.bounded.timeouts",
+    "batch.prove.host_fallbacks", "resilience.bounded.timeouts",
     "resilience.breaker.open", "resilience.breaker.rejected",
     "native.selfcheck.fail", "jax.cache.load_failures",
 )
-FALLBACK_EVENTS = ("verify.host_fallback", "sign.host_fallback",
-                   "sharding.fallback")
+FALLBACK_EVENTS = ("verify.host_fallback", "sign.host_fallback")
 # shown in every run's `window:` line; the cell's reader files add theirs
 WINDOW_COUNTERS = ("ledger.validate.batched", "ledger.validate.host",
                    "batch.sign.rows", "batch.sign.host",
                    "ledger.blocks.committed")
 COMPILES = "jax.core.compile.backend_compile_duration.seconds"
+# fields of a dispatch-ledger entry that add up over a window
+DISPATCH_SUMS = ("rows", "padded_rows", "dispatches", "wall_s")
 CLIENT_TIMEOUT_S = 900.0
 
 
@@ -107,12 +108,61 @@ def start_corpus(cell: dict, seed: int, entries: list, workdir: str) -> dict:
     return {"art_dir": art_dir, "procs": procs, "groups": names}
 
 
-def finish_corpus(corpus: dict, workdir: str) -> dict:
-    for p in corpus["procs"]:
-        if p.wait() != 0:
-            raise RuntimeError(f"corpus worker exited {p.returncode}")
-    return {g: read_group(os.path.join(workdir, f"group-{g}.bin"))
-            for g in corpus["groups"]}
+class Issues(threading.Thread):
+    """Hands each group's issue to the node as soon as its corpus worker
+    has written it (the workers build their issues first), one `submit`
+    after another (one issue a block, which the host validates: 0.075 s an
+    output), so that the issues run beside the proving of the transfers and
+    the loading of the cell's programs and not behind them. `result()`
+    joins, waits for the workers and gives the corpus: group -> (meta,
+    blobs)."""
+
+    def __init__(self, client, corpus: dict, workdir: str):
+        super().__init__(daemon=True)
+        self.client, self.procs, self.workdir = client, corpus["procs"], workdir
+        self.groups = list(corpus["groups"])
+        self.error = None
+        self.first_at = self.busy_s = 0.0
+
+    def path(self, kind: str, g: str) -> str:
+        return os.path.join(self.workdir, f"{kind}-{g}.bin")
+
+    def run(self) -> None:
+        todo = set(self.groups)
+        try:
+            while todo:
+                # the workers' state first: one writes its issues, then
+                # its groups, then exits
+                codes = [p.poll() for p in self.procs]
+                ready = sorted(g for g in todo
+                               if os.path.exists(self.path("issue", g)))
+                if not ready:
+                    if any(codes) or None not in codes:
+                        raise RuntimeError(f"corpus workers exited {codes} "
+                                           f"without the issues of {sorted(todo)}")
+                    time.sleep(0.05)
+                    continue
+                self.first_at = self.first_at or time.monotonic() - T_START
+                for g in ready:
+                    t0 = time.monotonic()
+                    ev = self.client.submit(read_group(self.path("issue", g))[1][0])
+                    if ev.status.value != "Valid":
+                        raise RuntimeError(f"issue of {g} rejected: {ev.message}")
+                    todo.discard(g)
+                    self.busy_s += time.monotonic() - t0
+        except Exception as e:  # handed to the thread that joins
+            self.error = e
+
+    def result(self) -> dict:
+        self.join()
+        if self.error is not None:
+            raise self.error
+        log(f"issues of {len(self.groups)} groups: the first was built "
+            f"t+{self.first_at:.1f}s, submitting took {self.busy_s:.1f}s in all")
+        for p in self.procs:
+            if p.wait() != 0:
+                raise RuntimeError(f"corpus worker exited {p.returncode}")
+        return {g: read_group(self.path("group", g)) for g in self.groups}
 
 
 def find_devices(chips: int, rehearse: bool) -> dict:
@@ -194,23 +244,21 @@ def snapshot(counters=(), histograms=()) -> dict:
         "histograms": {h: (mx.REGISTRY.histogram(h).buckets,
                            mx.REGISTRY.histogram(h).state()[0])
                        for h in histograms},
-        "dispatch": {f"{pl}:{prog}": {"rows": e["rows"],
-                                      "padded_rows": e["padded_rows"],
-                                      "dispatches": e["dispatches"],
-                                      "wall_s": e["wall_s"]}
+        "dispatch": {f"{pl}:{prog}": {f: e[f] for f in (*DISPATCH_SUMS, "tile_rows")}
                      for (pl, prog), e in devobs.snapshot().items()},
     }
 
 
 def delta(a: dict, b: dict) -> dict:
-    keys = ("rows", "padded_rows", "dispatches", "wall_s")
     return {
         "counters": {c: b["counters"][c] - a["counters"][c] for c in b["counters"]},
         "compiles": b["compiles"] - a["compiles"],
         "histograms": {h: (bounds, [y - x for x, y in
                                     zip(a["histograms"][h][1], counts)])
                        for h, (bounds, counts) in b["histograms"].items()},
-        "dispatch": {k: {f: e[f] - a["dispatch"].get(k, {}).get(f, 0) for f in keys}
+        # `tile_rows` is the height of the newest dispatch, not a sum
+        "dispatch": {k: dict({f: e[f] - a["dispatch"].get(k, {}).get(f, 0)
+                              for f in DISPATCH_SUMS}, tile_rows=e["tile_rows"])
                      for k, e in b["dispatch"].items()},
     }
 
@@ -225,8 +273,6 @@ def install_spans() -> None:
     """`jax.profiler.TraceAnnotation`s around the calls into each layer,
     put there at run time by the benchmark (traced runs only): the names
     by which the trace reduction attributes the device's idle gaps."""
-    import functools
-
     import jax
 
     from fabric_token_sdk_tpu.api.validator import RequestValidator
@@ -268,22 +314,34 @@ def sleep_until(t: float) -> None:
         time.sleep(d)
 
 
-def set_up_ledger(client, corpus: dict) -> int:
-    """The issues, then one small block through every plane the cell uses,
-    so that no program is dispatched first inside the window. -> how many
-    of the warm block's (valid) requests were rejected."""
-    t0 = time.monotonic()
-    for g in corpus:
-        ev = client.submit(corpus[g][1][0])
-        if ev.status.value != "Valid":
-            raise RuntimeError(f"issue of {g} rejected: {ev.message}")
+def stand_up(cell: dict, dep: Deployment, workdir: str) -> dict:
+    """The node: `Network` under the configuration's policy with its WAL,
+    served on loopback, and the harness's own connection to it."""
+    from fabric_token_sdk_tpu.services.network import BlockPolicy
+    from fabric_token_sdk_tpu.services.network.remote import (
+        LedgerServer, RemoteNetwork,
+    )
+
+    policy = dataclasses.replace(BlockPolicy(), **cell["config"].get("policy", {}))
+    wal_path = os.path.join(workdir, "ledger.wal")
+    net = dep.network(policy, wal_path=wal_path)
+    server = LedgerServer(network=net).start()
+    client = RemoteNetwork(server.address, timeout=CLIENT_TIMEOUT_S)
+    return {"net": net, "server": server, "client": client, "wal_path": wal_path}
+
+
+def warm_block(client, corpus: dict) -> int:
+    """One small block through every plane the cell uses, so that no
+    program is dispatched first inside the window. -> how many of its
+    (valid) requests were rejected."""
     rejected = 0
     if "warm" in corpus:
+        t0 = time.monotonic()
         for ev in client.submit_many(corpus["warm"][1][1:]):
             if ev.status.value != "Valid":
                 rejected += 1
                 log(f"warm block: {ev.tx_id} rejected: {ev.message}")
-    log(f"issues + warm block {time.monotonic() - t0:.1f}s")
+        log(f"warm block {time.monotonic() - t0:.1f}s")
     return rejected
 
 
@@ -317,12 +375,20 @@ def trace_slice(spec: dict, t_open: float, seconds: float, trace_dir: str) -> tu
 
     for_s = min(float(spec["for_s"]), seconds / 4)
     sleep_until(t_open + float(spec["at_share"]) * seconds)
-    key = spec.get("after_dispatch")
-    if key:
-        # from there, wait for the phase the slice is meant to see: a
-        # dispatch of that program in the program's own ledger
-        seen = dispatches(key)
-        while dispatches(key) == seen and time.monotonic() < t_open + seconds - 3.0:
+    # from there, wait for the phase the slice is meant to see: a dispatch
+    # of that program entered in the program's own ledger (the frame closes
+    # at its last read-back: what follows it is under the slice), or a
+    # counter the program moves before the phase begins (what it announces
+    # is under the slice whole)
+    if spec.get("after_counter"):
+        moved = functools.partial(counter, spec["after_counter"])
+    elif spec.get("after_dispatch"):
+        moved = functools.partial(dispatches, spec["after_dispatch"])
+    else:
+        moved = None
+    if moved:
+        seen = moved()
+        while moved() == seen and time.monotonic() < t_open + seconds - 3.0:
             time.sleep(0.005)
     jax.profiler.start_trace(trace_dir)
     t_trace = time.monotonic()
@@ -334,27 +400,19 @@ def trace_slice(spec: dict, t_open: float, seconds: float, trace_dir: str) -> tu
     return t_trace, for_s
 
 
-def run_once(cell: dict, dep: Deployment, corpus: dict, entries: list,
-             seconds: float, trace: bool, workdir: str, setup_from: float,
-             drain: bool) -> dict:
+def run_once(cell: dict, dep: Deployment, node: dict, corpus: dict,
+             entries: list, seconds: float, trace: bool, workdir: str,
+             setup_from: float, drain: bool) -> dict:
     import jax
 
-    from fabric_token_sdk_tpu.services.network import BlockPolicy
-    from fabric_token_sdk_tpu.services.network.remote import (
-        LedgerServer, RemoteNetwork,
-    )
     from fabric_token_sdk_tpu.utils import metrics as mx, resilience
 
     mix = cell["mix"]
     grace_s = float(mix.get("grace_s", 0.0))
-    policy = dataclasses.replace(BlockPolicy(), **cell["config"].get("policy", {}))
-    wal_path = os.path.join(workdir, "ledger.wal")
-    net = dep.network(policy, wal_path=wal_path)
-    server = LedgerServer(network=net).start()
-    client = RemoteNetwork(server.address, timeout=CLIENT_TIMEOUT_S)
+    net, server, client = node["net"], node["server"], node["client"]
     gen = None
     try:
-        warm_rejected = set_up_ledger(client, corpus)
+        warm_rejected = warm_block(client, corpus)
         for e in entries:
             e["tx_id"] = corpus[e["group"]][0]["tx_ids"][e["slot"]]
         gen, result_path = start_generator(server.address, mix, entries,
@@ -401,8 +459,7 @@ def run_once(cell: dict, dep: Deployment, corpus: dict, entries: list,
         if gen is not None and gen.poll() is None:
             gen.kill()
             gen.wait()
-        client.close()
-        server.stop()
+        shut_down(node)  # no new work; what is in flight goes on
         if drain:
             # a run per seed in one process: the next window must not share
             # the device with what this one left in flight
@@ -411,8 +468,9 @@ def run_once(cell: dict, dep: Deployment, corpus: dict, entries: list,
                 time.sleep(0.1)
 
     events = result["events"]
-    log_window(mix, events, seconds, grace_s, blocks, win, t_open if trace else None)
-    checks = judge(dep, corpus, entries, events, wal_path, final, win,
+    log_window(mix, events, seconds, grace_s, blocks, win,
+               t_open + wall_offset, t_open if trace else None)
+    checks = judge(dep, corpus, entries, events, node["wal_path"], final, win,
                    fallback_events, breakers, result, warm_rejected)
     if result["drained_at"] is not None:
         log(f"drained: the backlog was final {result['drained_at']:.2f}s into "
@@ -423,7 +481,13 @@ def run_once(cell: dict, dep: Deployment, corpus: dict, entries: list,
             "peak_bytes": peak}
 
 
-def log_window(mix, events, seconds, grace_s, blocks, win, spans_from) -> None:
+def shut_down(node: dict) -> None:
+    node["client"].close()
+    node["server"].stop()
+
+
+def log_window(mix, events, seconds, grace_s, blocks, win, opened_unix,
+               spans_from) -> None:
     """One line to stderr on what the window held (and, traced, the
     benchmark's spans): for the reader of a run, not for the driver."""
     lat = stats.finality_latencies(events, seconds, grace_s)
@@ -433,6 +497,7 @@ def log_window(mix, events, seconds, grace_s, blocks, win, spans_from) -> None:
             events, seconds, grace_s, mix["committed_tps_rule"]), 4),
         "blocks": len(blocks), "device_blocks": len(dev),
         "block_txs_max": max((len(b["txs"]) for b in blocks), default=0),
+        "commits_at_s": [round(b["ts"] - opened_unix, 2) for b in blocks][:16],
         "device_verify_s": round(sum(b.get("device_verify_s", 0) for b in blocks), 3),
         "sign_verify_s": round(sum(b.get("sign_verify_s", 0) for b in blocks), 3),
         "counters": {k: v for k, v in win["counters"].items() if v},
@@ -469,6 +534,11 @@ def same_verdict(got, ref) -> bool:
     return got[0] == "Invalid" and bool(head) and head == ref[1].split(": ", 1)[0]
 
 
+def check_lines(rows: list) -> list:
+    return [f"check {name}={value} limit {limit} {'ok' if ok else 'FAILED'}"
+            for name, value, limit, ok in rows]
+
+
 def judge(dep, corpus, entries, events, wal_path, final, win,
           fallback_events, breakers, gen_result, warm_rejected) -> list:
     """Every number compared, beside its limit: [name, value, limit, ok]."""
@@ -494,8 +564,15 @@ def judge(dep, corpus, entries, events, wal_path, final, win,
     bad_due = [e for e in entries if e["kind"] != "ok"]
     bad_judged = sum(1 for e in bad_due
                      if any(ev["i"] == e["i"] for ev in answered))
-    # durability: re-open the journal from disk, as a restarted node does
-    recovered = Network.recover(dep.validator(), wal_path)
+    # durability: re-open the journal from disk, as a restarted node does.
+    # From a copy of the bytes on disk: a backlog's node is still
+    # committing its queue on its own threads (journal first, so that a
+    # compaction in between finds the copy a prefix the snapshot covers)
+    reopened = wal_path + ".reopened"
+    shutil.copyfile(wal_path, reopened)
+    if os.path.exists(wal_path + ".snap"):
+        shutil.copyfile(wal_path + ".snap", reopened + ".snap")
+    recovered = Network.recover(dep.validator(), reopened)
     acked = [ev for ev in answered if ev["status"] == "Valid"]
     wal_missing = 0
     for ev in acked:
@@ -521,9 +598,8 @@ def judge(dep, corpus, entries, events, wal_path, final, win,
          int(gen_result["jax_imported"] or gen_result["ops_imported"]), "0",
          not (gen_result["jax_imported"] or gen_result["ops_imported"])),
     ]
-    for name, value, limit, ok in rows:
-        print(f"check {name}={value} limit {limit} {'ok' if ok else 'FAILED'}",
-              flush=True)
+    for ln in check_lines(rows):
+        print(ln, flush=True)
     for ex in examples[:3]:
         print(f"  differing verdict: {ex}", flush=True)
     if moved or open_breakers:
@@ -537,9 +613,14 @@ def judge(dep, corpus, entries, events, wal_path, final, win,
 def end_to_end(cell: dict, run: dict) -> dict:
     ev, s, g = run["events"], run["seconds"], run["grace_s"]
     lat = stats.finality_latencies(ev, s, g)
+    p50 = stats.percentile(lat, 0.5) if lat else None
     values = {
         "committed_tps": stats.committed_tps(ev, s, g, cell["mix"]["committed_tps_rule"]),
-        "finality_p50_s": stats.percentile(lat, 0.5) if lat else None,
+        # the same median under two names, each with a bound of its own: a
+        # cell lists `.host` where the host verifies its median block (a
+        # 0.17 s time that moves with the box's cores, not with the chip)
+        "finality_p50_s": p50,
+        "finality_p50_s.host": p50,
         "setup_s": run["setup_s"],
     }
     return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
@@ -551,6 +632,9 @@ def per_layer(cell: dict, run: dict, device: dict, rehearse: bool):
     import trace as tr
 
     reduced = {}
+    log("dispatch ledger of the window: " + json.dumps(
+        {k: dict(e, wall_s=round(e["wall_s"], 4))
+         for k, e in run["win"]["dispatch"].items() if e["dispatches"]}))
     if run["trace_dir"] and not rehearse:
         loaded = tr.load_xplane(run["trace_dir"])
         log("trace planes: " + "; ".join(
@@ -652,8 +736,6 @@ def main(argv=None) -> int:
         print(f"[bench] refused: {e}", file=sys.stderr, flush=True)
         return 2
     log(f"device {device}")
-    warm = warm_programs(cell["config"]["warm_programs"])
-    log(f"warm {warm}")
     if args.trace:
         install_spans()
 
@@ -661,11 +743,23 @@ def main(argv=None) -> int:
     for k in range(len(seeds)):
         nxt = None
         try:
-            corpus = finish_corpus(corpus_job, workdir)
+            dep = Deployment(one["config"], corpus_job["art_dir"])
+            node = stand_up(one, dep, workdir)
+            try:
+                issues = Issues(node["client"], corpus_job, workdir)
+                issues.start()
+                if k == 0:
+                    # the issues are validated on the host meanwhile
+                    warm = warm_programs(cell["config"]["warm_programs"])
+                    log(f"warm {warm}")
+                corpus = issues.result()
+            except BaseException:
+                shut_down(node)
+                raise
             log(f"corpus of seed {seeds[k]}: " + ", ".join(
                 f"{g} {corpus[g][0]['build']}" for g in list(corpus)[:2]))
-            dep = Deployment(one["config"], corpus_job["art_dir"])
-            run = run_once(one, dep, corpus, entries, args.seconds,
+            # the run shuts the node down, whatever happens in it
+            run = run_once(one, dep, node, corpus, entries, args.seconds,
                            bool(args.trace), workdir, setup_from,
                            drain=k + 1 < len(seeds))
             line = result_line(one, run, device, bool(args.trace),
@@ -674,6 +768,12 @@ def main(argv=None) -> int:
                 line["seed"], line["rate_tps"] = seeds[k], one["mix"].get("rate_tps")
                 extra = end_to_end(one, run) if args.trace else {}
                 line["metrics"] = dict(extra, **line["metrics"])
+            # every number compared beside its limit: last in the line, and
+            # the last lines on stderr (`judge` printed them to stdout)
+            line["checks"] = {name: {"value": value, "limit": limit}
+                              for name, value, limit, _ok in run["checks"]}
+            print("\n".join(check_lines(run["checks"])), file=sys.stderr,
+                  flush=True)
             print(json.dumps(line), flush=True)
             if k + 1 < len(seeds):
                 # only now: corpus workers beside a window stall the node
