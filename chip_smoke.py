@@ -381,8 +381,10 @@ def phase_tiles(run: Run, rng, pairing: bool) -> None:
               "g1_msm3_tile disagrees with hostmath.g1_multiexp")
 
         if pairing:
-            # 8 rows x 2 legs = one miller tile, one product + final-exp tile
-            legs = [[(g1(), g2()), (g1(), g2())] for _ in range(pr.FEXP_TILE)]
+            # one product + final-exp tile of 2-leg rows, whatever Miller
+            # tiles they make (on a TPU 128 rows x 2 legs: two of 128)
+            legs = [[(g1(), g2()), (g1(), g2())]
+                    for _ in range(st.tile_rows("fexp_tile"))]
             Ps = np.stack([pr.encode_g1([p for p, _ in row]) for row in legs])
             Qs = np.stack([pr.encode_g2([q for _, q in row]) for row in legs])
             got = tw.decode_fp12(twice(

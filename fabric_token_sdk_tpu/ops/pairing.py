@@ -313,21 +313,22 @@ def gt_is_one_host(arr) -> np.ndarray:
 # The pairing product runs as shape-stable tile programs compiled once
 # and shared by every verifier and batch size:
 #   * miller tile  — (tile_rows("miller_tile"), ...) pairs (1 program, ever)
-#   * row product  — (FEXP_TILE, K, ...) tree fp12 mul   (tiny, per K)
-#   * final-exp    — (FEXP_TILE, ...) GT rows            (1 program, ever)
+#   * row product  — (tile_rows("fexp_tile"), K, ...) tree fp12 mul
+#                                                        (tiny, per K)
+#   * final-exp    — (tile_rows("fexp_tile"), ...) GT rows
+#                                                        (1 program, ever)
 # Tiles pad with generator pairs / GT ones; padding is masked out before
 # the product so results are exact.
 #
-# The Miller tile's height is the backend's, as the stage tiles' is
-# (`stages.tile_rows`: 16 pairs off the chip, 128 on a TPU, where a warm
-# dispatch of 128 costs 96.5 ms against 93.0 ms for 16; the sweep and
-# the rule stand beside the number in `ops/stages.py`). Everything here
-# that needs it asks that function when it runs: the walk, the padding
-# and tile count, the ledger frame and `ops/warmup.py`'s shape. The
-# final-exp tile and the row product keep 8 rows: three readers of the
-# benchmark are tied to that height (PERF.md section 7).
-
-FEXP_TILE = 8
+# Both heights are the backend's, as the stage tiles' is
+# (`stages.tile_rows`; the sweeps and the rules stand beside the numbers
+# in `ops/stages.py`): 16 pairs and 8 GT rows off the chip; on a TPU
+# 128 of each, where a warm Miller dispatch of 128 costs 96.5 ms
+# against 93.0 ms for 16 and a warm product + final-exp dispatch of
+# 128 rows 207.4 ms against 193.6 ms for 8.
+# Everything here that needs a height asks that function when it runs:
+# the two walks, the padding and tile counts, the ledger frames and
+# `ops/warmup.py`'s shapes. One shape per program name per backend.
 
 
 @functools.lru_cache(maxsize=None)
@@ -370,9 +371,10 @@ def _fexp_tiles(frame, f, start: int, stop: int):
     """Sequential product+final-exp walk over [start, stop) tile indices
     (enqueue, then read back, per tile — as `_miller_tiles`)."""
     outs = []
-    for t in range(start * FEXP_TILE, stop * FEXP_TILE, FEXP_TILE):
+    T = st.tile_rows("fexp_tile")
+    for t in range(start * T, stop * T, T):
         with frame.tile():
-            gt = final_exp(_product_rows(jnp.asarray(f[t : t + FEXP_TILE])))
+            gt = final_exp(_product_rows(jnp.asarray(f[t : t + T])))
         with frame.wait():
             outs.append(np.asarray(gt))
     return outs
@@ -430,13 +432,15 @@ def pairing_product_staged(Ps, Qs, inf_mask=None):
         f[mask] = one_np
         f = f[:N].reshape(B, K, 6, 2, L)
         # pad rows BEFORE the product so both the per-K product program and
-        # the final-exp program see only (FEXP_TILE, ...) shapes
-        padB = (-B) % FEXP_TILE
+        # the final-exp program see only (tile_rows("fexp_tile"), ...)
+        # shapes
+        TF = st.tile_rows("fexp_tile")
+        padB = (-B) % TF
         if padB:
             f = np.concatenate(
                 [f, np.broadcast_to(one_np, (padB, K, 6, 2, L))], axis=0
             )
-        n_fexp = (B + padB) // FEXP_TILE
+        n_fexp = (B + padB) // TF
         mx.counter("pairing.staged.fexp_tiles").inc(n_fexp)
         with devobs.dispatch(
             "fexp_tile", rows=B, padded_rows=padB, tiles=n_fexp

@@ -13,9 +13,10 @@ the total distinct-program count is a small constant — independent of
 batch size, transfer shape, and parameter set.
 
 Stage inventory (`tile_rows(program)` flat rows each — one height per
-program per backend, see `tile_rows`, which also holds the height of
-the staged pairing product's Miller tile; tables/keys are ARGUMENTS,
-not baked constants, so one executable serves every parameter set):
+program per backend, see `tile_rows`, which also holds the heights of
+the staged pairing product's Miller and final-exp tiles; tables/keys
+are ARGUMENTS, not baked constants, so one executable serves every
+parameter set):
 
   G1:  msm tile (per nbases in {1,2,3}), variable-base scalar-mul tile,
        Jacobian add tile, Jacobian sub tile (add + neg fused),
@@ -101,6 +102,31 @@ _TPU_TILE_ROWS = 128
 # 512 is out (387 ms for a block of 32).
 _HOST_MILLER_ROWS = 16
 _TPU_MILLER_ROWS = 128
+# The final-exp tile of the same product (ledger frame `fexp_tile`: the
+# row product `_product_rows` and `final_exp` of one dispatch) is the
+# third height, found the same way. Off the chip 8 rows, as ever (the
+# CPU backend takes 177 s to compile `final_exp` and seconds a tile).
+# On the chip one warm dispatch (transfer in, both programs,
+# read-back; 4 legs a row, 2 legs 0.6-1.9 ms less) costs, ms at 8 / 16 /
+# 32 / 64 / 128 / 256 rows (sweep: PERF.md section 6, PR 35): 193.6 /
+# 212.0 / 229.7 / 217.8 / 207.4 / 447.2, i.e. 193.6 / 106.0 / 57.4 /
+# 27.2 / 13.0 / 14.0 per 8 rows: nearly flat up to 128 rows (worst at
+# 32, where the chip's compiler mixes its two layouts), more than
+# double from there; peak bytes in use 155-179 MB at every height to
+# 128, 297 MB at 256; a cold compile of `final_exp` 271-296 s at any
+# height. The rule: over the final-exp calls the benchmark's blocks
+# make (256 rows: a 64-tx (2,2) block at base 100 / exponent 2; 80:
+# an 8-tx block at base 300 / exponent 5; 30: a 3-tx block of the
+# test network's channel; 8: a 2-tx block, the smallest the device
+# sees) take the T <= 128 that minimises the sum of ceil(rows / T) *
+# c(T) among the T with c(T) <= 1.5 * c(8), so that the smallest block
+# loses at most half again. 8: 47 dispatches, 9,098 ms; 16: 5,088;
+# 32: 2,986; 64: 1,742; 128: 5 dispatches, 1,037 ms, and c(128) =
+# 1.07 * c(8). Not above 128: a 64-tx block must keep two dispatches
+# for the benchmark's traced slice (PERF.md section 7), and 256 costs
+# 2.16 * c(128).
+_HOST_FEXP_ROWS = 8
+_TPU_FEXP_ROWS = 128
 
 
 @functools.cache
@@ -111,10 +137,12 @@ def _on_tpu() -> bool:
 
 def tile_rows(program: str) -> int:
     """Rows one dispatch of tile program `program` (a
-    `stage_programs()` name, or `miller_tile`) holds on this process's
-    backend."""
+    `stage_programs()` name, `miller_tile` or `fexp_tile`) holds on
+    this process's backend."""
     if program == "miller_tile":
         return _TPU_MILLER_ROWS if _on_tpu() else _HOST_MILLER_ROWS
+    if program == "fexp_tile":
+        return _TPU_FEXP_ROWS if _on_tpu() else _HOST_FEXP_ROWS
     return _TPU_TILE_ROWS if _on_tpu() else _HOST_TILE_ROWS
 
 

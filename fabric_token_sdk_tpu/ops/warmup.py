@@ -42,9 +42,10 @@ def pairing_programs() -> Iterable[Tuple[str, object, tuple]]:
     L = lb.NLIMBS
     T = st.tile_rows("miller_tile")
     yield ("miller_tile", pr.miller_loop, ((T, 2, L), (T, 2, 2, L)))
+    F = st.tile_rows("fexp_tile")
     for k in (2, 4):
-        yield (f"gt_product_k{k}_tile", pr._product_rows, ((pr.FEXP_TILE, k, 6, 2, L),))
-    yield ("final_exp_tile", pr.final_exp, ((pr.FEXP_TILE, 6, 2, L),))
+        yield (f"gt_product_k{k}_tile", pr._product_rows, ((F, k, 6, 2, L),))
+    yield ("final_exp_tile", pr.final_exp, ((F, 6, 2, L),))
 
 
 # Program-set classification for `cmd/ftswarmup.py --list` and the
