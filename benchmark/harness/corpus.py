@@ -2,8 +2,10 @@
 
 Run as a child process of `benchmark/run.py` (never inside the measured
 window, never on the chip: the parent pins this process to the CPU
-backend). One *group* is an issue request plus the transfers that spend
-its outputs; groups share no token, so each is built and judged alone.
+backend). One *group* is a set-up issue request plus the requests that
+spend its outputs, each of the *form* its slot names (a transfer of any
+shape, a redeem, or an issue of the issuer's that spends nothing); groups
+share no token, so each is built and judged alone.
 For every slot of a group the file holds the signed wire request, the
 verdict expected by construction, and the verdict of the plain reference:
 the same bytes, in slot order, through the scalar host `RequestValidator`
@@ -138,63 +140,80 @@ def _tamper(raw: bytes) -> bytes:
     return bytes(bad)
 
 
-def build_issue(dep: Deployment, seed: int, group: str, slots: list,
-                transfer: dict) -> dict:
-    """The group's issue request, which the node needs before anything
-    else of the group: built first, so that the parent can hand it over
-    while the transfers are still being proved. -> what `build_group` goes
-    on from (the group's own random stream among it: the bytes of a group
-    do not depend on when the rest is built)."""
+def _issue_request(dep: Deployment, tx_id: str, values: list, rng) -> tuple:
+    """An issue of `values` to the owner, signed by the issuer and the
+    auditor. -> (the driver's outcome, the request's bytes)"""
     from fabric_token_sdk_tpu.api.request import IssueRecord, TokenRequest
 
-    rng = random.Random(f"{seed}/{group}")
-    ins = transfer["in_values"]
-    k = len(ins)
-    n = len(slots)
-    anchor = f"bench-{group}"
-
-    t0 = time.monotonic()
+    owners = [dep.owner_id] * len(values)
     kw = {"anonymous": False, "rng": rng} if dep.zk else {}
-    issue = dep.driver.issue(dep.issuer_id, "USD", ins * n,
-                             [dep.owner_id] * (k * n), **kw)
-    req = TokenRequest(anchor=f"{anchor}-issue")
+    issue = dep.driver.issue(dep.issuer_id, "USD", values, owners, **kw)
+    req = TokenRequest(anchor=tx_id)
     req.issues.append(IssueRecord(
         action=issue.action_bytes, issuer=dep.issuer_id,
-        outputs_metadata=issue.metadata, receivers=[dep.owner_id] * (k * n)))
+        outputs_metadata=issue.metadata, receivers=owners))
     req.issues[0].signature = dep.issuer_key.sign(req.marshal_to_sign(), rng)
     req.auditor_signature = dep.auditor_key.sign(req.marshal_to_audit(), rng)
-    return {"group": group, "slots": slots, "transfer": transfer, "rng": rng,
-            "issue": issue, "blob": req.to_bytes(),
+    return issue, req.to_bytes()
+
+
+def build_issue(dep: Deployment, seed: int, group: str, slots: list,
+                forms: dict) -> dict:
+    """The group's set-up issue, which the node needs before anything else
+    of the group: what its slots spend, one after another (an issue slot
+    spends nothing). Built first, so that the parent can hand it over while
+    the rest is still being proved. -> what `build_group` goes on from (the
+    group's own random stream among it: the bytes of a group do not depend
+    on when the rest is built)."""
+    rng = random.Random(f"{seed}/{group}")
+    t0 = time.monotonic()
+    values, first = [], []  # a slot's inputs start at its offset
+    for slot in slots:
+        first.append(len(values))
+        values += forms[slot.get("form", "")].get("in_values", [])
+    issue, blob = _issue_request(dep, f"bench-{group}-issue", values, rng)
+    return {"group": group, "slots": slots, "forms": forms, "rng": rng,
+            "issue": issue, "first": first, "blob": blob,
             "issue_s": time.monotonic() - t0}
 
 
 def build_group(dep: Deployment, started: dict) -> tuple:
-    """The transfers that spend the issue of `build_issue`. `slots` is the
+    """The requests behind the issue of `build_issue`. `slots` is the
     group's plan: one entry per request, in the order in which it will be
-    sent, `{"kind": "ok" | <bad kind>, "of": <slot>}` (`of`: the earlier
+    sent, `{"kind": "ok" | <bad kind>, "form": <name>, "of": <slot>}`
+    (`form`: absent where the mix has the one `transfer`; `of`: the earlier
     slot a double spend re-spends). Returns (meta, blobs)."""
     from fabric_token_sdk_tpu.api.request import TokenRequest, TransferRecord
     from fabric_token_sdk_tpu.crypto.serialization import dumps, loads
     from fabric_token_sdk_tpu.models.token import ID
 
-    group, slots, rng, issue = (started[f] for f in
-                                ("group", "slots", "rng", "issue"))
-    ins, outs = (started["transfer"][f] for f in ("in_values", "out_values"))
-    k = len(ins)
+    group, slots, forms, rng, issue, first = (
+        started[f] for f in ("group", "slots", "forms", "rng", "issue", "first"))
     n = len(slots)
     anchor = f"bench-{group}"
     blobs = [started["blob"]]
     issue_s = started["issue_s"]
+    form = [forms[s.get("form", "")] for s in slots]
 
     def inputs(i):
-        ids = [ID(f"{anchor}-issue", k * i + j) for j in range(k)]
-        return ids, issue.outputs[k * i:k * i + k], issue.metadata[k * i:k * i + k]
+        at = range(first[i], first[i] + len(form[i]["in_values"]))
+        return ([ID(f"{anchor}-issue", j) for j in at],
+                [issue.outputs[j] for j in at], [issue.metadata[j] for j in at])
+
+    def outputs(i):
+        """-> (values, owners); a redeem's first output has no owner, as
+        `api/tms.py:add_redeem` builds it."""
+        if form[i]["op"] == "redeem":
+            change = form[i]["change_values"]
+            return ([form[i]["redeem_value"], *change],
+                    [b"", *[dep.owner_id] * len(change)])
+        return form[i]["out_values"], [dep.owner_id] * len(form[i]["out_values"])
 
     # a double spend re-sends another slot's action under a new anchor
-    own = [i for i, s in enumerate(slots) if s["kind"] != "double_spend"]
-    owners = [dep.owner_id] * len(outs)
+    own = [i for i, s in enumerate(slots)
+           if s["kind"] != "double_spend" and form[i]["op"] != "issue"]
     t0 = time.monotonic()
-    specs = [(*inputs(i), "USD", outs, owners) for i in own]
+    specs = [(*inputs(i), "USD", *outputs(i)) for i in own]
     if dep.zk:
         # host prover, as clients prove: below min_batch nothing is batched
         proved = dep.driver.transfer_many(specs, rng=rng, min_batch=len(specs) + 1)
@@ -206,6 +225,11 @@ def build_group(dep: Deployment, started: dict) -> tuple:
     expect = []
     for i, slot in enumerate(slots):
         kind = slot["kind"]
+        expect.append("Valid" if kind == "ok" else "Invalid")
+        if form[i]["op"] == "issue":
+            blobs.append(_issue_request(dep, f"{anchor}-{i}",
+                                        form[i]["out_values"], rng)[1])
+            continue
         src = slot["of"] if kind == "double_spend" else i
         tout = outcome[src]
         action = tout.action_bytes
@@ -217,7 +241,7 @@ def build_group(dep: Deployment, started: dict) -> tuple:
         req = TokenRequest(anchor=f"{anchor}-{i}")
         req.transfers.append(TransferRecord(
             action=action, input_ids=ids, senders=[dep.owner_id] * len(ids),
-            outputs_metadata=tout.metadata, receivers=owners))
+            outputs_metadata=tout.metadata, receivers=outputs(src)[1]))
         payload = req.marshal_to_sign()
         sigs = [dep.owner_key.sign(payload, rng) for _ in ids]
         if kind == "bad_owner_signature":
@@ -226,7 +250,6 @@ def build_group(dep: Deployment, started: dict) -> tuple:
         req.transfers[0].signatures = sigs
         req.auditor_signature = dep.auditor_key.sign(req.marshal_to_audit(), rng)
         blobs.append(req.to_bytes())
-        expect.append("Valid" if kind == "ok" else "Invalid")
     meta = {
         "group": group, "anchor": anchor, "slots": slots, "expect": expect,
         "tx_ids": [f"{anchor}-{i}" for i in range(n)],
@@ -261,11 +284,11 @@ def main(argv) -> int:
         raise RuntimeError("the corpus worker must be pinned to the CPU backend")
     sys.path.insert(0, ROOT)
     dep = Deployment(spec["config"], spec["art_dir"])
-    # every issue first: the parent hands them to the node meanwhile
+    # every set-up issue first: the parent hands them to the node meanwhile
     started = []
     for g in spec["groups"]:
         s = build_issue(dep, spec["seed"], g["group"], g["slots"],
-                        spec["transfer"])
+                        spec["forms"])
         write_group(os.path.join(spec["out_dir"], f"issue-{g['group']}.bin"),
                     {"group": g["group"]}, [s["blob"]])
         started.append(s)

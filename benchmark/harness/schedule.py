@@ -13,7 +13,12 @@ A plan is a list of entries in sending order, each
 to the opening of the window (negative while warming), `group`/`slot` say
 where the request lives in the corpus, `kind` is "ok" or one of the bad
 kinds, `client` the connection that hands it over (backlog only), `joint`
-the hand-over it shares with its neighbours (one `submit_many`).
+the hand-over it shares with its neighbours (one `submit_many`), `form`
+the request form the slot sends (mixes with `requests` only: a mix with one
+`transfer` sends that everywhere and its entries carry no form).
+
+Every seed gets the same *multiset* of forms too, in another order: the
+counts are the mix's shares of the arrivals due, by largest remainder.
 """
 
 from __future__ import annotations
@@ -21,7 +26,45 @@ from __future__ import annotations
 import math
 import random
 
-GROUP_TXS = 64  # transfers per issue request: a 128-output issue is 4.5 MB
+GROUP_TXS = 64  # requests a group at the most
+# outputs of a group's set-up issue at the most (what its slots spend): at
+# 78.8 KB an output at base 300 / exponent 5 the request is 10 MB, under the
+# wire's 16 MiB; 64 two-input requests fill it exactly
+GROUP_OUTPUTS = 128
+
+
+def forms_of(mix: dict) -> dict:
+    """form name -> form, `{"op", "in_values", "out_values", ...}`. A mix
+    with one `transfer` has that one form, unnamed (its entries and slots
+    carry no `form`). Values are the mix's, never drawn: the range proof's
+    digits are part of the work."""
+    if ("transfer" in mix) == ("requests" in mix):
+        raise ValueError("a mix has exactly one of `transfer` and `requests`")
+    if "transfer" in mix:
+        return {"": dict(mix["transfer"], op="transfer")}
+    forms = {f["form"]: f for f in mix["requests"]}
+    if len(forms) != len(mix["requests"]):
+        raise ValueError("two forms of one name")
+    for name, f in forms.items():
+        if not _conserves(f):
+            raise ValueError(f"form {name!r} does not conserve")
+    return forms
+
+
+def _conserves(f: dict) -> bool:
+    spent = sum(f.get("in_values", []))
+    if f["op"] == "transfer":
+        return spent == sum(f["out_values"]) > 0
+    if f["op"] == "redeem":  # the first output has no owner, the rest is change
+        return spent == f["redeem_value"] + sum(f["change_values"]) > 0
+    if f["op"] == "issue":  # the issuer's request: it spends nothing
+        return "in_values" not in f and sum(f["out_values"]) > 0
+    raise ValueError(f"form {f['form']!r}: unknown op {f['op']!r}")
+
+
+def slot_plan(kind: str, form: str = "") -> dict:
+    """One slot of a group's plan, as the corpus worker reads it."""
+    return {"kind": kind, "form": form} if form else {"kind": kind}
 
 
 def _gaps(rate: float, span: float) -> list:
@@ -79,9 +122,71 @@ def plan(mix: dict, bad_kinds: list, seconds: float, seed: int) -> list:
     else:
         raise ValueError(f"no order of seed {seed}'s gaps in {DRAWS} has the "
                          f"joint_layout {layout}")
+    forms = forms_of(mix)
+    if "requests" in mix:
+        _place_forms(entries, mix, random.Random(f"{seed}/forms"))
+    _group(entries, forms)
     rng = random.Random(f"{seed}/bad")
-    _place_bad(entries, bad_kinds, mix, seconds, rng)
+    _place_bad(entries, bad_kinds, mix, seconds, rng, forms)
     return entries
+
+
+def form_counts(requests: list, n: int) -> dict:
+    """form -> how many of `n` arrivals send it: the shares by largest
+    remainder (a tie goes to the form listed first)."""
+    if abs(sum(float(f["share"]) for f in requests) - 1.0) > 1e-9:
+        raise ValueError("the shares of `requests` do not sum to 1")
+    quota = [float(f["share"]) * n for f in requests]
+    counts = [int(q) for q in quota]
+    # (rounded: 0.35 * 64 and 0.1 * 64 leave the same remainder, not two
+    # that differ in the sixteenth digit)
+    by_rest = sorted(range(len(requests)),
+                     key=lambda k: round(counts[k] - quota[k], 9))
+    for k in by_rest[:n - sum(counts)]:
+        counts[k] += 1
+    return {f["form"]: c for f, c in zip(requests, counts)}
+
+
+def _place_forms(entries, mix, rng) -> None:
+    """Every entry gets its form. The warm-up's arrivals and the window's
+    are counted apart, so that the window holds the same multiset for every
+    seed; a hand-over whose `joint` entry lists its `forms` takes those, in
+    that order, out of the window's multiset (the block a median or a traced
+    slice sits on then holds the same rows in every seed); the seed places
+    the rest."""
+    for k, joint in enumerate(mix.get("joint", [])):
+        if "forms" in joint:
+            if len(joint["forms"]) != int(joint["txs"]):
+                raise ValueError(f"joint {k}: `forms` must list its {joint['txs']}")
+            share = [e for e in entries if e.get("joint") == k]
+            for e, form in zip(share, joint["forms"]):
+                e["form"] = form
+    for part in ([e for e in entries if e["due_s"] < 0.0],
+                 [e for e in entries if e["due_s"] >= 0.0]):
+        counts = form_counts(mix["requests"], len(part))
+        for e in part:
+            if "form" in e:
+                counts[e["form"]] = counts.get(e["form"], 0) - 1
+        if min(counts.values()) < 0:
+            raise ValueError("the hand-overs' `forms` take more of a form than "
+                             f"its share of the arrivals holds: {counts}")
+        rest = [form for form, c in counts.items() for _ in range(c)]
+        rng.shuffle(rest)
+        for e, form in zip([e for e in part if "form" not in e], rest):
+            e["form"] = form
+
+
+def _group(entries, forms) -> None:
+    """Groups in sending order. A group's set-up issue covers what its
+    slots spend; the group is closed when the next slot would take that
+    issue past GROUP_OUTPUTS, or the group past GROUP_TXS requests."""
+    g = slots = outputs = 0
+    for e in entries:
+        spends = len(forms[e.get("form", "")].get("in_values", []))
+        if slots == GROUP_TXS or outputs + spends > GROUP_OUTPUTS:
+            g, slots, outputs = g + 1, 0, 0
+        e["group"], e["slot"] = f"g{g}", slots
+        slots, outputs = slots + 1, outputs + spends
 
 
 def _has_layout(entries, mix, layout, seconds) -> bool:
@@ -106,8 +211,7 @@ def _has_layout(entries, mix, layout, seconds) -> bool:
 def _ordered(mix: dict, seconds: float, seed: int, draw: int) -> list:
     due = arrivals(mix, seconds, seed, draw)
     n = len(due)
-    entries = [{"i": i, "due_s": due[i], "group": f"g{i // GROUP_TXS}",
-                "slot": i % GROUP_TXS, "kind": "ok", "client": 0}
+    entries = [{"i": i, "due_s": due[i], "kind": "ok", "client": 0}
                for i in range(n)]
     if mix["arrivals"] == "at_open":
         h = mix["handover"]
@@ -129,19 +233,23 @@ def _ordered(mix: dict, seconds: float, seed: int, draw: int) -> list:
     return entries
 
 
-def _place_bad(entries, bad_kinds, mix, seconds, rng) -> None:
+def _place_bad(entries, bad_kinds, mix, seconds, rng, forms) -> None:
     """Seeded places for the bad requests: due inside the window, early
     enough to be judged in it; a double spend after the slot it re-spends,
     in the same group and at least `min_gap_s` later (or, in a backlog,
-    later in the same hand-over)."""
+    later in the same hand-over). On transfers only: the bad kinds are
+    faults of a transfer (its proof, its inputs, its owners' signatures)."""
+    def transfers(e):
+        return forms[e.get("form", "")]["op"] == "transfer"
+
     if mix["arrivals"] == "at_open":
         # the first hand-over is the block that commits inside the window
-        pool = [e for e in entries if e["client"] == 0]
+        pool = [e for e in entries if e["client"] == 0 and transfers(e)]
         min_gap = 0.0
     else:
         last = seconds * float(mix.get("bad_before_share", 0.6))
         pool = [e for e in entries if 0.0 <= e["due_s"] <= last
-                and "joint" not in e]
+                and "joint" not in e and transfers(e)]
         min_gap = float(mix.get("min_gap_s", 0.5))
     if len(pool) < 2 * len(bad_kinds) + 2:
         raise ValueError("too few requests in the window for the bad ones")
@@ -154,6 +262,7 @@ def _place_bad(entries, bad_kinds, mix, seconds, rng) -> None:
             if kind == "double_spend":
                 earlier = [p for p in entries
                            if p["group"] == e["group"] and p["kind"] == "ok"
+                           and transfers(p)
                            and p["i"] < e["i"] and p["i"] not in taken
                            and e["due_s"] - p["due_s"] >= min_gap]
                 if not earlier:
@@ -172,7 +281,7 @@ def groups(entries: list) -> dict:
     """group name -> its slot plan, in slot order."""
     out = {}
     for e in entries:
-        slot = {"kind": e["kind"]}
+        slot = slot_plan(e["kind"], e.get("form", ""))
         if "of" in e:
             slot["of"] = e["of"]
         out.setdefault(e["group"], []).append(slot)

@@ -87,16 +87,20 @@ def start_corpus(cell: dict, seed: int, entries: list, workdir: str) -> dict:
     art_dir = os.path.join(workdir, "tokengen")
     make_artifacts(cell["config"], seed, art_dir)
     groups = schedule.groups(entries)
+    forms = schedule.forms_of(cell["mix"])
     warm = int(cell["config"].get("warm_block_txs", 0))
     if warm:
-        groups["warm"] = [{"kind": "ok"}] * warm
+        # of every form the mix sends: no program is dispatched first, and
+        # no shape met first, inside the window
+        groups["warm"] = [schedule.slot_plan("ok", f) for f in forms
+                          for _ in range(warm)]
     names = sorted(groups, key=lambda g: -len(groups[g]))
     n_proc = max(1, min(len(names), (os.cpu_count() or 2) - 2, 8))
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     procs = []
     for k in range(n_proc):
         spec = {"config": cell["config"], "art_dir": art_dir, "seed": seed,
-                "out_dir": workdir, "transfer": cell["mix"]["transfer"],
+                "out_dir": workdir, "forms": forms,
                 "groups": [{"group": g, "slots": groups[g]}
                            for g in names[k::n_proc]]}
         path = os.path.join(workdir, f"corpus-{k}.json")
@@ -687,6 +691,17 @@ def result_line(cell, run, device, trace: bool, rehearse: bool) -> dict:
 # ------------------------------------------------------------------- main
 
 
+def rehearsal(cell: dict) -> dict:
+    """The cell at the tiny sizes of its two files' `rehearsal` blocks. A
+    configuration's rehearsal `transfer` takes the place of a mix's; a mix
+    of `requests` brings its own small forms."""
+    small = cell["config"].get("rehearsal", {})
+    mix = {**cell["mix"], **cell["mix"].get("rehearsal", {})}
+    if "transfer" in mix and "transfer" in small:
+        mix["transfer"] = small["transfer"]
+    return dict(cell, config={**cell["config"], **small}, mix=mix)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -709,10 +724,7 @@ def main(argv=None) -> int:
             raise Refused(f"BENCHMARK.json: {faults}")
         cell = mf.cell(manifest, args.workload)
         if args.rehearse_cpu:
-            cell["config"] = {**cell["config"], **cell["config"].get("rehearsal", {})}
-            cell["mix"] = {**cell["mix"], **cell["mix"].get("rehearsal", {})}
-            if "transfer" in cell["config"].get("rehearsal", {}):
-                cell["mix"]["transfer"] = cell["config"]["rehearsal"]["transfer"]
+            cell = rehearsal(cell)
         seeds = ([int(s) for s in args.seeds.split(",")] if args.seeds
                  else [args.seed])
         rates = [float(r) for r in args.rates.split(",")] if args.rates else []

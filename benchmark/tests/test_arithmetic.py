@@ -79,9 +79,10 @@ def test_latencies_run_from_due_time_over_transactions_due_in_window():
 
 # --------------------------------------------------------------- schedule
 
-POISSON = {"arrivals": "poisson", "rate_tps": 20.0, "warm_s": 3.0,
-           "handover": {"call": "submit", "pool": 8}}
-BACKLOG = {"arrivals": "at_open", "backlog_txs": 192,
+TRANSFER = {"in_values": [100, 55], "out_values": [120, 35]}
+POISSON = {"transfer": TRANSFER, "arrivals": "poisson", "rate_tps": 20.0,
+           "warm_s": 3.0, "handover": {"call": "submit", "pool": 8}}
+BACKLOG = {"transfer": TRANSFER, "arrivals": "at_open", "backlog_txs": 192,
            "handover": {"call": "submit_many", "clients": 3, "stagger_s": 1.0}}
 
 

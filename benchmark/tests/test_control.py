@@ -22,17 +22,15 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-ONE_BLOCK = [
-    os.path.join(HERE, "data", "one_block.mix.json"),
-    "--workload", "fab22.steady", "--rehearse-cpu", "--seconds", "60",
-    "--trace", "0",
-]
 
-
-def drive(mode, seed):
+def drive(mode, seed, mix="one_block", cell="fab22.steady", seconds=60):
+    """One rehearsal of `cell` on the tests' mix `data/<mix>.mix.json`
+    through drive_broken.py. -> (the result line, check name -> ok|FAILED)"""
     out = subprocess.run(
         [sys.executable, os.path.join(HERE, "drive_broken.py"), mode,
-         *ONE_BLOCK, "--seed", str(seed)],
+         os.path.join(HERE, "data", f"{mix}.mix.json"), "--workload", cell,
+         "--rehearse-cpu", "--seconds", str(seconds), "--trace", "0",
+         "--seed", str(seed)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
         text=True, timeout=1500)
     assert out.returncode == 0, out.stderr[-2000:]

@@ -169,6 +169,11 @@ def test_each_cell_prints_the_median_finality_under_the_name_it_lists():
     e2e = {x["name"]: x for x in m["end_to_end"]}
     assert e2e["finality_p50_s"]["workloads"] == ["b300e5.batches", "b300e5.testnet"]
     assert e2e["finality_p50_s.host"]["workloads"] == ["zk22.steady"]
+    # PR 36: the host median's bound as the ledger's note on PR 34 asks, and
+    # `committed_tps` at five times what `zk22.backlog`'s runs spread
+    assert {k: e2e[k]["bound"] for k in e2e} == {
+        "committed_tps": 0.05, "finality_p50_s": 0.02,
+        "finality_p50_s.host": 0.062, "setup_s": 0.25}
     events = [{"due": float(i), "done": i + 0.1 * (i + 1), "status": "Valid"}
               for i in range(5)]
     run_ = {"events": events, "seconds": 10.0, "grace_s": 1.0, "setup_s": 3.0}
