@@ -17,7 +17,11 @@ once at one canonical `stages.tile_rows` shape) plus the compile-once pairing ti
 repetition, broadcasting parameter points, Fiat-Shamir re-hashing — is
 host numpy, so the distinct-program count is independent of batch size,
 transfer shape `(n_in, n_out)`, and parameter set. `ops/warmup.py`
-precompiles the whole set.
+precompiles the whole set. The transfer verifiers take rows of differing
+shapes in one call: every transaction's rows sit at a running offset of
+the flat rows a stage call is handed, so a block costs its fixed part
+(one padded dispatch per stage call, one Miller walk, one final
+exponentiation) once and not once a shape.
 """
 
 from __future__ import annotations
@@ -30,7 +34,7 @@ import numpy as np
 from . import hostmath as hm, pssign, sigproof
 from .rangeproof import RangeProof
 from .setup import PublicParams
-from .transfer import TransferProof
+from .transfer import TransferProof, _skip_range
 from .wellformedness import TransferWF, challenge_transfer_wf
 from ..ops import curve as cv, curve2 as cv2, limbs as lb, pairing as pr, \
     stages as st, tower as tw
@@ -125,8 +129,9 @@ class BatchedPSVerifier:
 
 
 class BatchedWFVerifier:
-    """Recomputes all Schnorr commitments of B same-shape transfer WF
-    proofs via the stage tiles, then re-derives challenges on host."""
+    """Recomputes all Schnorr commitments of B transfer WF proofs, of any
+    mix of shapes, via the stage tiles, then re-derives challenges on
+    host."""
 
     def __init__(self, pp: PublicParams):
         self.pp = pp
@@ -134,15 +139,18 @@ class BatchedWFVerifier:
 
     @_spanned("batch.wf.verify")
     def verify(self, txs: Sequence[Tuple[list, list, bytes]]) -> np.ndarray:
-        """txs: (inputs, outputs, wf_bytes) with uniform shapes.
+        """txs: (inputs, outputs, wf_bytes); each transaction has its own
+        `(n_in, n_out)` and `n_in + n_out + 2` rows (+ the two aggregate
+        statements) at a running offset of the flat rows.
         Returns bool array (B,)."""
         B = len(txs)
         if B == 0:
             return np.zeros(0, dtype=bool)
         mx.counter("batch.wf.txs").inc(B)
-        n_in = len(txs[0][0])
-        n_out = len(txs[0][1])
-        n = n_in + n_out + 2  # + the two aggregate statements
+        ns = [len(t[0]) + len(t[1]) + 2 for t in txs]
+        # transaction i owns the flat rows at[i]:at[i+1]
+        at = np.concatenate([[0], np.cumsum(ns)])
+        N = int(at[-1])
         proofs: List[Optional[TransferWF]] = []
         for t in txs:
             try:
@@ -150,10 +158,11 @@ class BatchedWFVerifier:
             except Exception:
                 proofs.append(None)  # malformed: row verifies False
         stmts: List = []
-        resp = np.zeros((B, n, 3, lb.NLIMBS), dtype=np.int32)
+        resp = np.zeros((N, 3, lb.NLIMBS), dtype=np.int32)
         chals = np.zeros((B, lb.NLIMBS), dtype=np.int32)
         ok_shape = np.ones(B, dtype=bool)
         for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
+            n_in, n_out = len(inputs), len(outputs)
             if (
                 wf is None
                 or len(wf.input_values) != n_in
@@ -162,7 +171,7 @@ class BatchedWFVerifier:
                 or len(wf.output_bfs) != n_out
             ):
                 ok_shape[i] = False
-                stmts.extend([None] * n)
+                stmts.extend([None] * ns[i])
                 continue
             stmts.extend(inputs)
             stmts.append(hm.g1_sum(inputs))
@@ -188,28 +197,22 @@ class BatchedWFVerifier:
                 ]
             )
             for j, r in enumerate(rows):
-                resp[i, j] = cv.encode_scalars(r)
+                resp[at[i] + j] = cv.encode_scalars(r)
             chals[i] = cv.encode_scalars([wf.challenge])[0]
 
-        stmt_np = np.stack([cv.encode_point(s) for s in stmts]).reshape(
-            B, n, 3, lb.NLIMBS
-        )
-        # com_j = prod ped_i^{resp_ji} - stmt_j^challenge over B*n flat rows
-        fixed = st.g1_msm_rows(
-            self.table.flat, resp.reshape(B * n, 3, lb.NLIMBS)
-        )
-        sc = st.g1_mul_rows(
-            stmt_np.reshape(B * n, 3, lb.NLIMBS), np.repeat(chals, n, axis=0),
-        )
+        stmt_np = np.stack([cv.encode_point(s) for s in stmts])
+        # com_j = prod ped_i^{resp_ji} - stmt_j^challenge over the flat rows
+        fixed = st.g1_msm_rows(self.table.flat, resp)
+        sc = st.g1_mul_rows(stmt_np, np.repeat(chals, ns, axis=0))
         coms = st.g1_sub_rows(fixed, sc)
-        com_pts = cv.decode_points(coms)  # B*n host points
+        com_pts = cv.decode_points(coms)  # N host points
         out = np.zeros(B, dtype=bool)
         for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
             if not ok_shape[i] or wf is None:
                 continue
-            row = com_pts[i * n : (i + 1) * n]
-            in_coms = row[: n_in + 1]
-            out_coms = row[n_in + 1 :]
+            row = com_pts[at[i] : at[i + 1]]
+            in_coms = row[: len(inputs) + 1]
+            out_coms = row[len(inputs) + 1 :]
             chal = challenge_transfer_wf(
                 in_coms[:-1], in_coms[-1], out_coms[:-1], out_coms[-1], inputs, outputs
             )
@@ -321,12 +324,17 @@ class BatchedMembershipVerifier:
 
 
 class BatchedTransferVerifier:
-    """Verifies whole blocks of same-shape zkatdlog transfer proofs.
+    """Verifies whole blocks of zkatdlog transfer proofs, of any mix of
+    shapes `(n_in, n_out)`, in one call.
 
     Composition mirrors `transfer.TransferVerifier` but the group/pairing
     work of ALL transactions runs through the fixed-shape stage tiles —
     the total distinct-program count is constant in `(n_in, n_out)`,
-    batch size, and parameter set.
+    batch size, and parameter set. Rows are flat: a transaction's
+    well-formedness rows, its membership rows (output x digit) and its
+    equality rows (one per output) sit at running offsets, so a call of
+    one shape dispatches exactly the rows a call of mixed shapes with as
+    many rows does.
     """
 
     def __init__(self, pp: PublicParams):
@@ -338,12 +346,14 @@ class BatchedTransferVerifier:
 
     @_spanned("batch.transfer.verify")
     def verify(self, txs: Sequence[Tuple[list, list, bytes]]) -> np.ndarray:
-        """txs: (inputs, outputs, transfer_proof_bytes), uniform shapes.
-        Returns bool array (B,). 1-in/1-out txs skip range (reference
+        """txs: (inputs, outputs, transfer_proof_bytes), each of its own
+        shape. Returns bool array (B,). A 1-in/1-out tx carries no range
+        proof and contributes no range rows (reference
         transfer.go:55-59)."""
         B = len(txs)
         if B == 0:
             return np.zeros(0, dtype=bool)
+        shapes = [(len(t[0]), len(t[1])) for t in txs]
 
         def _count_done():
             # counted on COMPLETION (not entry): an ABANDONED bounded
@@ -354,8 +364,9 @@ class BatchedTransferVerifier:
             # and defeat the guard.
             if not resilience.call_abandoned():
                 mx.counter("batch.transfer.txs").inc(B)
+                mx.counter("batch.transfer.calls").inc()
+                mx.counter("batch.transfer.shapes").inc(len(set(shapes)))
 
-        n_in, n_out = len(txs[0][0]), len(txs[0][1])
         proofs = []
         ok = np.ones(B, dtype=bool)
         for i, t in enumerate(txs):
@@ -368,14 +379,14 @@ class BatchedTransferVerifier:
             [(t[0], t[1], p.wf) for t, p in zip(txs, proofs)]
         )
         ok &= wf_ok
-        if n_in == 1 and n_out == 1:
-            _count_done()
-            return ok
 
         rp = self.pp.range_params
         exponent, base = rp.exponent, rp.base
         ranges: List[Optional[RangeProof]] = []
-        for i, p in enumerate(proofs):
+        for i, (p, (n_in, n_out)) in enumerate(zip(proofs, shapes)):
+            if _skip_range(n_in, n_out):
+                ranges.append(None)  # the WF verdict is the whole verdict
+                continue
             if p.range_correctness is None:
                 ok[i] = False
                 ranges.append(None)
@@ -402,7 +413,7 @@ class BatchedTransferVerifier:
         for i, rpf in enumerate(ranges):
             if rpf is None:
                 continue
-            for k in range(n_out):
+            for k in range(shapes[i][1]):
                 for d in range(exponent):
                     mem_proofs.append(rpf.membership_proofs[k][d])
                     mem_coms.append(rpf.digit_commitments[k][d])
@@ -413,52 +424,49 @@ class BatchedTransferVerifier:
                 if not mem_ok[j]:
                     ok[i] = False
 
-        # ---- equality proofs: token rows (3 bases) + aggregate rows (2)
+        # ---- equality proofs: token rows (3 bases) + aggregate rows (2),
+        # one of each per output, flat over the live transactions
         live = [i for i in range(B) if ranges[i] is not None]
         if not live:
             _count_done()
             return ok
         L = lb.NLIMBS
-        nl = len(live)
-        tok_resp = np.zeros((nl, n_out, 3, L), dtype=np.int32)
-        tok_stmt = np.zeros((nl, n_out, 3, L), dtype=np.int32)
-        agg_resp = np.zeros((nl, n_out, 2, L), dtype=np.int32)
-        agg_stmt = np.zeros((nl, n_out, 3, L), dtype=np.int32)
-        chals = np.zeros((nl, L), dtype=np.int32)
+        n_outs = [shapes[i][1] for i in live]
+        # live transaction li owns the flat rows at[li]:at[li+1]
+        at = np.concatenate([[0], np.cumsum(n_outs)])
+        N = int(at[-1])
+        tok_resp = np.zeros((N, 3, L), dtype=np.int32)
+        tok_stmt = np.zeros((N, 3, L), dtype=np.int32)
+        agg_resp = np.zeros((N, 2, L), dtype=np.int32)
+        agg_stmt = np.zeros((N, 3, L), dtype=np.int32)
+        chals = np.zeros((len(live), L), dtype=np.int32)
         for li, i in enumerate(live):
             rpf = ranges[i]
             outputs = txs[i][1]
-            for k in range(n_out):
-                tok_resp[li, k] = cv.encode_scalars(
+            for k in range(n_outs[li]):
+                r = at[li] + k
+                tok_resp[r] = cv.encode_scalars(
                     [rpf.type_resp, rpf.value_resps[k], rpf.token_bf_resps[k]]
                 )
-                tok_stmt[li, k] = cv.encode_point(outputs[k])
+                tok_stmt[r] = cv.encode_point(outputs[k])
                 agg = hm.g1_multiexp(
                     rpf.digit_commitments[k],
                     [base**d % hm.R for d in range(exponent)],
                 )
-                agg_stmt[li, k] = cv.encode_point(agg)
-                agg_resp[li, k] = cv.encode_scalars(
+                agg_stmt[r] = cv.encode_point(agg)
+                agg_resp[r] = cv.encode_scalars(
                     [rpf.value_resps[k], rpf.com_bf_resps[k]]
                 )
             chals[li] = cv.encode_scalars([rpf.challenge])[0]
 
-        chal_rep = np.repeat(chals, n_out, axis=0)
+        chal_rep = np.repeat(chals, n_outs, axis=0)
         com_tok = st.g1_sub_rows(
-            st.g1_msm_rows(
-                self.table3.flat, tok_resp.reshape(nl * n_out, 3, L),
-            ),
-            st.g1_mul_rows(
-                tok_stmt.reshape(nl * n_out, 3, L), chal_rep
-            ),
+            st.g1_msm_rows(self.table3.flat, tok_resp),
+            st.g1_mul_rows(tok_stmt, chal_rep),
         )
         com_val = st.g1_sub_rows(
-            st.g1_msm_rows(
-                self.table2.flat, agg_resp.reshape(nl * n_out, 2, L),
-            ),
-            st.g1_mul_rows(
-                agg_stmt.reshape(nl * n_out, 3, L), chal_rep
-            ),
+            st.g1_msm_rows(self.table2.flat, agg_resp),
+            st.g1_mul_rows(agg_stmt, chal_rep),
         )
         com_tok_h = cv.decode_points(com_tok)
         com_val_h = cv.decode_points(com_val)
@@ -471,8 +479,8 @@ class BatchedTransferVerifier:
                 rp.sign_pk, self.pp.ped_gen, rp.Q,
             )
             chal = verifier._challenge(
-                com_tok_h[li * n_out : (li + 1) * n_out],
-                com_val_h[li * n_out : (li + 1) * n_out],
+                com_tok_h[at[li] : at[li + 1]],
+                com_val_h[at[li] : at[li + 1]],
                 rpf.digit_commitments,
             )
             if chal != rpf.challenge:

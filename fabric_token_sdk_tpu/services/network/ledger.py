@@ -3,9 +3,10 @@
 Reference: `token/services/network/*` (fabric/orion backends + vault
 processor) plus the ordering service in front of them. Submissions enter
 the `Orderer`'s queue (`orderer.py`); blocks are cut by size/linger
-policy and validated by the block pipeline — same-shape zkatdlog
-transfer groups in ONE `BatchedTransferVerifier` call over the
-compile-once stage tiles, host `RequestValidator` for the rest — then
+policy and validated by the block pipeline — a block's zkatdlog
+transfers, whatever their shapes, in ONE `BatchedTransferVerifier` call
+over the compile-once stage tiles, host `RequestValidator` for the rest
+— then
 committed atomically: intra-block MVCC (a double-spend inside a block
 invalidates the LATER tx only), per-tx finality events, and
 crash-isolated listener notification.
@@ -630,6 +631,9 @@ class Network:
                 "queue_wait_max_s": round(queue_wait_max, 6),
                 "grouping_s": round(timings.get("grouping_s", 0.0), 6),
                 "device_verify_s": round(timings.get("device_verify_s", 0.0), 6),
+                # completed proof-plane calls of this block: 0 (the host
+                # verified it) or 1 (all its transfer rows, any shapes)
+                "verify_calls": int(timings.get("verify_calls", 0)),
                 "sign_verify_s": round(timings.get("sign_verify_s", 0.0), 6),
                 # the two device planes from inside (utils/devobs.py):
                 # this block's seconds in dispatch frames, of those

@@ -3,11 +3,12 @@
 Reference: Fabric's ordering service in front of the committing peers,
 and the validator scope note in SURVEY §3 — "the validator runs batched
 verification for a whole block". Submissions enter an ordering queue;
-blocks are cut by size/linger policy; a block validation pipeline groups
-same-shape zkatdlog transfers and verifies each group in ONE
-`BatchedTransferVerifier` call over the compile-once stage tiles
-(`ops/stages.py`), with the host `RequestValidator` as the fallback for
-fabtoken transfers, issues, and shapes too rare to batch. The ledger
+blocks are cut by size/linger policy; a block validation pipeline
+verifies a block's zkatdlog transfers, whatever their shapes
+`(n_in, n_out)`, in ONE `BatchedTransferVerifier` call over the
+compile-once stage tiles (`ops/stages.py`), with the host
+`RequestValidator` as the fallback for fabtoken transfers, issues, and
+blocks with too few transfer rows to batch. The ledger
 (`ledger.py`) then applies intra-block MVCC — a double-spend inside a
 block invalidates the LATER tx, never the block — and commits the block
 atomically with per-tx finality events.
@@ -132,8 +133,10 @@ class BlockPolicy:
                        with `MessageTooLarge`
                        [BatchSize.AbsoluteMaxBytes]. 0 = off.
                        A message's size is its request's wire length.
-    `min_batch`      — smallest same-shape transfer group worth a device
-                       batch call; smaller groups take the host path.
+    `min_batch`      — the least number of transfer rows in a block
+                       worth a device batch call (of any shapes: a
+                       block's rows ride one call); a block with fewer
+                       takes the host path.
     `use_batched`    — master switch for the batched proof plane.
     `queue_max`      — admission control: ordering-queue depth beyond
                        which enqueues are rejected with `Backpressure`
@@ -596,14 +599,17 @@ class BlockValidationPipeline:
     """The batched proof plane for one block.
 
     Phase 1 (plan): ask the driver for a batch plan per transfer record —
-    `(shape_key, (input_points, output_points, proof_bytes))`, or None
+    `(shape, (input_points, output_points, proof_bytes))`, or None
     for host validation (fabtoken, malformed bytes, non-batchable kinds).
+    The shape `(n_in, n_out)` is the row's label, not a grouping key.
 
-    Phase 2 (batched verify): group plans by shape; every group of at
-    least `min_batch` rows goes through ONE `BatchedTransferVerifier`
-    call (constant XLA program count regardless of shape/batch — see
-    `crypto/batch.py`). Verdicts come back keyed
-    `{tx_index: {transfer_index: bool}}`.
+    Phase 2 (batched verify): the call is the block's. If the block has
+    at least `min_batch` planned rows they go, in request order and
+    whatever their shapes, through ONE `BatchedTransferVerifier` call
+    (constant XLA program count regardless of shape/batch, and the
+    block's fixed cost — a padded dispatch per stage call, one Miller
+    walk, one final exponentiation — paid once: see `crypto/batch.py`).
+    Verdicts come back keyed `{tx_index: {transfer_index: bool}}`.
 
     Phase 3 is the ledger's: sequential per-tx `RequestValidator.validate`
     with MVCC over the block view; records with a verdict skip (True) or
@@ -649,9 +655,10 @@ class BlockValidationPipeline:
         host_verdicts: Optional[Dict[int, Dict[int, bool]]] = None,
     ) -> Dict[int, Dict[int, bool]]:
         """`timings`, when passed, is filled with the critical-path
-        split of this call: `grouping_s` (plan + same-shape grouping)
-        and `device_verify_s` (time inside batched verify calls,
-        including failed ones that degraded to host).
+        split of this call: `grouping_s` (planning the block's rows),
+        `device_verify_s` (time inside the batched verify call,
+        including a failed one that degraded to host) and `verify_calls`
+        (completed plane calls of the block: 0 or 1).
 
         `host_verdicts`, when passed as a dict, receives True-only
         verdicts from the batch-first HOST pass over every row the
@@ -678,6 +685,7 @@ class BlockValidationPipeline:
     ) -> Dict[int, Dict[int, bool]]:
         timings.setdefault("grouping_s", 0.0)
         timings.setdefault("device_verify_s", 0.0)
+        timings.setdefault("verify_calls", 0)
         if not self.policy.use_batched:
             return {}
         driver = self.validator.driver
@@ -685,112 +693,120 @@ class BlockValidationPipeline:
         if plan is None:
             return {}
         t0 = time.monotonic()
-        groups: Dict[tuple, List[Tuple[int, int, tuple]]] = {}
+        # the block's plannable transfer rows in request order, each
+        # labelled with its shape: they ride ONE plane call whatever
+        # their shapes
+        rows: List[Tuple[int, int, tuple]] = []
+        shapes = set()
         for ti, req in enumerate(requests):
             for ri, rec in enumerate(req.transfers):
                 p = plan(rec.action)
                 if p is None:
                     continue
                 shape, row = p
-                groups.setdefault(shape, []).append((ti, ri, row))
+                shapes.add(shape)
+                rows.append((ti, ri, row))
         timings["grouping_s"] = time.monotonic() - t0
 
+        ok = self._device_proof_call(rows, len(shapes), timings)
+        if ok is None:
+            # rows the device plane leaves behind (a block under
+            # `min_batch`, open breaker, failed/timed-out dispatch, no
+            # device plane at all): the batch-first HOST pass still
+            # verifies them in one native multiexp + one block-level
+            # Fiat-Shamir call before the per-tx scalar loop sees them
+            if host_verdicts is not None:
+                self._host_proof_batch(rows, host_verdicts, timings)
+            return {}
         verdicts: Dict[int, Dict[int, bool]] = {}
-        verifier = None
-        # rows the device plane leaves behind (small groups, open
-        # breaker, failed/timed-out dispatches, no device plane at all):
-        # the batch-first HOST pass below still verifies them in one
-        # native multiexp + one block-level Fiat-Shamir call before the
-        # per-tx scalar loop sees them
-        leftovers: List[Tuple[int, int, tuple]] = []
-        device_dead = False
-        brk = resilience.breaker("verify")
-        deadline_s = resilience.device_deadline_s("verify")
-        for shape, rows in sorted(groups.items()):
-            if device_dead or len(rows) < max(1, self.policy.min_batch):
-                leftovers.extend(rows)
-                continue
-            if not brk.allow():
-                # open breaker: instant host fallback — no deadline paid,
-                # no worker stacked onto a sick backend. The host plane
-                # re-verifies these rows with verdicts unchanged.
-                mx.flight(
-                    "verify.host_fallback", shape=str(shape),
-                    txs=len(rows), reason="breaker_open",
-                )
-                leftovers.extend(rows)
-                continue
-            if verifier is None:
-                try:
-                    verifier = driver.batch_verifier()
-                except Exception:
-                    # construction failures (device stack unavailable,
-                    # OOM building tables) degrade to host validation,
-                    # same as verify failures — never fail a block
-                    brk.record_failure()
-                    mx.counter("ledger.block.batch_errors").inc()
-                    mx.flight("verify.host_fallback", reason="construct")
-                    device_dead = True
-                    leftovers.extend(rows)
-                    continue
-                if verifier is None:
-                    # the driver HAS no batched plane: neither success
-                    # nor failure — release the admission (else a
-                    # half-open probe would stay consumed forever)
-                    brk.cancel_probe()
-                    device_dead = True
-                    leftovers.extend(rows)
-                    continue
-
-            def _device_verify(rows=rows):
-                # device-plane fault point: firing here (INSIDE the
-                # bounded worker, so a `hang` kind is governed by the
-                # deadline) exercises the degrade-to-host path below
-                faults.fire("batch.verify")
-                return verifier.verify([row for _, _, row in rows])
-
-            tg = time.monotonic()
-            try:
-                with mx.span(
-                    "ledger.block.batch_verify", shape=str(shape), txs=len(rows)
-                ):
-                    ok = resilience.bounded_call(
-                        _device_verify, deadline_s, plane="verify"
-                    )
-            except resilience.DeviceTimeout:
-                # the dispatch outlived its wall budget: abandon it (the
-                # straggler's late result is discarded by the supervisor)
-                # and fall to host — the block must not stall
-                brk.record_failure(timeout=True)
-                mx.counter("ledger.block.batch_errors").inc()
-                mx.flight(
-                    "verify.host_fallback", shape=str(shape),
-                    txs=len(rows), reason="timeout",
-                )
-                leftovers.extend(rows)
-                continue
-            except Exception:
-                # the host plane re-verifies these rows; never fail a block
-                # on a device-plane error
-                brk.record_failure()
-                mx.counter("ledger.block.batch_errors").inc()
-                mx.flight(
-                    "verify.host_fallback", shape=str(shape), txs=len(rows)
-                )
-                leftovers.extend(rows)
-                continue
-            finally:
-                timings["device_verify_s"] += time.monotonic() - tg
-            brk.record_success()
-            mx.flight(
-                "verify.device", shape=str(shape), txs=len(rows),
-                ok=int(sum(1 for g in ok if g)),
-            )
-            for (ti, ri, _), good in zip(rows, ok):
-                verdicts.setdefault(ti, {})[ri] = bool(good)
-        if host_verdicts is not None:
-            self._host_proof_batch(leftovers, host_verdicts, timings)
+        for (ti, ri, _), good in zip(rows, ok):
+            verdicts.setdefault(ti, {})[ri] = bool(good)
         return verdicts
+
+    def _device_proof_call(
+        self, rows: List[Tuple[int, int, tuple]], n_shapes: int,
+        timings: dict,
+    ) -> Optional[Sequence[bool]]:
+        """The block's one `BatchedTransferVerifier.verify` call over
+        `rows`, bounded and behind the `verify` breaker. -> one verdict a
+        row, or None where the rows are the host's: fewer than
+        `min_batch`, an open breaker, no device plane, or a call that
+        failed or timed out (a fallback, counted and logged)."""
+        if len(rows) < max(1, self.policy.min_batch):
+            return None
+        driver = self.validator.driver
+        brk = resilience.breaker("verify")
+        if not brk.allow():
+            # open breaker: instant host fallback — no deadline paid,
+            # no worker stacked onto a sick backend. The host plane
+            # re-verifies these rows with verdicts unchanged.
+            mx.flight(
+                "verify.host_fallback", shapes=n_shapes,
+                txs=len(rows), reason="breaker_open",
+            )
+            return None
+        try:
+            verifier = driver.batch_verifier()
+        except Exception:
+            # construction failures (device stack unavailable, OOM
+            # building tables) degrade to host validation, same as
+            # verify failures — never fail a block
+            brk.record_failure()
+            mx.counter("ledger.block.batch_errors").inc()
+            mx.flight("verify.host_fallback", reason="construct")
+            return None
+        if verifier is None:
+            # the driver HAS no batched plane: neither success nor
+            # failure — release the admission (else a half-open probe
+            # would stay consumed forever)
+            brk.cancel_probe()
+            return None
+
+        def _device_verify():
+            # device-plane fault point: firing here (INSIDE the bounded
+            # worker, so a `hang` kind is governed by the deadline)
+            # exercises the degrade-to-host path below
+            faults.fire("batch.verify")
+            return verifier.verify([row for _, _, row in rows])
+
+        tg = time.monotonic()
+        try:
+            with mx.span(
+                "ledger.block.batch_verify", shapes=n_shapes, txs=len(rows)
+            ):
+                ok = resilience.bounded_call(
+                    _device_verify, resilience.device_deadline_s("verify"),
+                    plane="verify",
+                )
+        except resilience.DeviceTimeout:
+            # the dispatch outlived its wall budget: abandon it (the
+            # straggler's late result is discarded by the supervisor)
+            # and fall to host — the block must not stall
+            brk.record_failure(timeout=True)
+            mx.counter("ledger.block.batch_errors").inc()
+            mx.flight(
+                "verify.host_fallback", shapes=n_shapes,
+                txs=len(rows), reason="timeout",
+            )
+            return None
+        except Exception:
+            # the host plane re-verifies these rows; never fail a block
+            # on a device-plane error
+            brk.record_failure()
+            mx.counter("ledger.block.batch_errors").inc()
+            mx.flight(
+                "verify.host_fallback", shapes=n_shapes, txs=len(rows)
+            )
+            return None
+        finally:
+            timings["device_verify_s"] += time.monotonic() - tg
+        brk.record_success()
+        timings["verify_calls"] += 1
+        mx.flight(
+            "verify.device", shapes=n_shapes, txs=len(rows),
+            ok=int(sum(1 for g in ok if g)),
+        )
+        return ok
 
     def _host_proof_batch(
         self, rows: List[Tuple[int, int, tuple]],

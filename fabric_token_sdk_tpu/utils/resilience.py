@@ -36,7 +36,7 @@ in tests/test_resilience.py).
 
 Planes wired (one breaker each, registered lazily by name):
 
-    verify  — `BlockValidationPipeline.proof_verdicts` group calls
+    verify  — `BlockValidationPipeline.proof_verdicts` block calls
     sign    — `BlockValidationPipeline.sign_verdicts` (REPLACES the old
               permanent construction-failure latch: a transient OOM now
               heals via the half-open probe)

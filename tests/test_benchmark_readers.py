@@ -4,8 +4,9 @@
 guarded the yardstick: the reader files `benchmark/layer_metrics/*.json`
 with `benchmark/harness/readers.py` and the trace reduction
 `harness/trace.py`. These are the pure-reader cases of
-`benchmark/tests/test_arithmetic.py`, copied as they stand (no JAX, no
-chip): the final-exp readers at heights 8 and 128 on the committed
+`benchmark/tests/test_arithmetic.py`, copied as they stand but for the
+list of cells the final-exp readers are pinned to, which may grow (no JAX,
+no chip): the final-exp readers at heights 8 and 128 on the committed
 reader files, `None` where nothing was dispatched, no reader file
 naming a reader that is gone. Nothing under `benchmark/` is edited.
 
@@ -230,7 +231,13 @@ def test_every_reader_file_names_a_reader_and_the_kernel_pair_reads_the_device()
                          ("tiles.fexp_ms_per_tile", "program_span"),
                          ("kernel.miller_tile_ms", "program_span")):
         assert by_name[name]["source"] == source
-        assert by_name[name]["workloads"] == three
+        # (the benchmark's own copy of this case pins the list to the
+        # three; a cell added since is one whose slice waits for a
+        # pairing walk, as theirs do: `b300e5.wallets`, PR 37)
+        assert by_name[name]["workloads"][:3] == three
+        for cell in by_name[name]["workloads"][3:]:
+            spec = mf.cell(m, cell)["mix"]["trace"]
+            assert spec.get("after_counter") == "pairing.staged.calls"
     for name in ("kernel.fexp_tile_ms", "kernel.fexp_roofline"):
         spec = mf._load(mf.data_file("layer_metrics", name))
         assert "rows_per_dispatch" not in json.dumps(spec)

@@ -278,6 +278,7 @@ def test_ops_rpcs_answer_live_during_slow_commits(tmp_path):
     # `overlap_s` rides along only when the pipelined engine is active
     assert set(lb["breakdown"]) - {"overlap_s"} == {
         "queue_wait_max_s", "grouping_s", "device_verify_s",
+        "verify_calls",  # completed proof-plane calls: 0 or 1 (PR 37)
         "sign_verify_s", "host_validate_s", "host_unmarshal_s",
         "host_fiat_shamir_s", "host_sig_verify_s",
         "host_conservation_s", "host_input_match_s", "wal_s", "merge_s",

@@ -2,16 +2,17 @@
 
 Covers the block pipeline end to end: intra-block double spends (the
 LATER tx is invalidated, never the block), conflicts across consecutive
-blocks, same-shape zkatdlog groups riding ONE `BatchedTransferVerifier`
-call, mixed batched/host blocks (issues + odd shapes fall back to the
-host `RequestValidator`), differential block-mode vs per-tx commits,
+blocks, a block's zkatdlog transfers riding ONE `BatchedTransferVerifier`
+call whatever their shapes, mixed batched/host blocks (issues fall back
+to the host `RequestValidator`), differential block-mode vs per-tx commits,
 listener crash isolation, block-cut policy, and snapshot/restore of
 multi-tx blocks.
 
 The zkatdlog cases use 1-in/1-out transfers on purpose: that shape skips
 range proofs (reference transfer.go:55-59), so the batched path touches
 only the non-slow stage tiles — the pairing-heavy shapes stay in the
-slow-marked tests.
+slow-marked tests, but for the one `(1,2)` of the mixed block, whose
+pairing kernels are stood in for (`tests/hostplane.py`).
 """
 import random
 import threading
@@ -269,15 +270,23 @@ def test_zk_block_differential_vs_host(zk_pp):
     assert [e.status for e in seq[1:]] == [e.status for e in block_events]
 
 
-def test_zk_mixed_block_host_and_batched(zk_pp):
-    """One block mixing every plane: an issue (host), a same-shape
-    transfer group (batched), and an odd-shape singleton transfer (host
-    fallback) — plus an issue-only block as the empty-group case."""
+def test_zk_mixed_block_host_and_batched(zk_pp, monkeypatch):
+    """One block mixing every plane: an issue (host) and three transfers
+    of two shapes, which ride ONE batched call whatever their shapes
+    (until PR 37 the `(1,2)`, alone of its shape, fell to the host) —
+    plus an issue-only block as the no-rows case. The stage tiles are the
+    backend's real programs; the `(1,2)`'s membership proofs run the
+    pairing walk over exact host stand-ins for its three kernels
+    (`tests/hostplane.py`: the real ones cost the CPU backend minutes to
+    compile), so the verdicts are real."""
+    import hostplane
+
+    hostplane.install(monkeypatch, stage=False)
     network, parties, issuer, alice, bob = zk_env(
         zk_pp, BlockPolicy(max_block_txs=8, min_batch=2)
     )
     alice_p = parties["alice-node"]
-    issue_to(parties, alice, [5, 5, 5], "mx-seed")  # issue-only block: no groups
+    issue_to(parties, alice, [5, 5, 5], "mx-seed")  # issue-only block: no rows
 
     t1 = Transaction(alice_p, "mx-t1")
     t1.transfer("alice", "USD", [5], [bob.recipient_identity()])  # (1,1)
@@ -295,6 +304,8 @@ def test_zk_mixed_block_host_and_batched(zk_pp):
 
     before_batched = _counter("ledger.validate.batched")
     before_host = _counter("ledger.validate.host")
+    before_calls = _counter("batch.transfer.calls")
+    before_shapes = _counter("batch.transfer.shapes")
     h0 = network.height()
     events = network.submit_many(
         [issue2.request.to_bytes(), t1.request.to_bytes(),
@@ -302,9 +313,11 @@ def test_zk_mixed_block_host_and_batched(zk_pp):
     )
     assert all(e.status == TxStatus.VALID for e in events)
     assert network.height() == h0 + 1
-    # the (1,1) pair was batched; the (1,2) singleton fell back to host
-    assert _counter("ledger.validate.batched") - before_batched == 2
-    assert _counter("ledger.validate.host") - before_host == 1
+    # the (1,1) pair and the (1,2) rode one call; nothing fell to the host
+    assert _counter("ledger.validate.batched") - before_batched == 3
+    assert _counter("ledger.validate.host") - before_host == 0
+    assert _counter("batch.transfer.calls") - before_calls == 1
+    assert _counter("batch.transfer.shapes") - before_shapes == 2
     assert parties["bob-node"].balance("USD") == 13
     assert alice_p.balance("USD") == 4  # 2 change + 2 fresh issue
 
