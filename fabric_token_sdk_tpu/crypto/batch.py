@@ -6,7 +6,9 @@ transactions verify through a SMALL CONSTANT set of XLA programs:
 
 * `BatchedPSVerifier`      — Pointcheval-Sanders signature batches
 * `BatchedWFVerifier`      — transfer well-formedness sigma proofs
-* `BatchedMembershipVerifier` — the pairing side of membership proofs
+* `BatchedMembershipVerifier` — the pairing side of membership proofs,
+  two pairings a proof (the scalar verifier's four legs, those that
+  share an argument merged by bilinearity)
 * `BatchedTransferVerifier`— full transfer proofs (WF + range)
 
 Execution model (staged tiles — see `ops/stages.py`): every verifier is a
@@ -228,9 +230,23 @@ class BatchedWFVerifier:
 class BatchedMembershipVerifier:
     """Verifies B membership proofs (the per-digit unit of range proofs).
 
-    Device: GT commitment via 4-pairing products + G1 commitment via
+    Device: GT commitment via 2-pairing products + G1 commitment via
     fixed/variable multiexp — all through the compile-once stage tiles.
     Host: per-proof Fiat-Shamir challenge.
+
+    The scalar verifier's four legs (`sigproof.POKVerifier.
+    recompute_commitment`)
+
+        e(-S^c, Q) e(R^c, PK0) e(R, PK1^{z_v} + PK2^{z_h}) e(P^{z_bf}, Q)
+
+    are two here: the legs that share an argument are merged by
+    bilinearity (G1 of BN254 has cofactor 1, so it holds for every
+    curve point a proof can carry),
+
+        e(P^{z_bf} - S^c, Q) e(R, PK0^c + PK1^{z_v} + PK2^{z_h}),
+
+    the same element of GT after the final exponentiation, so the
+    challenge hashed over it is the same and no verdict differs.
     """
 
     def __init__(self, pp: PublicParams):
@@ -242,7 +258,6 @@ class BatchedMembershipVerifier:
         self.ped2 = pp.ped_params[:2]
         self.pk_np = np.asarray(cv2.encode_points(self.pk))  # (l+2,3,2,L)
         self.Q_np = np.asarray(pr.encode_g2([self.Q]))[0]
-        self.pk0_np = np.asarray(pr.encode_g2([self.pk[0]]))[0]
         self.table2 = cv.FixedBaseTable(self.ped2)
         self.tableP = cv.FixedBaseTable([self.P])
 
@@ -267,45 +282,41 @@ class BatchedMembershipVerifier:
         com_resp = np.stack(
             [z[:, 0], cv.encode_scalars([p.com_bf_resp for p in proofs])], axis=1
         )
-        neg_chal = cv.encode_scalars([-p.challenge for p in proofs])
-        S_np = np.asarray(pr.encode_g1([p.signature.S for p in proofs]))
+        S_jac = np.stack([cv.encode_point(p.signature.S) for p in proofs])
         R_np = np.asarray(pr.encode_g1([p.signature.R for p in proofs]))
         com_jac = np.stack([cv.encode_point(c) for c in commitments])
 
-        # G2 term: t = PK1^{z_v} + PK2^{z_h}
+        # G2 term: t' = PK0^c + PK1^{z_v} + PK2^{z_h}
         bases = np.broadcast_to(
-            self.pk_np[1:3], (B, 2) + self.pk_np.shape[1:]
-        ).reshape((2 * B,) + self.pk_np.shape[1:])
-        terms = st.g2_mul_rows(bases, z[:, 0:2].reshape(2 * B, L))
-        terms = terms.reshape((B, 2) + terms.shape[1:])
-        t_aff = st.g2_to_affine_rows(st.g2_add_rows(terms[:, 0], terms[:, 1]))
+            self.pk_np[0:3], (B, 3) + self.pk_np.shape[1:]
+        ).reshape((3 * B,) + self.pk_np.shape[1:])
+        terms = st.g2_mul_rows(bases, z[:, [3, 0, 1]].reshape(3 * B, L))
+        t_jac = st.g2_tree_sum_rows(terms.reshape((B, 3) + terms.shape[1:]))
+        t_aff = st.g2_to_affine_rows(t_jac)
 
-        # G1 sides: -S^c as S^{r-c} (scalar negation — no extra neg
-        # program), R^c, and P^{z_bf}; one fused to-affine pass for all
-        Sj = st.affine_to_jac_np(S_np)
-        Rj = st.affine_to_jac_np(R_np)
-        powc = st.g1_mul_rows(
-            np.concatenate([Sj, Rj]), np.concatenate([neg_chal, z[:, 3]]),
-        )
+        # G1 term: P^{z_bf} - S^c (R is affine on the wire)
+        Sc = st.g1_mul_rows(S_jac, z[:, 3])
         Pz_j = st.g1_msm_rows(self.tableP.flat, z[:, 2:3])
-        aff = st.g1_to_affine_rows(np.concatenate([powc, Pz_j]))
-        negSc, Rc, Pz = aff[:B], aff[B : 2 * B], aff[2 * B :]
+        m_jac = st.g1_sub_rows(Pz_j, Sc)
+        m_aff = st.g1_to_affine_rows(m_jac)
 
         # G1 commitment: ped0^{z_v} ped1^{z_cb} - com^c
         fixed = st.g1_msm_rows(self.table2.flat, com_resp)
         comc = st.g1_mul_rows(com_jac, z[:, 3])
         com_val = st.g1_sub_rows(fixed, comc)
 
-        # 4-leg pairing product via the compile-once staged tile programs
-        Ps = np.stack([negSc, Rc, R_np, Pz], axis=1)  # (B, 4, 2, L)
+        # 2-leg pairing product via the compile-once staged tile programs.
+        # A sender can make either merged point the point at infinity
+        # (S^c = P^{z_bf}, say): the to-affine tiles return no point
+        # there, and the leg is the identity
+        Ps = np.stack([m_aff, R_np], axis=1)  # (B, 2, 2, L)
         Qs = np.stack(
-            [np.broadcast_to(self.Q_np, t_aff.shape),
-             np.broadcast_to(self.pk0_np, t_aff.shape),
-             t_aff,
-             np.broadcast_to(self.Q_np, t_aff.shape)],
-            axis=1,
-        )  # (B, 4, 2, 2, L)
-        gt = pr.pairing_product_staged(Ps, Qs)
+            [np.broadcast_to(self.Q_np, t_aff.shape), t_aff], axis=1
+        )  # (B, 2, 2, 2, L)
+        inf = np.stack(
+            [st.jac_infinity_np(m_jac), st.jac_infinity_np(t_jac)], axis=1
+        )
+        gt = pr.pairing_product_staged(Ps, Qs, inf_mask=inf)
         gt_host = tw.decode_fp12(gt)
         com_host = cv.decode_points(com_val)
         out = np.zeros(B, dtype=bool)

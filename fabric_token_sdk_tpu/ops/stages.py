@@ -99,7 +99,12 @@ _TPU_TILE_ROWS = 128
 # T with ceil(32 / T) * c(T) <= 2 * c(16), so that the smallest device
 # block (32 rows, two dispatches of 16 = 186 ms) does not get slower.
 # 128: 772 + 290 ms; 256: 710 + 355 ms (and 177 ms for a block of 32);
-# 512 is out (387 ms for a block of 32).
+# 512 is out (387 ms for a block of 32). Those rows were four legs a
+# membership proof; since PR 39 a proof has two (`crypto/batch.py`), the
+# two calls are 512 and 160 rows and the smallest block 16: by the same
+# sweep 128: 386 + 193 ms, 256: 355 + 177 ms, but a 3-tx block of the
+# test network's channel (60 rows) and the 2-tx block would pay 177 ms
+# for 96.5, so 128 stays (reckoned from PR 29's sweep, not swept again).
 _HOST_MILLER_ROWS = 16
 _TPU_MILLER_ROWS = 128
 # The final-exp tile of the same product (ledger frame `fexp_tile`: the
@@ -408,6 +413,16 @@ def affine_to_jac_np(p: np.ndarray) -> np.ndarray:
         np.asarray(FP.one_mont, dtype=np.int32), p[..., 0, :].shape
     )
     return np.concatenate([p, one[..., None, :]], axis=-2)
+
+
+def jac_infinity_np(p: np.ndarray) -> np.ndarray:
+    """Host glue: Jacobian rows, (N, 3, L) in G1 or (N, 3, 2, L) in G2,
+    -> (N,) bool, True where Z == 0: the point at infinity, for which
+    the to-affine tiles return (0, 0) and no point. An element lives in
+    [0, 2p), so the limbs of 0 and of p both read zero."""
+    z = p[:, 2].reshape(p.shape[0], -1, p.shape[-1])
+    zero = (z == 0).all(axis=-1) | (z == FP.p_limbs).all(axis=-1)
+    return zero.all(axis=-1)
 
 
 # ------------------------------------------------------------ warmup hooks

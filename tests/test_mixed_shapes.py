@@ -13,9 +13,13 @@ padding over exact host stand-ins for the tile kernels
 (`tests/hostplane.py`): real verdicts, no compile. One `slow` case runs
 a mixed call through the real programs.
 
-`tests/data/one_shape_calls.json` holds what a call of ONE shape handed
-the stage functions and the pairing walk on the parent of PR 37 (the
-per-shape code): `python tests/test_mixed_shapes.py --record` prints it.
+`tests/data/one_shape_calls.json` holds what a call of ONE shape hands
+the stage functions and the pairing walk: `python
+tests/test_mixed_shapes.py --record` prints it. Recorded first on the
+parent of PR 37 (the per-shape code); again in PR 39 for the two legs a
+membership row has since (`tests/test_membership_legs.py`), where the
+well-formedness calls, the membership verifier's G1 commitment calls and
+the equality calls stayed the parent's bit for bit.
 """
 import json
 import os
@@ -342,8 +346,11 @@ def test_served_mixed_block_is_one_call_with_the_scalar_verdicts(
     assert mixed["dispatches"] == uniform["dispatches"]
     assert mixed["dispatches"]["miller_tile"] == 1
     assert mixed["dispatches"]["fexp_tile"] == 1
-    # 18 stage calls, one Miller walk, one final exponentiation: a tile each
-    assert sum(mixed["dispatches"].values()) == 20
+    # 20 stage calls (18 + the membership rows' `g1_sub` and second
+    # `g2_add` since its legs are two), one Miller walk, one final
+    # exponentiation: a tile each
+    assert sum(mixed["dispatches"].values()) == 22
+    assert mixed["dispatches"]["g1_to_affine_tile"] == 1
 
 
 def test_a_block_under_min_batch_goes_to_the_host_whole(monkeypatch, pp):
