@@ -24,7 +24,8 @@ node-side), and process/device memory. Ctrl-C exits cleanly.
 `devices` polls the same `ops.health` RPC and renders the device-plane
 dispatch ledger (`utils/devobs.py`) as a per-program table: dispatches,
 mean occupancy, padding waste %, p50/p99 dispatch wall, compiles with
-their wall time, persistent-cache hits/misses, and degrade decisions.
+their wall time and persistent-cache hits/misses; above it, per plane,
+how its spans' host glue divides by part.
 
 `compare` is the observatory: it diffs bench results against each other
 or against the history file `bench.py` appends every outcome to
@@ -221,7 +222,8 @@ def _pct(v) -> str:
 def format_devices(health: dict) -> str:
     """The per-program device-plane table from an `ops.health` dict
     (pure — unit-testable without a socket). One header line with the
-    per-plane occupancy roll-up, one row per (plane, program)."""
+    per-plane occupancy roll-up, one line per plane with its host glue
+    by part (`glue_parts`), one row per (plane, program)."""
     dev = health.get("device")
     if not isinstance(dev, dict):
         return "devices: node predates the dispatch ledger"
@@ -238,10 +240,17 @@ def format_devices(health: dict) -> str:
     if not programs:
         return head
     lines = [head]
+    for name, p in sorted(planes.items()):
+        if p.get("glue_parts"):
+            lines.append(
+                f"glue {name}[{_s(p.get('glue_s'))}]: " + "  ".join(
+                    f"{part}={_s(v)}" for part, v in p["glue_parts"].items()
+                )
+            )
     cols = (
         f"{'plane':<8} {'program':<20} {'disp':>6} {'occ':>7} "
         f"{'waste':>7} {'p50':>9} {'p99':>9} "
-        f"{'compiles':>8} {'comp_s':>7} {'hit/miss':>9} {'degr':>5}"
+        f"{'compiles':>8} {'comp_s':>7} {'hit/miss':>9}"
     )
     lines.append(cols)
     for _key, r in sorted(programs.items()):
@@ -251,8 +260,7 @@ def format_devices(health: dict) -> str:
             f"{_pct(r.get('waste_frac')):>7} {_s(r.get('p50_s')):>9} "
             f"{_s(r.get('p99_s')):>9} "
             f"{r.get('compiles', 0):>8} {r.get('compile_s', 0):>7g} "
-            f"{r.get('cache_hits', 0)}/{r.get('cache_misses', 0):<4} "
-            f"{r.get('degrades', 0):>5}"
+            f"{r.get('cache_hits', 0)}/{r.get('cache_misses', 0):<4}"
         )
     return "\n".join(lines)
 
@@ -582,7 +590,6 @@ def compare_device(args) -> int:
             f"waste={s.get('waste_frac')} "
             f"p99={s.get('dispatch_p99_s')}s "
             f"compiles={s.get('compiles', 0)} "
-            f"degrades={s.get('degrades', 0)} "
             f"planes={','.join(sorted((s.get('planes') or {})))}"
         ),
     )

@@ -360,8 +360,7 @@ def devices(path: str, plane: Optional[str] = None, out=None) -> int:
         f"compiles={dev.get('compiles', 0)} "
         f"({dev.get('compile_s', 0)}s)  "
         f"cache={dev.get('cache_hits', 0)}h/"
-        f"{dev.get('cache_misses', 0)}m  "
-        f"degrades={dev.get('degrades', 0)}",
+        f"{dev.get('cache_misses', 0)}m",
         file=out,
     )
     total_wall = sum(
@@ -384,8 +383,7 @@ def devices(path: str, plane: Optional[str] = None, out=None) -> int:
             f"wall={_fmt_s(r.get('wall_s', 0.0)):>8} ({share:.0%}) "
             f"p50={_fmt_s(r.get('p50_s') or 0.0):>8} "
             f"p99={_fmt_s(r.get('p99_s') or 0.0):>8} "
-            f"compiles={r.get('compiles', 0)} "
-            f"degrades={r.get('degrades', 0)}",
+            f"compiles={r.get('compiles', 0)}",
             file=out,
         )
     return 0

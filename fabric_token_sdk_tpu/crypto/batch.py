@@ -88,23 +88,33 @@ class BatchedPSVerifier:
             return np.zeros(0, dtype=bool)
         mx.counter("batch.ps.sigs").inc(B)
         l = len(self.pk_host) - 2
-        scal = np.zeros((B, l + 1, lb.NLIMBS), dtype=np.int32)
-        negS, R = [], []
         malformed = np.zeros(B, dtype=bool)
-        for i, (msgs, sig) in enumerate(zip(messages_rows, sigs)):
-            try:
-                if len(msgs) != l:
-                    raise ValueError("PS batch: message count mismatch")
-                ms = list(msgs) + [pssign.hash_messages(msgs)]
-                scal[i] = cv.encode_scalars(ms)
-                negS.append(hm.g1_neg(sig.S))
-                R.append(sig.R)
-            except Exception:
-                malformed[i] = True
-                negS.append(hm.G1_GEN)  # placeholder; row forced False
-                R.append(hm.G1_GEN)
-        P1 = np.asarray(pr.encode_g1(negS))
-        P2 = np.asarray(pr.encode_g1(R))
+        with devobs.glue("challenge"):
+            hashed: List[Optional[list]] = []
+            for i, msgs in zip(range(B), messages_rows):
+                try:
+                    if len(msgs) != l:
+                        raise ValueError("PS batch: message count mismatch")
+                    hashed.append(list(msgs) + [pssign.hash_messages(msgs)])
+                except Exception:
+                    malformed[i] = True
+                    hashed.append(None)
+        with devobs.glue("encode"):
+            scal = np.zeros((B, l + 1, lb.NLIMBS), dtype=np.int32)
+            negS, R = [], []
+            for i, (ms, sig) in enumerate(zip(hashed, sigs)):
+                try:
+                    if ms is None:
+                        raise ValueError("PS batch: malformed messages")
+                    scal[i] = cv.encode_scalars(ms)
+                    negS.append(hm.g1_neg(sig.S))  # a sign, no curve work
+                    R.append(sig.R)
+                except Exception:
+                    malformed[i] = True
+                    negS.append(hm.G1_GEN)  # placeholder; row forced False
+                    R.append(hm.G1_GEN)
+            P1 = np.asarray(pr.encode_g1(negS))
+            P2 = np.asarray(pr.encode_g1(R))
         # H = PK0 + sum PK_i^{m_i} (+ PK_last^{hash}) in G2, staged:
         # one flat scalar-mul pass, a host-folded tree sum, one to-affine
         k = l + 1
@@ -120,7 +130,8 @@ class BatchedPSVerifier:
             [np.broadcast_to(self.Q_np, H_aff.shape), H_aff], axis=1
         )  # (B, 2, 2, 2, L)
         gt = pr.pairing_product_staged(Ps, Qs)
-        out = pr.gt_is_one_host(gt)
+        with devobs.glue("decode"):
+            out = pr.gt_is_one_host(gt)
         out[malformed] = False
         return out
 
@@ -153,72 +164,89 @@ class BatchedWFVerifier:
         # transaction i owns the flat rows at[i]:at[i+1]
         at = np.concatenate([[0], np.cumsum(ns)])
         N = int(at[-1])
-        proofs: List[Optional[TransferWF]] = []
-        for t in txs:
-            try:
-                proofs.append(TransferWF.from_bytes(t[2]))
-            except Exception:
-                proofs.append(None)  # malformed: row verifies False
-        stmts: List = []
-        resp = np.zeros((N, 3, lb.NLIMBS), dtype=np.int32)
-        chals = np.zeros((B, lb.NLIMBS), dtype=np.int32)
         ok_shape = np.ones(B, dtype=bool)
-        for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
-            n_in, n_out = len(inputs), len(outputs)
-            if (
-                wf is None
-                or len(wf.input_values) != n_in
-                or len(wf.input_bfs) != n_in
-                or len(wf.output_values) != n_out
-                or len(wf.output_bfs) != n_out
-            ):
-                ok_shape[i] = False
-                stmts.extend([None] * ns[i])
-                continue
-            stmts.extend(inputs)
-            stmts.append(hm.g1_sum(inputs))
-            stmts.extend(outputs)
-            stmts.append(hm.g1_sum(outputs))
-            rows = []
-            for k in range(n_in):
-                rows.append([wf.type_resp, wf.input_values[k], wf.input_bfs[k]])
-            rows.append(
-                [
-                    wf.type_resp * n_in % hm.R,
-                    wf.sum_resp,
-                    sum(wf.input_bfs) % hm.R,
-                ]
-            )
-            for k in range(n_out):
-                rows.append([wf.type_resp, wf.output_values[k], wf.output_bfs[k]])
-            rows.append(
-                [
-                    wf.type_resp * n_out % hm.R,
-                    wf.sum_resp,
-                    sum(wf.output_bfs) % hm.R,
-                ]
-            )
-            for j, r in enumerate(rows):
-                resp[at[i] + j] = cv.encode_scalars(r)
-            chals[i] = cv.encode_scalars([wf.challenge])[0]
-
-        stmt_np = np.stack([cv.encode_point(s) for s in stmts])
+        with devobs.glue("parse"):
+            proofs: List[Optional[TransferWF]] = []
+            for i, (inputs, outputs, raw) in enumerate(txs):
+                try:
+                    wf = TransferWF.from_bytes(raw)
+                except Exception:
+                    wf = None  # malformed: row verifies False
+                proofs.append(wf)
+                n_in, n_out = len(inputs), len(outputs)
+                ok_shape[i] = (
+                    wf is not None
+                    and len(wf.input_values) == n_in
+                    and len(wf.input_bfs) == n_in
+                    and len(wf.output_values) == n_out
+                    and len(wf.output_bfs) == n_out
+                )
+        with devobs.glue("hostec"):
+            # the two aggregate statements of a transaction
+            sums = [
+                (hm.g1_sum(t[0]), hm.g1_sum(t[1])) if ok_shape[i] else None
+                for i, t in enumerate(txs)
+            ]
+        with devobs.glue("encode"):
+            stmts: List = []
+            resp = np.zeros((N, 3, lb.NLIMBS), dtype=np.int32)
+            chals = np.zeros((B, lb.NLIMBS), dtype=np.int32)
+            for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
+                if not ok_shape[i]:
+                    stmts.extend([None] * ns[i])
+                    continue
+                n_in, n_out = len(inputs), len(outputs)
+                stmts.extend(inputs)
+                stmts.append(sums[i][0])
+                stmts.extend(outputs)
+                stmts.append(sums[i][1])
+                rows = []
+                for k in range(n_in):
+                    rows.append(
+                        [wf.type_resp, wf.input_values[k], wf.input_bfs[k]]
+                    )
+                rows.append(
+                    [
+                        wf.type_resp * n_in % hm.R,
+                        wf.sum_resp,
+                        sum(wf.input_bfs) % hm.R,
+                    ]
+                )
+                for k in range(n_out):
+                    rows.append(
+                        [wf.type_resp, wf.output_values[k], wf.output_bfs[k]]
+                    )
+                rows.append(
+                    [
+                        wf.type_resp * n_out % hm.R,
+                        wf.sum_resp,
+                        sum(wf.output_bfs) % hm.R,
+                    ]
+                )
+                for j, r in enumerate(rows):
+                    resp[at[i] + j] = cv.encode_scalars(r)
+                chals[i] = cv.encode_scalars([wf.challenge])[0]
+            stmt_np = np.stack([cv.encode_point(s) for s in stmts])
+            chal_rep = np.repeat(chals, ns, axis=0)
         # com_j = prod ped_i^{resp_ji} - stmt_j^challenge over the flat rows
         fixed = st.g1_msm_rows(self.table.flat, resp)
-        sc = st.g1_mul_rows(stmt_np, np.repeat(chals, ns, axis=0))
+        sc = st.g1_mul_rows(stmt_np, chal_rep)
         coms = st.g1_sub_rows(fixed, sc)
-        com_pts = cv.decode_points(coms)  # N host points
+        with devobs.glue("decode"):
+            com_pts = cv.decode_points(coms)  # N host points
         out = np.zeros(B, dtype=bool)
-        for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
-            if not ok_shape[i] or wf is None:
-                continue
-            row = com_pts[at[i] : at[i + 1]]
-            in_coms = row[: len(inputs) + 1]
-            out_coms = row[len(inputs) + 1 :]
-            chal = challenge_transfer_wf(
-                in_coms[:-1], in_coms[-1], out_coms[:-1], out_coms[-1], inputs, outputs
-            )
-            out[i] = chal == wf.challenge
+        with devobs.glue("challenge"):
+            for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
+                if not ok_shape[i]:
+                    continue
+                row = com_pts[at[i] : at[i + 1]]
+                in_coms = row[: len(inputs) + 1]
+                out_coms = row[len(inputs) + 1 :]
+                chal = challenge_transfer_wf(
+                    in_coms[:-1], in_coms[-1], out_coms[:-1], out_coms[-1],
+                    inputs, outputs,
+                )
+                out[i] = chal == wf.challenge
         return out
 
 
@@ -269,22 +297,25 @@ class BatchedMembershipVerifier:
             return np.zeros(0, dtype=bool)
         mx.counter("batch.membership.proofs").inc(B)
         L = lb.NLIMBS
-        # one vectorized limb encoding per response field across the batch
-        z = np.stack(
-            [
-                cv.encode_scalars([p.value_resp for p in proofs]),
-                cv.encode_scalars([p.hash_resp for p in proofs]),
-                cv.encode_scalars([p.sig_bf_resp for p in proofs]),
-                cv.encode_scalars([p.challenge for p in proofs]),
-            ],
-            axis=1,
-        )  # (B, 4, L): value, hash, sig_bf, chal
-        com_resp = np.stack(
-            [z[:, 0], cv.encode_scalars([p.com_bf_resp for p in proofs])], axis=1
-        )
-        S_jac = np.stack([cv.encode_point(p.signature.S) for p in proofs])
-        R_np = np.asarray(pr.encode_g1([p.signature.R for p in proofs]))
-        com_jac = np.stack([cv.encode_point(c) for c in commitments])
+        with devobs.glue("encode"):
+            # one vectorized limb encoding per response field across the
+            # batch
+            z = np.stack(
+                [
+                    cv.encode_scalars([p.value_resp for p in proofs]),
+                    cv.encode_scalars([p.hash_resp for p in proofs]),
+                    cv.encode_scalars([p.sig_bf_resp for p in proofs]),
+                    cv.encode_scalars([p.challenge for p in proofs]),
+                ],
+                axis=1,
+            )  # (B, 4, L): value, hash, sig_bf, chal
+            com_resp = np.stack(
+                [z[:, 0], cv.encode_scalars([p.com_bf_resp for p in proofs])],
+                axis=1,
+            )
+            S_jac = np.stack([cv.encode_point(p.signature.S) for p in proofs])
+            R_np = np.asarray(pr.encode_g1([p.signature.R for p in proofs]))
+            com_jac = np.stack([cv.encode_point(c) for c in commitments])
 
         # G2 term: t' = PK0^c + PK1^{z_v} + PK2^{z_h}
         bases = np.broadcast_to(
@@ -309,23 +340,28 @@ class BatchedMembershipVerifier:
         # A sender can make either merged point the point at infinity
         # (S^c = P^{z_bf}, say): the to-affine tiles return no point
         # there, and the leg is the identity
-        Ps = np.stack([m_aff, R_np], axis=1)  # (B, 2, 2, L)
-        Qs = np.stack(
-            [np.broadcast_to(self.Q_np, t_aff.shape), t_aff], axis=1
-        )  # (B, 2, 2, 2, L)
-        inf = np.stack(
-            [st.jac_infinity_np(m_jac), st.jac_infinity_np(t_jac)], axis=1
-        )
+        with devobs.glue("encode"):
+            Ps = np.stack([m_aff, R_np], axis=1)  # (B, 2, 2, L)
+            Qs = np.stack(
+                [np.broadcast_to(self.Q_np, t_aff.shape), t_aff], axis=1
+            )  # (B, 2, 2, 2, L)
+            inf = np.stack(
+                [st.jac_infinity_np(m_jac), st.jac_infinity_np(t_jac)], axis=1
+            )
         gt = pr.pairing_product_staged(Ps, Qs, inf_mask=inf)
-        gt_host = tw.decode_fp12(gt)
-        com_host = cv.decode_points(com_val)
+        with devobs.glue("decode"):
+            gt_host = tw.decode_fp12(gt)
+            com_host = cv.decode_points(com_val)
         out = np.zeros(B, dtype=bool)
-        for i, (p, com) in enumerate(zip(proofs, commitments)):
-            if p.commitment != com:
-                continue
-            mv = sigproof.MembershipVerifier(com, self.P, self.Q, self.pk, self.ped2)
-            chal = mv._challenge(gt_host[i], com_host[i], p.signature)
-            out[i] = chal == p.challenge
+        with devobs.glue("challenge"):
+            for i, (p, com) in enumerate(zip(proofs, commitments)):
+                if p.commitment != com:
+                    continue
+                mv = sigproof.MembershipVerifier(
+                    com, self.P, self.Q, self.pk, self.ped2
+                )
+                chal = mv._challenge(gt_host[i], com_host[i], p.signature)
+                out[i] = chal == p.challenge
         return out
 
 
@@ -380,12 +416,13 @@ class BatchedTransferVerifier:
 
         proofs = []
         ok = np.ones(B, dtype=bool)
-        for i, t in enumerate(txs):
-            try:
-                proofs.append(TransferProof.from_bytes(t[2]))
-            except Exception:
-                proofs.append(TransferProof(wf=b"", range_correctness=None))
-                ok[i] = False
+        with devobs.glue("parse"):
+            for i, t in enumerate(txs):
+                try:
+                    proofs.append(TransferProof.from_bytes(t[2]))
+                except Exception:
+                    proofs.append(TransferProof(wf=b"", range_correctness=None))
+                    ok[i] = False
         wf_ok = self.wf.verify(
             [(t[0], t[1], p.wf) for t, p in zip(txs, proofs)]
         )
@@ -394,30 +431,31 @@ class BatchedTransferVerifier:
         rp = self.pp.range_params
         exponent, base = rp.exponent, rp.base
         ranges: List[Optional[RangeProof]] = []
-        for i, (p, (n_in, n_out)) in enumerate(zip(proofs, shapes)):
-            if _skip_range(n_in, n_out):
-                ranges.append(None)  # the WF verdict is the whole verdict
-                continue
-            if p.range_correctness is None:
-                ok[i] = False
-                ranges.append(None)
-                continue
-            try:
-                rpf = RangeProof.from_bytes(p.range_correctness)
-                if (
-                    len(rpf.membership_proofs) != n_out
-                    or len(rpf.digit_commitments) != n_out
-                    or any(len(r) != exponent for r in rpf.membership_proofs)
-                    or any(len(r) != exponent for r in rpf.digit_commitments)
-                    or len(rpf.value_resps) != n_out
-                    or len(rpf.token_bf_resps) != n_out
-                    or len(rpf.com_bf_resps) != n_out
-                ):
-                    raise ValueError("range proof not well formed")
-                ranges.append(rpf)
-            except Exception:
-                ok[i] = False
-                ranges.append(None)
+        with devobs.glue("parse"):
+            for i, (p, (n_in, n_out)) in enumerate(zip(proofs, shapes)):
+                if _skip_range(n_in, n_out):
+                    ranges.append(None)  # the WF verdict is the whole verdict
+                    continue
+                if p.range_correctness is None:
+                    ok[i] = False
+                    ranges.append(None)
+                    continue
+                try:
+                    rpf = RangeProof.from_bytes(p.range_correctness)
+                    if (
+                        len(rpf.membership_proofs) != n_out
+                        or len(rpf.digit_commitments) != n_out
+                        or any(len(r) != exponent for r in rpf.membership_proofs)
+                        or any(len(r) != exponent for r in rpf.digit_commitments)
+                        or len(rpf.value_resps) != n_out
+                        or len(rpf.token_bf_resps) != n_out
+                        or len(rpf.com_bf_resps) != n_out
+                    ):
+                        raise ValueError("range proof not well formed")
+                    ranges.append(rpf)
+                except Exception:
+                    ok[i] = False
+                    ranges.append(None)
 
         # ---- membership proofs, flattened over (tx, output, digit)
         mem_proofs, mem_coms, mem_idx = [], [], []
@@ -446,31 +484,36 @@ class BatchedTransferVerifier:
         # live transaction li owns the flat rows at[li]:at[li+1]
         at = np.concatenate([[0], np.cumsum(n_outs)])
         N = int(at[-1])
-        tok_resp = np.zeros((N, 3, L), dtype=np.int32)
-        tok_stmt = np.zeros((N, 3, L), dtype=np.int32)
-        agg_resp = np.zeros((N, 2, L), dtype=np.int32)
-        agg_stmt = np.zeros((N, 3, L), dtype=np.int32)
-        chals = np.zeros((len(live), L), dtype=np.int32)
-        for li, i in enumerate(live):
-            rpf = ranges[i]
-            outputs = txs[i][1]
-            for k in range(n_outs[li]):
-                r = at[li] + k
-                tok_resp[r] = cv.encode_scalars(
-                    [rpf.type_resp, rpf.value_resps[k], rpf.token_bf_resps[k]]
-                )
-                tok_stmt[r] = cv.encode_point(outputs[k])
-                agg = hm.g1_multiexp(
-                    rpf.digit_commitments[k],
-                    [base**d % hm.R for d in range(exponent)],
-                )
-                agg_stmt[r] = cv.encode_point(agg)
-                agg_resp[r] = cv.encode_scalars(
-                    [rpf.value_resps[k], rpf.com_bf_resps[k]]
-                )
-            chals[li] = cv.encode_scalars([rpf.challenge])[0]
+        with devobs.glue("hostec"):
+            # an output's digit commitments folded: prod_d com_d^(base^d)
+            powers = [base**d % hm.R for d in range(exponent)]
+            aggs = [
+                hm.g1_multiexp(ranges[i].digit_commitments[k], powers)
+                for li, i in enumerate(live)
+                for k in range(n_outs[li])
+            ]
+        with devobs.glue("encode"):
+            tok_resp = np.zeros((N, 3, L), dtype=np.int32)
+            tok_stmt = np.zeros((N, 3, L), dtype=np.int32)
+            agg_resp = np.zeros((N, 2, L), dtype=np.int32)
+            agg_stmt = np.zeros((N, 3, L), dtype=np.int32)
+            chals = np.zeros((len(live), L), dtype=np.int32)
+            for li, i in enumerate(live):
+                rpf = ranges[i]
+                outputs = txs[i][1]
+                for k in range(n_outs[li]):
+                    r = at[li] + k
+                    tok_resp[r] = cv.encode_scalars(
+                        [rpf.type_resp, rpf.value_resps[k], rpf.token_bf_resps[k]]
+                    )
+                    tok_stmt[r] = cv.encode_point(outputs[k])
+                    agg_stmt[r] = cv.encode_point(aggs[r])
+                    agg_resp[r] = cv.encode_scalars(
+                        [rpf.value_resps[k], rpf.com_bf_resps[k]]
+                    )
+                chals[li] = cv.encode_scalars([rpf.challenge])[0]
+            chal_rep = np.repeat(chals, n_outs, axis=0)
 
-        chal_rep = np.repeat(chals, n_outs, axis=0)
         com_tok = st.g1_sub_rows(
             st.g1_msm_rows(self.table3.flat, tok_resp),
             st.g1_mul_rows(tok_stmt, chal_rep),
@@ -479,22 +522,24 @@ class BatchedTransferVerifier:
             st.g1_msm_rows(self.table2.flat, agg_resp),
             st.g1_mul_rows(agg_stmt, chal_rep),
         )
-        com_tok_h = cv.decode_points(com_tok)
-        com_val_h = cv.decode_points(com_val)
+        with devobs.glue("decode"):
+            com_tok_h = cv.decode_points(com_tok)
+            com_val_h = cv.decode_points(com_val)
         from .rangeproof import RangeVerifier
 
-        for li, i in enumerate(live):
-            rpf = ranges[i]
-            verifier = RangeVerifier(
-                txs[i][1], base, exponent, self.pp.ped_params,
-                rp.sign_pk, self.pp.ped_gen, rp.Q,
-            )
-            chal = verifier._challenge(
-                com_tok_h[at[li] : at[li + 1]],
-                com_val_h[at[li] : at[li + 1]],
-                rpf.digit_commitments,
-            )
-            if chal != rpf.challenge:
-                ok[i] = False
+        with devobs.glue("challenge"):
+            for li, i in enumerate(live):
+                rpf = ranges[i]
+                verifier = RangeVerifier(
+                    txs[i][1], base, exponent, self.pp.ped_params,
+                    rp.sign_pk, self.pp.ped_gen, rp.Q,
+                )
+                chal = verifier._challenge(
+                    com_tok_h[at[li] : at[li + 1]],
+                    com_val_h[at[li] : at[li + 1]],
+                    rpf.digit_commitments,
+                )
+                if chal != rpf.challenge:
+                    ok[i] = False
         _count_done()
         return ok

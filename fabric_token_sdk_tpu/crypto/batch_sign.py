@@ -37,7 +37,7 @@ from . import hostmath as hm, sign
 from .batch import _spanned
 from .serialization import loads
 from ..ops import curve as cv, stages as st
-from ..utils import metrics as mx, resilience
+from ..utils import devobs, metrics as mx, resilience
 
 
 class BatchedSchnorrVerifier:
@@ -70,40 +70,47 @@ class BatchedSchnorrVerifier:
             return []
         mx.counter("batch.sign.batches").inc()
         parsed: List[Optional[Tuple[int, int]]] = []
-        for _pk, _msg, sig_raw in rows:
-            try:
-                d = loads(sig_raw)
-                chal, resp = d["c"], d["z"]
-                if (
-                    not isinstance(chal, int) or isinstance(chal, bool)
-                    or not isinstance(resp, int) or isinstance(resp, bool)
-                ):
-                    raise ValueError("non-integer signature fields")
-                parsed.append((chal, resp))
-            except Exception:
-                parsed.append(None)  # host path reports the precise error
+        with devobs.glue("parse"):
+            for _pk, _msg, sig_raw in rows:
+                try:
+                    d = loads(sig_raw)
+                    chal, resp = d["c"], d["z"]
+                    if (
+                        not isinstance(chal, int) or isinstance(chal, bool)
+                        or not isinstance(resp, int) or isinstance(resp, bool)
+                    ):
+                        raise ValueError("non-integer signature fields")
+                    parsed.append((chal, resp))
+                except Exception:
+                    parsed.append(None)  # host path reports the precise error
         live = [i for i in range(B) if parsed[i] is not None]
         verdicts: List[Optional[bool]] = [None] * B
         if not live:
             return verdicts
         # flat rows: com = table^z - pk^c over the msm/mul/sub tiles
-        resp_np = cv.encode_scalars([parsed[i][1] for i in live])[:, None, :]
-        chal_np = cv.encode_scalars([parsed[i][0] for i in live])
-        pk_np = np.stack([cv.encode_point(rows[i][0]) for i in live])
+        with devobs.glue("encode"):
+            resp_np = cv.encode_scalars(
+                [parsed[i][1] for i in live]
+            )[:, None, :]
+            chal_np = cv.encode_scalars([parsed[i][0] for i in live])
+            pk_np = np.stack([cv.encode_point(rows[i][0]) for i in live])
         coms = st.g1_sub_rows(
             st.g1_msm_rows(self.table.flat, resp_np),
             st.g1_mul_rows(pk_np, chal_np),
         )
-        com_pts = cv.decode_points(coms)
+        with devobs.glue("decode"):
+            com_pts = cv.decode_points(coms)
         # counted on COMPLETION only (PR-9 precedent): a device failure
         # above falls to host and must never report as device-verified —
         # nor may an ABANDONED bounded worker that completes late (its
         # rows were already counted as host fallbacks by the caller)
         if not resilience.call_abandoned():
             mx.counter("batch.sign.rows").inc(len(live))
-        for j, i in enumerate(live):
-            pk_point, message, _sig = rows[i]
-            verdicts[i] = (
-                sign.challenge(pk_point, com_pts[j], message) == parsed[i][0]
-            )
+        with devobs.glue("challenge"):
+            for j, i in enumerate(live):
+                pk_point, message, _sig = rows[i]
+                verdicts[i] = (
+                    sign.challenge(pk_point, com_pts[j], message)
+                    == parsed[i][0]
+                )
         return verdicts

@@ -366,7 +366,6 @@ DEVICE_OPTIONAL = {
     "compile_s": _NUM,
     "cache_hits": int,
     "cache_misses": int,
-    "degrades": int,
 }
 
 _DEVICE_PLANE_REQUIRED = {

@@ -20,6 +20,7 @@ feeds the metrics registry:
   * ``device.<plane>.occupancy``           — rows / (rows + padding)
   * ``device.<program>.padded_rows``       — cumulative padding waste
   * ``device.<plane>.{span,stage,wait,glue}_us`` — per plane span (below)
+  * ``device.<plane>.glue.<part>_us``      — the glue by part (below)
 
 The outermost `plane(...)` block on a thread is a **plane span** (one
 batched verify / sign / prove call). Its time outside any frame is host
@@ -27,11 +28,21 @@ glue — Fiat-Shamir hashing, limb encode/decode, numpy reshapes — so per
 plane `span_s = stage_s + wait_s + glue_s` exactly (`plane_snapshot()`).
 A frame that no plane span encloses is its own span with no glue.
 
-Frames, tiles, read-backs and plane spans are also on the profiler's
-clock: while a `jax.profiler` session runs they show in the host plane
-of the trace as `fts:<plane>`, `fts:<plane>:<program>` (one tile's
-enqueue) and `fts:wait:<plane>:<program>` (one read-back), next to the
-device's `XLA Ops` (`annotate()` marks the host layers the same way).
+The glue has names: inside a plane span `glue(part)` bills the host
+time of its block to one of `GLUE_PARTS` (`parse`, `hostec`, `encode`,
+`decode`, `challenge`), less the frames and the nested `glue` blocks it
+encloses. What no block claimed is `other`, computed at the close of
+the span and never timed, so per plane `glue_us = parse_us + hostec_us +
+encode_us + decode_us + challenge_us + other_us` exactly.
+
+Frames, tiles, read-backs, glue blocks and plane spans are also on the
+profiler's clock: while a `jax.profiler` session runs they show in the
+host plane of the trace as `fts:<plane>`, `fts:<plane>:<program>` (one
+tile's enqueue), `fts:wait:<plane>:<program>` (one read-back) and
+`fts:<plane>:glue:<part>`, next to the device's `XLA Ops` (`annotate()`
+marks the host layers the same way). Every instant of a plane span is
+under exactly one of the last three, or under the bare `fts:<plane>`
+(= `other`).
 With no session a `TraceAnnotation` is one atomic check; `jax` is never
 imported from here (no `jax` in the process, no session to write to).
 
@@ -39,9 +50,6 @@ Frames are thread-local, so the `jax.monitoring` compile/cache
 listeners (ops/__init__) can attribute backend compile wall time and
 persistent-cache hits to the program that triggered them — the join
 between XLA's anonymous compile events and `stages.stage_programs()`.
-Degrade decisions land in the same per-program ledger via
-`note_degrade`, so "this program ran slow because it ran on the host"
-is visible next to its occupancy.
 
 Contract (mirrors utils/profiler.py): **zero cost when off**. The
 ledger is on by default (it is pure dict arithmetic on the dispatch
@@ -59,7 +67,7 @@ import os
 import sys
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 from . import metrics as mx
 
@@ -67,12 +75,13 @@ __all__ = [
     "enabled",
     "dispatch",
     "plane",
+    "glue",
+    "GLUE_PARTS",
     "annotate",
     "attribute",
     "current_program",
     "note_compile",
     "note_cache",
-    "note_degrade",
     "snapshot",
     "plane_snapshot",
     "reset",
@@ -146,7 +155,6 @@ def _entry(frame: Tuple[str, str]) -> dict:
             "compile_s": 0.0,
             "cache_hits": 0,
             "cache_misses": 0,
-            "degrades": {},
         }
     return e
 
@@ -155,33 +163,132 @@ def current_plane() -> str:
     return getattr(_tl, "plane", None) or DEFAULT_PLANE
 
 
+# what the host does between a plane's dispatches, by cure: deserialise
+# proofs and signatures / curve arithmetic on host integers / limb
+# encoding and row assembly / limb decoding after a read-back /
+# Fiat-Shamir. Closed, like `profiler.LEGS`; the rest of the glue is
+# `other`
+GLUE_PARTS = ("parse", "hostec", "encode", "decode", "challenge")
+_GLUE_INDEX = {part: i for i, part in enumerate(GLUE_PARTS)}
+# plane -> the parts' `fts:<plane>:glue:<part>` names, formatted once
+_glue_names: Dict[str, Tuple[str, ...]] = {}
+
+
+class _Span:
+    """The open plane span of a thread: what its frames and its `glue`
+    blocks have added up so far."""
+
+    __slots__ = ("frames_s", "wait_s", "parts", "glue", "names")
+
+    def __init__(self, pl: str):
+        self.frames_s = 0.0  # the enclosed frames' wall_s
+        self.wait_s = 0.0  # of that, their wait_s
+        self.parts = [0.0] * len(GLUE_PARTS)  # exclusive seconds a part
+        self.glue: Optional["_Glue"] = None  # the innermost open block
+        names = _glue_names.get(pl)
+        if names is None:
+            names = _glue_names[pl] = tuple(
+                f"fts:{pl}:glue:{part}" for part in GLUE_PARTS
+            )
+        self.names = names
+
+
+class _Glue:
+    """One `glue(part)` block of an open plane span: its wall time less
+    the frames closed inside it and less the blocks nested in it goes to
+    the part; marked `fts:<plane>:glue:<part>` on the profiler's clock."""
+
+    __slots__ = ("_span", "_idx", "_ann", "_outer", "_t0", "_frames0",
+                 "_inner_s")
+
+    def __init__(self, span: _Span, idx: int):
+        self._span = span
+        self._idx = idx
+        self._ann = _annotation(span.names[idx])
+
+    def __enter__(self):
+        span = self._span
+        self._outer = span.glue
+        span.glue = self
+        self._inner_s = 0.0
+        self._frames0 = span.frames_s
+        self._t0 = time.monotonic()
+        self._ann.__enter__()
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        span = self._span
+        # this block's host time: the frames inside it are stage / wait
+        host_s = (
+            time.monotonic() - self._t0 - (span.frames_s - self._frames0)
+        )
+        span.parts[self._idx] += host_s - self._inner_s
+        outer = span.glue = self._outer
+        if outer is not None:
+            outer._inner_s += host_s
+
+
+def glue(part: str):
+    """`with devobs.glue("encode"):` inside a plane span bills the host
+    time of the block to `part` (one of `GLUE_PARTS`; anything else
+    raises) — exclusively: a dispatch frame the block encloses stays
+    `stage_us` / `wait_us`, and a nested `glue` block is billed to its
+    own part. Enter one per batch, not per row: an entry is two clock
+    reads and a thread-local update, the counters move at the close of
+    the plane span. Passthrough outside a plane span, inside an open
+    frame (that time is the frame's), and so with the ledger off."""
+    idx = _GLUE_INDEX.get(part)
+    if idx is None:
+        raise ValueError(
+            f"unknown glue part {part!r}; one of {', '.join(GLUE_PARTS)}"
+        )
+    span = getattr(_tl, "span", None)
+    if span is None or getattr(_tl, "frame", None) is not None:
+        return _NULL
+    return _Glue(span, idx)
+
+
 def _close_plane_span(
-    pl: str, span_s: float, frames_s: float, wait_s: float
+    pl: str, span_s: float, frames_s: float, wait_s: float,
+    parts: Sequence[float] = (0.0,) * len(GLUE_PARTS),
 ) -> None:
     """One plane span into the per-plane aggregate and the always-on
-    microsecond counters (`span_us = stage_us + wait_us + glue_us`
-    exactly: the three parts are rounded, the total is their sum)."""
+    microsecond counters. `span_us = stage_us + wait_us + glue_us` and
+    `glue_us = the five parts + other_us`, both exactly: the addends are
+    rounded, each total is their sum, and `other` is what is left."""
     stage_s = frames_s - wait_s
     glue_s = max(0.0, span_s - frames_s)
+    stage_us = round(stage_s * 1e6)
+    wait_us = round(wait_s * 1e6)
+    glue_us = round(glue_s * 1e6)
+    parts_us = [max(0, round(v * 1e6)) for v in parts]
+    over = sum(parts_us) - glue_us
+    if over > 0:
+        # the parts cover the whole glue and their rounding went up: the
+        # microseconds come off the largest
+        parts_us[parts_us.index(max(parts_us))] -= over
+    by_part = dict(zip(GLUE_PARTS, parts_us), other=glue_us - sum(parts_us))
     with _lock:
         p = _planes.get(pl)
         if p is None:
             p = _planes[pl] = {
                 "calls": 0, "span_s": 0.0, "stage_s": 0.0,
                 "wait_s": 0.0, "glue_s": 0.0,
+                "glue_parts": dict.fromkeys(by_part, 0.0),
             }
         p["calls"] += 1
         p["span_s"] += stage_s + wait_s + glue_s
         p["stage_s"] += stage_s
         p["wait_s"] += wait_s
         p["glue_s"] += glue_s
-    stage_us = round(stage_s * 1e6)
-    wait_us = round(wait_s * 1e6)
-    glue_us = round(glue_s * 1e6)
+        for part, us in by_part.items():
+            p["glue_parts"][part] += us / 1e6
     mx.counter(f"device.{pl}.span_us").inc(stage_us + wait_us + glue_us)
     mx.counter(f"device.{pl}.stage_us").inc(stage_us)
     mx.counter(f"device.{pl}.wait_us").inc(wait_us)
     mx.counter(f"device.{pl}.glue_us").inc(glue_us)
+    for part, us in by_part.items():
+        mx.counter(f"device.{pl}.glue.{part}_us").inc(us)
 
 
 @contextlib.contextmanager
@@ -189,10 +296,10 @@ def plane(name: str):
     """Tag dispatches in this block with a logical plane (verify, sign,
     prove, ...). The OUTERMOST such block on a thread is the plane span:
     its wall time splits into the frames it encloses (`stage_s` +
-    `wait_s`) and the rest, host glue (`glue_s`); a nested block (the
-    transfer verifier calling the wf / membership / PS verifiers) only
-    re-tags and is never counted twice. Passthrough when the ledger is
-    off."""
+    `wait_s`) and the rest, host glue (`glue_s`, by part where `glue`
+    blocks name it); a nested block (the transfer verifier calling the
+    wf / membership / PS verifiers) only re-tags and is never counted
+    twice. Passthrough when the ledger is off."""
     if not enabled():
         yield
         return
@@ -204,8 +311,7 @@ def plane(name: str):
         finally:
             _tl.plane = prev
         return
-    # [sum of the enclosed frames' wall_s, of their wait_s]
-    acc = _tl.span = [0.0, 0.0]
+    span = _tl.span = _Span(name)
     t0 = time.monotonic()
     try:
         with _annotation("fts:" + name):
@@ -213,7 +319,10 @@ def plane(name: str):
     finally:
         _tl.plane = prev
         _tl.span = None
-        _close_plane_span(name, time.monotonic() - t0, acc[0], acc[1])
+        _close_plane_span(
+            name, time.monotonic() - t0, span.frames_s, span.wait_s,
+            span.parts,
+        )
 
 
 @contextlib.contextmanager
@@ -356,8 +465,8 @@ def dispatch(
             e["wait_s"] += wait
         span = getattr(_tl, "span", None)
         if span is not None:
-            span[0] += wall
-            span[1] += wait
+            span.frames_s += wall
+            span.wait_s += wait
         else:
             # no plane span around it: the frame is its own, glue-free
             _close_plane_span(pl, wall, wall, wait)
@@ -417,42 +526,25 @@ def note_cache(event: str) -> None:
         _entry(frame)[key] += 1
 
 
-def note_degrade(
-    reason: str,
-    program: Optional[str] = None,
-    plane: Optional[str] = None,
-) -> None:
-    """Record a degrade decision against the active — or explicitly
-    named — program."""
-    if not enabled():
-        return
-    if program is not None:
-        frame = (plane or current_plane(), program)
-    else:
-        frame = _active_frame()
-    with _lock:
-        degrades = _entry(frame)["degrades"]
-        degrades[reason] = degrades.get(reason, 0) + 1
-
-
 def snapshot() -> Dict[Tuple[str, str], dict]:
     """Raw per-(plane, program) aggregates — for window diffing in
     tests and bench; values are copies."""
     with _lock:
-        return {
-            frame: dict(e, degrades=dict(e["degrades"]))
-            for frame, e in _programs.items()
-        }
+        return {frame: dict(e) for frame, e in _programs.items()}
 
 
 def plane_snapshot() -> Dict[str, dict]:
     """Raw per-plane aggregates of the plane spans: `calls`, `span_s`,
     `stage_s`, `wait_s`, `glue_s` with `span_s = stage_s + wait_s +
-    glue_s` — for window diffing (the orderer takes a block's share so);
-    values are copies. Kept apart from `snapshot()`, whose entries are
-    programs."""
+    glue_s`, and `glue_parts`, the glue by part and `other` (the
+    counters' split in seconds: whole microseconds a span) — for window
+    diffing (the orderer takes a block's share so); values are copies.
+    Kept apart from `snapshot()`, whose entries are programs."""
     with _lock:
-        return {pl: dict(p) for pl, p in _planes.items()}
+        return {
+            pl: dict(p, glue_parts=dict(p["glue_parts"]))
+            for pl, p in _planes.items()
+        }
 
 
 def reset() -> None:
@@ -504,8 +596,6 @@ def health_section() -> dict:
             "compile_s": round(e["compile_s"], 3),
             "cache_hits": e["cache_hits"],
             "cache_misses": e["cache_misses"],
-            "degrades": sum(e["degrades"].values()),
-            "degrade_reasons": dict(e["degrades"]),
         }
         agg = planes.setdefault(
             pl, {"dispatches": 0, "rows": 0, "padded_rows": 0}
@@ -521,6 +611,9 @@ def health_section() -> dict:
             agg["calls"] = sp["calls"]
             for k in ("span_s", "stage_s", "wait_s", "glue_s"):
                 agg[k] = round(sp[k], 6)
+            agg["glue_parts"] = {
+                part: round(v, 6) for part, v in sp["glue_parts"].items()
+            }
     return {"enabled": enabled(), "planes": planes, "programs": programs}
 
 
@@ -554,7 +647,6 @@ def section() -> dict:
         "cache_misses": sum(
             e["cache_misses"] for e in h["programs"].values()
         ),
-        "degrades": sum(e["degrades"] for e in h["programs"].values()),
         "planes": h["planes"],
         "programs": h["programs"],
     }

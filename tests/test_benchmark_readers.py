@@ -244,6 +244,74 @@ def test_every_reader_file_names_a_reader_and_the_kernel_pair_reads_the_device()
         assert "verify:fexp_tile" in json.dumps(spec)  # the height is the ledger's
 
 
+# ------------------------------------- the readers of the glue by part (PR 40)
+
+_GLUE_SHARES = (
+    [f"verify.glue_{part}_share"
+     for part in ("parse", "hostec", "encode", "decode", "challenge")]
+    + [f"sign.glue_{part}_share"
+       for part in ("parse", "encode", "decode", "challenge")])
+
+
+def _emitted_counters(plane):
+    """The counters one plane span of the program leaves behind."""
+    from fabric_token_sdk_tpu.utils import devobs, metrics as mx
+
+    with devobs.plane(plane):
+        with devobs.glue("encode"):
+            pass
+    return set(mx.REGISTRY.snapshot()["counters"])
+
+
+@pytest.mark.parametrize("name", _GLUE_SHARES + ["pipeline.overlap_share"])
+def test_the_readers_of_pr_40_name_what_the_program_emits(name):
+    """Each new reader file loads, names a registered reader and only
+    counters or `block.commit` fields the program emits; a part's share is
+    listed exactly where its plane's `host_glue_share` is and over the same
+    denominator, so the parts add up to it less `other`."""
+    m = mf.load()
+    assert mf.validate(m) == []
+    by_name = {x["name"]: x for x in m["per_layer"]}
+    entry = by_name[name]
+    spec = mf._load(mf.data_file("layer_metrics", name))
+    assert spec["reader"] in readers.READERS
+    assert (entry["unit"], entry["moves"]) == ("%", "committed_tps")
+    if name == "pipeline.overlap_share":
+        assert spec == {"reader": "blocks_ratio", "num": "overlap_s",
+                        "den": "device_verify_s", "scale": 100.0}
+        assert (entry["source"], entry["better"]) == ("program_span", "higher")
+        assert entry["workloads"] == ["zk22.backlog"]
+        ledger = os.path.join(os.path.dirname(os.path.dirname(HERE)),
+                              "fabric_token_sdk_tpu", "services", "network",
+                              "ledger.py")
+        with open(ledger) as fh:
+            text = fh.read()
+        assert '"device_verify_s":' in text and 'breakdown["overlap_s"]' in text
+        blocks = [{"txs": ["a"], "device_verify_s": 2.0, "overlap_s": 0.5},
+                  {"txs": ["b"], "device_verify_s": 2.0}]  # the first block
+        assert readers.read(sources(blocks=blocks), spec) == pytest.approx(12.5)
+        return
+    plane, part = name.split(".")[0], name.split("_")[1]
+    whole = by_name[f"{plane}.host_glue_share"]
+    for key in ("unit", "better", "source", "layer", "moves", "workloads"):
+        assert entry[key] == whole[key], key
+    assert entry["workloads"][-1] == "b300e5.wallets"
+    whole_spec = mf._load(mf.data_file("layer_metrics", whole["name"]))
+    assert spec == dict(whole_spec, num=[f"device.{plane}.glue.{part}_us"])
+    assert set(spec["num"] + spec["den"]) <= _emitted_counters(plane)
+    counters = {f"device.{plane}.span_us": 2_000_000,
+                f"device.{plane}.glue_us": 400_000,
+                f"device.{plane}.glue.{part}_us": 100_000}
+    src = sources(counters=counters)
+    assert readers.read(src, spec) == pytest.approx(5.0)
+    assert readers.read(src, whole_spec) == pytest.approx(20.0)
+    # a program without the part's counter (the parent of PR 40) whose plane
+    # ran reads 0, and nothing where the plane did not run: never a raise
+    del counters[f"device.{plane}.glue.{part}_us"]
+    assert readers.read(sources(counters=counters), spec) == 0.0
+    assert readers.read(sources(), spec) is None
+
+
 # ------------------------------------------------------------------ trace
 
 

@@ -52,12 +52,12 @@ def test_off_is_passthrough(monkeypatch):
     agg_before = _hist_count("device.dispatch.seconds")
     threads_before = threading.active_count()
     with devobs.plane("verify"):
+        assert devobs.glue("parse") is devobs._NULL
         with devobs.attribute("offtest_attr"):
             with devobs.dispatch("offtest_prog", rows=5, padded_rows=3):
                 pass
     devobs.note_compile(1.0)
     devobs.note_cache("/jax/compilation_cache/cache_hits")
-    devobs.note_degrade("offtest_reason")
     # no ledger state, no per-program registry metrics, no threads
     assert devobs.snapshot() == {}
     assert devobs.current_program() is None
@@ -139,16 +139,6 @@ def test_compile_and_cache_attribution():
         devobs.note_compile(0.2)
     e = devobs.snapshot()[(devobs.DEFAULT_PLANE, "warm_prog")]
     assert (e["dispatches"], e["compiles"]) == (0, 1)
-
-
-def test_note_degrade_lands_on_named_program():
-    devobs.note_degrade("k_not_divisible", program="fused_pairing")
-    devobs.note_degrade("k_not_divisible", program="fused_pairing")
-    e = devobs.snapshot()[(devobs.DEFAULT_PLANE, "fused_pairing")]
-    assert e["degrades"] == {"k_not_divisible": 2}
-    prog = devobs.health_section()["programs"]["stages:fused_pairing"]
-    assert prog["degrades"] == 2
-    assert prog["degrade_reasons"] == {"k_not_divisible": 2}
 
 
 # ===================================================================
@@ -267,6 +257,22 @@ def _plane_counters(pl):
                        for k in ("span", "stage", "wait", "glue")))
 
 
+_GLUE_KEYS = devobs.GLUE_PARTS + ("other",)
+
+
+def _glue_counters(pl):
+    """A plane's span_us, its three addends, and glue_us' six, by short
+    name."""
+    names = {**{k: f"device.{pl}.{k}_us"
+                for k in ("span", "stage", "wait", "glue")},
+             **{k: f"device.{pl}.glue.{k}_us" for k in _GLUE_KEYS}}
+    return {k: mx.REGISTRY.counter(n).value for k, n in names.items()}
+
+
+def _moved(before, after):
+    return {k: after[k] - before[k] for k in after}
+
+
 def test_frame_wall_includes_the_read_back():
     """The regression test of the enqueue-time bug: `run_rows` used to
     close its frame once the tiles were ENQUEUED; the blocking read-back
@@ -363,6 +369,209 @@ def test_frame_records_itself_as_the_span():
         assert _hist_count("device.dispatch.seconds") == agg + 1
     finally:
         mx.enable(was)
+
+
+# ===================================================================
+# the glue by part: exclusive, closed vocabulary, summing to the glue
+# ===================================================================
+
+
+class _Clock:
+    """`devobs`'s clock in the test's hands: it counts its reads, and
+    time passes only where the test says so."""
+
+    def __init__(self, monkeypatch):
+        self.now, self.reads = 100.0, 0
+        monkeypatch.setattr(devobs, "time", self)
+
+    def monotonic(self):
+        self.reads += 1
+        return self.now
+
+    def passes(self, seconds):
+        self.now += seconds
+
+    def frame(self, seconds):
+        """A dispatch frame that waits `seconds` for its read-back."""
+        with devobs.dispatch("clock_prog", rows=1) as frame:
+            with frame.wait():
+                self.passes(seconds)
+
+
+def test_glue_is_exclusive_of_frames_and_nested_parts(monkeypatch):
+    """A part is billed its own host time: not the frame it encloses
+    (that is stage / wait), not the part nested in it; what no block
+    claimed is `other`; the seven counters sum to the integer."""
+    clock = _Clock(monkeypatch)
+    before = _glue_counters("verify")
+    with devobs.plane("verify"):
+        with devobs.glue("encode"):
+            clock.passes(0.02)
+            clock.frame(0.05)  # a frame inside a part
+            with devobs.glue("hostec"):  # a part inside a part
+                clock.passes(0.04)
+                clock.frame(0.05)
+        with devobs.glue("encode"):  # entered twice: added up
+            clock.passes(0.01)
+        clock.passes(0.03)  # claimed by no block
+    d = _moved(before, _glue_counters("verify"))
+    assert d == {"span": 200_000, "stage": 0, "wait": 100_000,
+                 "glue": 100_000, "parse": 0, "hostec": 40_000,
+                 "encode": 30_000, "decode": 0, "challenge": 0,
+                 "other": 30_000}
+    # the ledger and the operator's view say the same in seconds
+    p = devobs.plane_snapshot()["verify"]
+    assert p["span_s"] == pytest.approx(
+        p["stage_s"] + p["wait_s"] + p["glue_s"], abs=1e-9)
+    assert p["glue_parts"] == {k: d[k] / 1e6 for k in _GLUE_KEYS}
+    assert sum(p["glue_parts"].values()) == pytest.approx(p["glue_s"])
+    hp = devobs.health_section()["planes"]["verify"]
+    assert hp["glue_parts"] == pytest.approx(p["glue_parts"])
+    assert benchschema.validate_device(devobs.section()) == []
+
+
+def test_glue_takes_five_names_and_needs_a_plane_span(monkeypatch):
+    for part in ("other", "hash", "", "Parse"):
+        with pytest.raises(ValueError, match="unknown glue part"):
+            devobs.glue(part)
+    clock = _Clock(monkeypatch)
+    before = _glue_counters("stages")
+    # no plane span open: a passthrough, as with the ledger off
+    assert all(devobs.glue(part) is devobs._NULL
+               for part in devobs.GLUE_PARTS)
+    with devobs.glue("parse"):
+        pass
+    assert clock.reads == 0
+    # a frame is no plane span, and inside an open frame the time is the
+    # frame's: a block there is billed to no part
+    with devobs.plane("stages"):
+        with devobs.dispatch("glue_prog", rows=1):
+            assert devobs.glue("decode") is devobs._NULL
+    with devobs.dispatch("glue_prog", rows=1):
+        assert devobs.glue("decode") is devobs._NULL
+    d = _moved(before, _glue_counters("stages"))
+    assert d["glue"] == d["other"]
+    assert all(d[k] == 0 for k in devobs.GLUE_PARTS)
+
+
+def _mixed_transfer_block():
+    """A real `BatchedTransferVerifier.verify` of eight rows of six
+    shapes with four seeded faults (`tests/test_mixed_shapes.py`), four
+    times over: the stand-ins compute a row they have seen once, the
+    glue works on all 32, and beside it the fixed cost of some twenty
+    dispatches' bookkeeping (`other`) is small, as it is served."""
+    import test_mixed_shapes as mixed
+    from fabric_token_sdk_tpu.crypto import batch
+
+    pp = mixed._pp()
+    _shapes, rows, places = mixed.seeded_block(pp, 7, "shuffled")
+    want = [i not in places for i in range(len(rows))] * 4
+    rows = rows * 4
+    return (lambda: batch.BatchedTransferVerifier(pp).verify(rows).tolist(),
+            want)
+
+
+def _schnorr_block():
+    from fabric_token_sdk_tpu.crypto import sign
+    from fabric_token_sdk_tpu.crypto.batch_sign import BatchedSchnorrVerifier
+
+    rng = random.Random(0x5167)
+    # a block's worth of rows: beside the rows' work the fixed cost of
+    # three dispatches' bookkeeping (`other`) is small, as it is served
+    keys = [sign.keygen(rng) for _ in range(8)]
+    rows = [(k.public.point, b"pay %d" % i, k.sign(b"pay %d" % i, rng))
+            for i, k in enumerate(keys * 8)]
+    rows[3] = (rows[3][0], b"pay another", rows[3][2])
+    rows[5] = (rows[5][0], rows[5][1], b"\x00not a signature")
+    want = [True] * 64
+    want[3], want[5] = False, None
+    return lambda: BatchedSchnorrVerifier().verify(rows), want
+
+
+_PLANE_BLOCKS = [
+    ("verify", _mixed_transfer_block, devobs.GLUE_PARTS),
+    ("sign", _schnorr_block, ("parse", "encode", "decode", "challenge")),
+]
+
+
+@pytest.mark.parametrize("plane,block_of,parts", _PLANE_BLOCKS,
+                         ids=["verify", "sign"])
+def test_a_real_call_bills_its_glue_to_the_parts(monkeypatch, plane,
+                                                 block_of, parts):
+    """After a real verifier call over exact host stand-ins for the
+    kernels: `glue_us` = the five parts + `other_us` to the integer,
+    every part the plane has is non-zero, and the named parts are nine
+    tenths of the glue or more."""
+    import hostplane
+
+    hostplane.install(monkeypatch)
+    run, want = block_of()
+    before = _glue_counters(plane)
+    assert run() == want
+    d = _moved(before, _glue_counters(plane))
+    assert d["span"] == d["stage"] + d["wait"] + d["glue"]
+    assert d["glue"] == sum(d[k] for k in _GLUE_KEYS) > 0
+    assert all(d[k] > 0 for k in parts), d
+    assert all(d[k] == 0 for k in devobs.GLUE_PARTS if k not in parts), d
+    assert d["other"] >= 0
+    assert sum(d[k] for k in parts) >= 0.9 * d["glue"], d
+    assert set(devobs.plane_snapshot()) == {plane}
+    p = devobs.plane_snapshot()[plane]
+    assert p["calls"] == 1
+    assert p["glue_parts"] == pytest.approx({k: d[k] / 1e6 for k in _GLUE_KEYS})
+
+
+@pytest.mark.parametrize("plane,block_of,_parts", _PLANE_BLOCKS,
+                         ids=["verify", "sign"])
+def test_ledger_never_perturbs_a_planes_verdicts(monkeypatch, plane,
+                                                 block_of, _parts):
+    """The verifiers whose glue is billed by part, ledger on and off:
+    the same verdicts, and off no part's counter moves."""
+    import hostplane
+
+    hostplane.install(monkeypatch)
+    run, want = block_of()
+    monkeypatch.setenv("FTS_DEVOBS", "1")
+    assert run() == want
+    assert set(devobs.plane_snapshot()) == {plane}
+    monkeypatch.setenv("FTS_DEVOBS", "0")
+    devobs.reset()
+    before = _glue_counters(plane)
+    assert run() == want
+    assert _glue_counters(plane) == before
+    assert devobs.plane_snapshot() == {} and devobs.snapshot() == {}
+
+
+@pytest.mark.parametrize("n_txs", [2, 64])
+def test_glue_is_entered_per_batch_not_per_row(monkeypatch, n_txs):
+    """The same fifteen `glue` blocks a transfer verify (the budget is
+    40), at 2 transactions as at 64 (the stand-ins compute a row they
+    have seen once, so 64 copies of one two-output transfer cost one)."""
+    import collections
+
+    import hostplane
+    import test_mixed_shapes as mixed
+    from fabric_token_sdk_tpu.crypto import batch
+
+    hostplane.install(monkeypatch)
+    pp = mixed._pp()
+    row = mixed.make_row(pp, (2, 2), random.Random("entries"))
+    entered = collections.Counter()
+    inner = devobs.glue
+
+    def counted(part):
+        entered[part] += 1
+        return inner(part)
+
+    monkeypatch.setattr(devobs, "glue", counted)
+    ok = batch.BatchedTransferVerifier(pp).verify([row] * n_txs)
+    assert ok.all() and len(ok) == n_txs
+    assert sum(entered.values()) <= 40
+    # transfer + wf + range proofs; wf + equality rows; wf + membership
+    # (rows, pairing legs) + equality; and a read-back and a challenge
+    # loop each in wf, membership and equality
+    assert entered == {"parse": 3, "hostec": 2, "encode": 4, "decode": 3,
+                       "challenge": 3}
 
 
 # ===================================================================
@@ -595,8 +804,9 @@ def _fts_events(trace_dir):
 def test_frames_are_on_the_profilers_clock(tmp_path, monkeypatch):
     """Under a `jax.profiler` session one `run_rows` call leaves a
     `fts:<plane>:<program>` event per tile and a `fts:wait:...` event
-    per read-back in the host plane, and `annotate` its `fts:<name>`;
-    outside a session, and with the ledger off, nothing is recorded."""
+    per read-back in the host plane, a `glue` block its
+    `fts:<plane>:glue:<part>` and `annotate` its `fts:<name>`; outside
+    a session, and with the ledger off, nothing is recorded."""
     import jax
 
     from fabric_token_sdk_tpu.crypto.batch import _spanned
@@ -608,7 +818,13 @@ def test_frames_are_on_the_profilers_clock(tmp_path, monkeypatch):
     def verify():
         with devobs.annotate("validate"):
             pass
+        with devobs.glue("encode"):
+            time.sleep(0.001)
+            with devobs.glue("hostec"):
+                time.sleep(0.001)
         st.run_rows(_slow_tile(0.001), rows)
+        with devobs.glue("decode"):
+            time.sleep(0.001)
 
     jax.profiler.start_trace(str(tmp_path / "on"))
     try:
@@ -631,6 +847,20 @@ def test_frames_are_on_the_profilers_clock(tmp_path, monkeypatch):
     for n, s, d in events:
         if n.endswith("verify:slow_tile"):
             assert span[1] <= s and s + d <= span[1] + span[2]
+    # and its glue blocks, one event each, which overlap no tile and no
+    # read-back: an instant of the span has one name
+    glue = [e for e in events if e[0].startswith("fts:verify:glue:")]
+    assert sorted(n for n, _s, _d in glue) == [
+        f"fts:verify:glue:{part}" for part in ("decode", "encode", "hostec")]
+    frames = [e for e in events if e[0].endswith("verify:slow_tile")]
+    for _n, s, d in glue:
+        assert d >= 1e6
+        assert span[1] <= s and s + d <= span[1] + span[2]
+        assert all(s + d <= fs or fs + fd <= s for _fn, fs, fd in frames)
+    # the nested block lies inside the one that encloses it
+    by = {n.rsplit(":", 1)[1]: (s, s + d) for n, s, d in glue}
+    assert by["encode"][0] <= by["hostec"][0] <= by["hostec"][1] <= by["encode"][1]
+    assert by["encode"][1] <= by["decode"][0]
 
     monkeypatch.setenv("FTS_DEVOBS", "0")
     jax.profiler.start_trace(str(tmp_path / "off"))
@@ -645,11 +875,17 @@ def test_off_moves_no_plane_counter_and_no_timing(monkeypatch):
     monkeypatch.setenv("FTS_DEVOBS", "0")
     names = [f"device.{pl}.{k}_us" for pl in ("stages", "verify")
              for k in ("span", "stage", "wait", "glue")]
+    names += [f"device.verify.glue.{k}_us" for k in _GLUE_KEYS]
     before = _counters(*names)
     agg = _hist_count("device.dispatch.seconds")
+    clock = _Clock(monkeypatch)
     with devobs.plane("verify"):
-        out = st.run_rows(_slow_tile(0.0), np.ones((3, 1), dtype=np.int32))
-    assert (out == 2).all()
+        with devobs.glue("encode"):
+            rows = np.ones((3, 1), dtype=np.int32)
+        out = st.run_rows(_slow_tile(0.0), rows)
+        with devobs.glue("decode"):
+            assert (out == 2).all()
+    assert clock.reads == 0
     with devobs.dispatch("offtest_prog", rows=1) as frame:
         with frame.tile(), frame.wait():
             pass
