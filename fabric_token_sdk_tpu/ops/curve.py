@@ -19,6 +19,7 @@ from jax import lax
 from . import limbs as lb
 from .field import FP, FR
 from ..crypto import hostmath as hm
+from ..utils import metrics as mx
 
 
 def infinity(shape=()) -> jnp.ndarray:
@@ -217,29 +218,58 @@ def encode_points(pts) -> jnp.ndarray:
     return jnp.asarray(np.stack([encode_point(p) for p in pts]))
 
 
-_RINV = None  # lazily: R^-1 mod p for host Montgomery decode
+_R = (1 << (lb.RADIX_BITS * lb.NLIMBS)) % hm.P  # Montgomery radix mod p
+
+
+def batch_fp_inv(zs) -> list:
+    """Host: inverses mod p of a list of residues, by Montgomery's trick:
+    prefix products, ONE modular inversion, a walk back. A zero stays out
+    of the product and comes back as 0.
+
+    Counts what the read-back decode does: ``batch.decode.points`` by
+    ``len(zs)``, ``batch.decode.inversions`` by the inversions done (1, or
+    0 where every entry is zero)."""
+    P = hm.P
+    prefix = []
+    acc = 1
+    for z in zs:
+        prefix.append(acc)
+        if z:
+            acc = acc * z % P
+    mx.counter("batch.decode.points").inc(len(zs))
+    out = [0] * len(zs)
+    if not any(zs):
+        return out
+    mx.counter("batch.decode.inversions").inc()
+    inv = pow(acc, -1, P)
+    for i in range(len(zs) - 1, -1, -1):
+        if zs[i]:
+            out[i] = inv * prefix[i] % P
+            inv = inv * zs[i] % P
+    return out
 
 
 def decode_points(arr):
     """Device (..., 3, NLIMBS) -> host affine tuples.
 
-    Pure host arithmetic — Montgomery conversion is one modular multiply
-    by R^-1 per coordinate, inversion via Fermat on python ints — so
-    decoding compiles no device program (the batched verifiers' XLA
-    program set stays independent of batch/statement shape)."""
-    global _RINV
-    if _RINV is None:
-        _RINV = pow(1 << (lb.RADIX_BITS * lb.NLIMBS), -1, hm.P)
-    flat = np.asarray(arr).reshape(-1, 3, lb.NLIMBS)
+    Pure host arithmetic over the whole batch: the limbs come off as bytes
+    (``lb.batch_limbs_to_ints``), the z of every finite row is inverted in
+    one ``batch_fp_inv``, and the Montgomery factor rides the same pass
+    (with w = (zR)^-1: u = wR = z^-1, t = uw = R / (zR)^2, so x = xR * t
+    and y = yR * t * u). Decoding compiles no device program (the batched
+    verifiers' XLA program set stays independent of batch/statement
+    shape). A row whose z is 0 mod p is None."""
+    P = hm.P
+    vals = lb.batch_limbs_to_ints(np.asarray(arr).reshape(-1, 3, lb.NLIMBS))
+    ws = batch_fp_inv([z % P for z in vals[2::3]])
     out = []
-    for row in flat:
-        x, y, z = (lb.limbs_to_int(c) * _RINV % hm.P for c in row)
-        if z == 0:
+    for x, y, w in zip(vals[0::3], vals[1::3], ws):
+        if not w:
             out.append(None)
             continue
-        zinv = hm.fp_inv(z)
-        zi2 = zinv * zinv % hm.P
-        out.append((x * zi2 % hm.P, y * zi2 % hm.P * zinv % hm.P))
+        u = w * _R % P
+        t = u * w % P
+        out.append((x * t % P, y * t % P * u % P))
     return out
 
 

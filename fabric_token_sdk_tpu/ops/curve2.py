@@ -139,17 +139,20 @@ def encode_points(pts) -> np.ndarray:
 
 
 def decode_points(arr):
-    """Device (..., 3, 2, L) -> host affine fp2 pairs (inversion on host)."""
-    flat = np.asarray(arr).reshape(-1, 3, 2, lb.NLIMBS)
-    coords = tw.decode_fp2(flat.reshape(-1, 2, lb.NLIMBS))
+    """Device (..., 3, 2, L) -> host affine fp2 pairs.
+
+    The G1 decode's form over Fp2: z^-1 = conj(z) / norm(z), the norms of
+    the whole batch inverted in one ``cv.batch_fp_inv``."""
+    coords = tw.decode_fp2(arr)
+    zs = coords[2::3]
+    ninvs = cv.batch_fp_inv([(a * a + b * b) % hm.P for a, b in zs])
     out = []
-    for i in range(len(flat)):
-        x, y, z = coords[3 * i], coords[3 * i + 1], coords[3 * i + 2]
-        if z == (0, 0):
+    for x, y, z, n in zip(coords[0::3], coords[1::3], zs, ninvs):
+        if not n:
             out.append(None)
             continue
-        zinv = hm.fp2_inv(z)
-        zi2 = hm.fp2_mul(zinv, zinv)
+        zinv = hm.fp2_scale(hm.fp2_conj(z), n)
+        zi2 = hm.fp2_sqr(zinv)
         out.append(
             (hm.fp2_mul(x, zi2), hm.fp2_mul(hm.fp2_mul(y, zi2), zinv))
         )

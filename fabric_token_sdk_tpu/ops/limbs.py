@@ -55,9 +55,20 @@ def ints_to_limbs(xs, nlimbs: int = NLIMBS) -> np.ndarray:
 
 
 def batch_limbs_to_ints(arr) -> list:
+    """Host: (..., n) limb array (canonical or not) -> flat list of ints.
+
+    Canonical limbs (integers in [0, RADIX)) are the little-endian bytes
+    of the value: one ``int.from_bytes`` per element over the bytes of the
+    whole array. Any other array takes ``limbs_to_int`` row by row."""
     a = np.asarray(arr)
-    flat = a.reshape(-1, a.shape[-1])
-    return [limbs_to_int(row) for row in flat]
+    if a.size == 0:
+        return []
+    n = a.shape[-1]
+    flat = a.reshape(-1, n)
+    if a.dtype.kind not in "iu" or flat.min() < 0 or flat.max() > MASK:
+        return [limbs_to_int(row) for row in flat]
+    raw = flat.astype(np.uint8).tobytes()
+    return [int.from_bytes(raw[i : i + n], "little") for i in range(0, len(raw), n)]
 
 
 # ---------------------------------------------------------------- carries

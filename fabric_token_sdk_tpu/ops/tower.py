@@ -154,8 +154,8 @@ def decode_fp2(arr):
     R^-1): decoding compiles no device program, so batched verifiers stay
     shape-invariant in their XLA program set."""
     a = np.asarray(arr).reshape(-1, lb.NLIMBS)
-    flat = [lb.limbs_to_int(row) * _RINV % hm.P for row in a]
-    return [(flat[2 * i], flat[2 * i + 1]) for i in range(len(flat) // 2)]
+    flat = [v * _RINV % hm.P for v in lb.batch_limbs_to_ints(a)]
+    return list(zip(flat[0::2], flat[1::2]))
 
 
 def encode_fp12(vals) -> np.ndarray:
