@@ -138,10 +138,22 @@ class Driver(abc.ABC):
         None to route this action through the host path (default)."""
         return None
 
+    def issue_batch_plan(self, action_bytes: bytes):
+        """Optional hook for the block-batched validation plane: the row
+        of an issue action, which `batch_verifier().verify` takes beside
+        the block's transfer rows in the same call, or None to verify
+        this action's proof on the host (default). A driver that plans
+        issues accepts the verdict as `validate_issue(action_bytes,
+        proof_verified=...)` — True: skip the host proof check, False:
+        reject, None: verify on host — and still runs every
+        authorisation check itself; a driver that plans none is never
+        called with the argument."""
+        return None
+
     def batch_verifier(self):
-        """The driver's block-batched transfer-proof verifier (an object
-        with `verify(rows) -> bool array`), or None when the driver has
-        no batched plane (default)."""
+        """The driver's block-batched proof verifier (an object with
+        `verify(rows) -> bool array` over the rows its plan hooks emit),
+        or None when the driver has no batched plane (default)."""
         return None
 
     def batch_prover(self):

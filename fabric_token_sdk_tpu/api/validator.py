@@ -53,7 +53,8 @@ class RequestValidator:
                  now=None,
                  transfer_proofs: Optional[Dict[int, bool]] = None,
                  sig_verified: Optional[Dict[tuple, tuple]] = None,
-                 conservation: Optional[Dict[int, bool]] = None) -> ValidationResult:
+                 conservation: Optional[Dict[int, bool]] = None,
+                 issue_proofs: Optional[Dict[int, bool]] = None) -> ValidationResult:
         """`now`: deterministic commit timestamp for time-locked scripts.
 
         `transfer_proofs`: verdicts from the block-batched proof plane,
@@ -62,6 +63,11 @@ class RequestValidator:
         proof check), False means it was already REJECTED. Records with
         no verdict verify on host. Everything else (ledger-input
         matching, conservation) always runs here.
+
+        `issue_proofs`: the same plane's verdicts on the request's issue
+        records, keyed by issue-record index, with the same three
+        meanings. Who may issue is checked on the host for every issue,
+        verdict or none.
 
         `sig_verified`: verdicts from the block-batched SIGNATURE plane,
         `{obligation_key: (identity_bytes, bool)}` (see the module
@@ -82,11 +88,11 @@ class RequestValidator:
         with devobs.annotate("validate"):
             return self._validate(
                 request, resolve_input, now, transfer_proofs, sig_verified,
-                conservation,
+                conservation, issue_proofs,
             )
 
     def _validate(self, request, resolve_input, now, transfer_proofs,
-                  sig_verified, conservation) -> ValidationResult:
+                  sig_verified, conservation, issue_proofs) -> ValidationResult:
         result = ValidationResult()
         payload = request.marshal_to_sign()
         sv = sig_verified or {}
@@ -119,7 +125,14 @@ class RequestValidator:
         for ii, rec in enumerate(request.issues):
             # the driver returns the issuer identity the ACTION names (after
             # authorization checks); the record-level field is untrusted.
-            outputs, action_issuer = self.driver.validate_issue(rec.action)
+            proved = issue_proofs.get(ii) if issue_proofs else None
+            # (the kwarg is bound only where there is a verdict, and one
+            # exists only for a driver whose own `issue_batch_plan`
+            # emitted the row: the same SPI opt-in as `sig_verified`)
+            kwargs = {} if proved is None else {"proof_verified": proved}
+            outputs, action_issuer = self.driver.validate_issue(
+                rec.action, **kwargs
+            )
             if action_issuer:
                 if not rec.signature:
                     raise ValidationError("issue is missing the issuer signature")

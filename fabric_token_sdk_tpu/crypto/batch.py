@@ -5,11 +5,13 @@ The reference verifies each proof sequentially with goroutines
 transactions verify through a SMALL CONSTANT set of XLA programs:
 
 * `BatchedPSVerifier`      — Pointcheval-Sanders signature batches
-* `BatchedWFVerifier`      — transfer well-formedness sigma proofs
+* `BatchedWFVerifier`      — transfer and issue well-formedness sigma
+  proofs
 * `BatchedMembershipVerifier` — the pairing side of membership proofs,
   two pairings a proof (the scalar verifier's four legs, those that
   share an argument merged by bilinearity)
-* `BatchedTransferVerifier`— full transfer proofs (WF + range)
+* `BatchedTransferVerifier`— a block's full action proofs (WF + range),
+  transfers and issues in one call
 
 Execution model (staged tiles — see `ops/stages.py`): every verifier is a
 HOST-SIDE composition of primitive stage kernels (fixed-base multiexp,
@@ -20,10 +22,12 @@ repetition, broadcasting parameter points, Fiat-Shamir re-hashing — is
 host numpy, so the distinct-program count is independent of batch size,
 transfer shape `(n_in, n_out)`, and parameter set. `ops/warmup.py`
 precompiles the whole set. The transfer verifiers take rows of differing
-shapes in one call: every transaction's rows sit at a running offset of
-the flat rows a stage call is handed, so a block costs its fixed part
-(one padded dispatch per stage call, one Miller walk, one final
-exponentiation) once and not once a shape.
+shapes, and of either operation (a transfer is an `(inputs, outputs,
+proof)` tuple, an issue a `crypto/issue.py:IssueRow`), in one call: every
+action's rows sit at a running offset of the flat rows a stage call is
+handed, so a block costs its fixed part (one padded dispatch per stage
+call, one Miller walk, one final exponentiation) once, and not once a
+shape or once an operation.
 """
 
 from __future__ import annotations
@@ -34,10 +38,12 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import hostmath as hm, pssign, sigproof
-from .rangeproof import RangeProof
+from .issue import IssueProof, IssueRow
+from .rangeproof import RangeProof, RangeVerifier
 from .setup import PublicParams
 from .transfer import TransferProof, _skip_range
-from .wellformedness import TransferWF, challenge_transfer_wf
+from .wellformedness import IssueWF, TransferWF, challenge_issue_wf, \
+    challenge_transfer_wf
 from ..ops import curve as cv, curve2 as cv2, limbs as lb, pairing as pr, \
     stages as st, tower as tw
 from ..utils import devobs
@@ -142,87 +148,120 @@ class BatchedPSVerifier:
 
 
 class BatchedWFVerifier:
-    """Recomputes all Schnorr commitments of B transfer WF proofs, of any
-    mix of shapes, via the stage tiles, then re-derives challenges on
-    host."""
+    """Recomputes all Schnorr commitments of B well-formedness proofs, of
+    transfers of any mix of shapes and of issues, via the stage tiles,
+    then re-derives challenges on host."""
 
     def __init__(self, pp: PublicParams):
         self.pp = pp
         self.table = cv.FixedBaseTable(pp.ped_params)
 
+    @staticmethod
+    def _parse(tx):
+        """-> (the row's parsed proof, its type response) where it has
+        the responses its statement asks for, else None: the row
+        verifies False, as the scalar verifiers raise."""
+        try:
+            if isinstance(tx, IssueRow):
+                wf = IssueWF.from_bytes(tx.proof)
+                n_out = len(tx.outputs)
+                if not n_out or len(wf.values) != n_out or len(wf.bfs) != n_out:
+                    return None
+                if tx.anonymous:
+                    return None if wf.type_resp is None else (wf, wf.type_resp)
+                if not wf.type_clear:
+                    return None
+                # the type's randomness is zero: response = c * hash(type)
+                return wf, wf.challenge * hm.hash_to_zr(
+                    wf.type_clear.encode()) % hm.R
+            inputs, outputs, raw = tx
+            wf = TransferWF.from_bytes(raw)
+            n_in, n_out = len(inputs), len(outputs)
+            if (
+                len(wf.input_values) == n_in
+                and len(wf.input_bfs) == n_in
+                and len(wf.output_values) == n_out
+                and len(wf.output_bfs) == n_out
+            ):
+                return wf, wf.type_resp
+        except Exception:
+            pass  # malformed
+        return None
+
     @_spanned("batch.wf.verify")
-    def verify(self, txs: Sequence[Tuple[list, list, bytes]]) -> np.ndarray:
-        """txs: (inputs, outputs, wf_bytes); each transaction has its own
-        `(n_in, n_out)` and `n_in + n_out + 2` rows (+ the two aggregate
-        statements) at a running offset of the flat rows.
+    def verify(self, txs: Sequence) -> np.ndarray:
+        """txs: `(inputs, outputs, wf_bytes)` for a transfer, an
+        `IssueRow` (its proof the issue's wf bytes) for an issue. Each
+        transfer has its own `(n_in, n_out)` and `n_in + n_out + 2` rows
+        (the two aggregate statements among them), each issue one row an
+        output, at a running offset of the flat rows.
         Returns bool array (B,)."""
         B = len(txs)
         if B == 0:
             return np.zeros(0, dtype=bool)
         mx.counter("batch.wf.txs").inc(B)
-        ns = [len(t[0]) + len(t[1]) + 2 for t in txs]
-        # transaction i owns the flat rows at[i]:at[i+1]
+        issue = [isinstance(t, IssueRow) for t in txs]
+        ns = [
+            len(t.outputs) if issue[i] else len(t[0]) + len(t[1]) + 2
+            for i, t in enumerate(txs)
+        ]
+        # row i owns the flat rows at[i]:at[i+1]
         at = np.concatenate([[0], np.cumsum(ns)])
         N = int(at[-1])
-        ok_shape = np.ones(B, dtype=bool)
         with devobs.glue("parse"):
-            proofs: List[Optional[TransferWF]] = []
-            for i, (inputs, outputs, raw) in enumerate(txs):
-                try:
-                    wf = TransferWF.from_bytes(raw)
-                except Exception:
-                    wf = None  # malformed: row verifies False
-                proofs.append(wf)
-                n_in, n_out = len(inputs), len(outputs)
-                ok_shape[i] = (
-                    wf is not None
-                    and len(wf.input_values) == n_in
-                    and len(wf.input_bfs) == n_in
-                    and len(wf.output_values) == n_out
-                    and len(wf.output_bfs) == n_out
-                )
+            proofs = [self._parse(t) for t in txs]
         with devobs.glue("hostec"):
-            # the two aggregate statements of a transaction
+            # the two aggregate statements of a transfer
             sums = [
-                (hm.g1_sum(t[0]), hm.g1_sum(t[1])) if ok_shape[i] else None
+                (hm.g1_sum(t[0]), hm.g1_sum(t[1]))
+                if proofs[i] is not None and not issue[i] else None
                 for i, t in enumerate(txs)
             ]
         with devobs.glue("encode"):
             stmts: List = []
             resp = np.zeros((N, 3, lb.NLIMBS), dtype=np.int32)
             chals = np.zeros((B, lb.NLIMBS), dtype=np.int32)
-            for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
-                if not ok_shape[i]:
+            for i, (t, parsed) in enumerate(zip(txs, proofs)):
+                if parsed is None:
                     stmts.extend([None] * ns[i])
                     continue
-                n_in, n_out = len(inputs), len(outputs)
-                stmts.extend(inputs)
-                stmts.append(sums[i][0])
-                stmts.extend(outputs)
-                stmts.append(sums[i][1])
-                rows = []
-                for k in range(n_in):
-                    rows.append(
-                        [wf.type_resp, wf.input_values[k], wf.input_bfs[k]]
-                    )
-                rows.append(
-                    [
-                        wf.type_resp * n_in % hm.R,
-                        wf.sum_resp,
-                        sum(wf.input_bfs) % hm.R,
+                wf, type_resp = parsed
+                if issue[i]:
+                    stmts.extend(t.outputs)
+                    rows = [
+                        [type_resp, wf.values[k], wf.bfs[k]]
+                        for k in range(ns[i])
                     ]
-                )
-                for k in range(n_out):
+                else:
+                    inputs, outputs, _ = t
+                    n_in, n_out = len(inputs), len(outputs)
+                    stmts.extend(inputs)
+                    stmts.append(sums[i][0])
+                    stmts.extend(outputs)
+                    stmts.append(sums[i][1])
+                    rows = []
+                    for k in range(n_in):
+                        rows.append(
+                            [type_resp, wf.input_values[k], wf.input_bfs[k]]
+                        )
                     rows.append(
-                        [wf.type_resp, wf.output_values[k], wf.output_bfs[k]]
+                        [
+                            type_resp * n_in % hm.R,
+                            wf.sum_resp,
+                            sum(wf.input_bfs) % hm.R,
+                        ]
                     )
-                rows.append(
-                    [
-                        wf.type_resp * n_out % hm.R,
-                        wf.sum_resp,
-                        sum(wf.output_bfs) % hm.R,
-                    ]
-                )
+                    for k in range(n_out):
+                        rows.append(
+                            [type_resp, wf.output_values[k], wf.output_bfs[k]]
+                        )
+                    rows.append(
+                        [
+                            type_resp * n_out % hm.R,
+                            wf.sum_resp,
+                            sum(wf.output_bfs) % hm.R,
+                        ]
+                    )
                 for j, r in enumerate(rows):
                     resp[at[i] + j] = cv.encode_scalars(r)
                 chals[i] = cv.encode_scalars([wf.challenge])[0]
@@ -236,17 +275,21 @@ class BatchedWFVerifier:
             com_pts = cv.decode_points(coms)  # N host points
         out = np.zeros(B, dtype=bool)
         with devobs.glue("challenge"):
-            for i, ((inputs, outputs, _), wf) in enumerate(zip(txs, proofs)):
-                if not ok_shape[i]:
+            for i, (t, parsed) in enumerate(zip(txs, proofs)):
+                if parsed is None:
                     continue
                 row = com_pts[at[i] : at[i + 1]]
-                in_coms = row[: len(inputs) + 1]
-                out_coms = row[len(inputs) + 1 :]
-                chal = challenge_transfer_wf(
-                    in_coms[:-1], in_coms[-1], out_coms[:-1], out_coms[-1],
-                    inputs, outputs,
-                )
-                out[i] = chal == wf.challenge
+                if issue[i]:
+                    chal = challenge_issue_wf(row, t.outputs)
+                else:
+                    inputs, outputs, _ = t
+                    in_coms = row[: len(inputs) + 1]
+                    out_coms = row[len(inputs) + 1 :]
+                    chal = challenge_transfer_wf(
+                        in_coms[:-1], in_coms[-1], out_coms[:-1], out_coms[-1],
+                        inputs, outputs,
+                    )
+                out[i] = chal == parsed[0].challenge
         return out
 
 
@@ -355,7 +398,10 @@ class BatchedMembershipVerifier:
         out = np.zeros(B, dtype=bool)
         with devobs.glue("challenge"):
             for i, (p, com) in enumerate(zip(proofs, commitments)):
-                if p.commitment != com:
+                sig = p.signature
+                if p.commitment != com or sig.R is None or sig.S is None:
+                    # (a signature at infinity: the scalar verifier's
+                    # rejection, `sigproof.MembershipVerifier.verify`)
                     continue
                 mv = sigproof.MembershipVerifier(
                     com, self.P, self.Q, self.pk, self.ped2
@@ -371,17 +417,20 @@ class BatchedMembershipVerifier:
 
 
 class BatchedTransferVerifier:
-    """Verifies whole blocks of zkatdlog transfer proofs, of any mix of
-    shapes `(n_in, n_out)`, in one call.
+    """Verifies a whole block's zkatdlog action proofs in one call:
+    transfers of any mix of shapes `(n_in, n_out)` and issues.
 
-    Composition mirrors `transfer.TransferVerifier` but the group/pairing
-    work of ALL transactions runs through the fixed-shape stage tiles —
-    the total distinct-program count is constant in `(n_in, n_out)`,
-    batch size, and parameter set. Rows are flat: a transaction's
-    well-formedness rows, its membership rows (output x digit) and its
-    equality rows (one per output) sit at running offsets, so a call of
-    one shape dispatches exactly the rows a call of mixed shapes with as
-    many rows does.
+    Composition mirrors `transfer.TransferVerifier` and
+    `issue.IssueVerifier` but the group/pairing work of ALL rows runs
+    through the fixed-shape stage tiles — the total distinct-program
+    count is constant in `(n_in, n_out)`, batch size, and parameter set.
+    Rows are flat: an action's well-formedness rows, its membership rows
+    (output x digit) and its equality rows (one per output) sit at
+    running offsets, so a call of one shape dispatches exactly the rows
+    a call of mixed shapes with as many rows does, and an issue's rows
+    ride the stage calls of the block's transfers: an issue is its
+    outputs' well-formedness rows (no aggregate rows) and the range
+    statement a transfer's outputs carry.
     """
 
     def __init__(self, pp: PublicParams):
@@ -392,77 +441,108 @@ class BatchedTransferVerifier:
         self.table2 = self.membership.table2  # ped[:2]
 
     @_spanned("batch.transfer.verify")
-    def verify(self, txs: Sequence[Tuple[list, list, bytes]]) -> np.ndarray:
-        """txs: (inputs, outputs, transfer_proof_bytes), each of its own
-        shape. Returns bool array (B,). A 1-in/1-out tx carries no range
-        proof and contributes no range rows (reference
-        transfer.go:55-59)."""
+    def verify(self, txs: Sequence) -> np.ndarray:
+        """txs: `(inputs, outputs, transfer_proof_bytes)`, each of its
+        own shape, or `IssueRow`s. Returns bool array (B,). A 1-in/1-out
+        transfer carries no range proof and contributes no range rows
+        (reference transfer.go:55-59); an issue always carries one."""
         B = len(txs)
         if B == 0:
             return np.zeros(0, dtype=bool)
-        shapes = [(len(t[0]), len(t[1])) for t in txs]
-
-        def _count_done():
-            # counted on COMPLETION (not entry): an ABANDONED bounded
-            # worker (verify timeout already degraded the block to host)
-            # must not report its discarded txs as device-verified —
-            # they were counted under ledger.validate.host instead. An
-            # entry-side count would always precede the deadline expiry
-            # and defeat the guard.
-            if not resilience.call_abandoned():
-                mx.counter("batch.transfer.txs").inc(B)
-                mx.counter("batch.transfer.calls").inc()
-                mx.counter("batch.transfer.shapes").inc(len(set(shapes)))
+        issue = [isinstance(t, IssueRow) for t in txs]
+        issues = [t for t, is_issue in zip(txs, issue) if is_issue]
+        shapes = {
+            (len(t[0]), len(t[1]))
+            for t, is_issue in zip(txs, issue) if not is_issue
+        }
 
         proofs = []
         ok = np.ones(B, dtype=bool)
         with devobs.glue("parse"):
             for i, t in enumerate(txs):
+                kind = IssueProof if issue[i] else TransferProof
                 try:
-                    proofs.append(TransferProof.from_bytes(t[2]))
+                    proofs.append(kind.from_bytes(t[2]))
                 except Exception:
-                    proofs.append(TransferProof(wf=b"", range_correctness=None))
+                    proofs.append(kind(wf=b"", range_correctness=None))
                     ok[i] = False
-        wf_ok = self.wf.verify(
-            [(t[0], t[1], p.wf) for t, p in zip(txs, proofs)]
+        # (a row of either kind with its well-formedness bytes third)
+        ok &= self.wf.verify(
+            [
+                t._replace(proof=p.wf) if issue[i] else (t[0], t[1], p.wf)
+                for i, (t, p) in enumerate(zip(txs, proofs))
+            ]
         )
-        ok &= wf_ok
 
-        rp = self.pp.range_params
-        exponent, base = rp.exponent, rp.base
-        ranges: List[Optional[RangeProof]] = []
+        # a row's range statement: (outputs, RangeProof), or None where
+        # it has none to verify
+        ranges: List[Optional[Tuple[list, RangeProof]]] = []
         with devobs.glue("parse"):
-            for i, (p, (n_in, n_out)) in enumerate(zip(proofs, shapes)):
-                if _skip_range(n_in, n_out):
+            for i, (t, p) in enumerate(zip(txs, proofs)):
+                if not issue[i] and _skip_range(len(t[0]), len(t[1])):
                     ranges.append(None)  # the WF verdict is the whole verdict
                     continue
-                if p.range_correctness is None:
-                    ok[i] = False
-                    ranges.append(None)
-                    continue
-                try:
-                    rpf = RangeProof.from_bytes(p.range_correctness)
-                    if (
-                        len(rpf.membership_proofs) != n_out
-                        or len(rpf.digit_commitments) != n_out
-                        or any(len(r) != exponent for r in rpf.membership_proofs)
-                        or any(len(r) != exponent for r in rpf.digit_commitments)
-                        or len(rpf.value_resps) != n_out
-                        or len(rpf.token_bf_resps) != n_out
-                        or len(rpf.com_bf_resps) != n_out
-                    ):
-                        raise ValueError("range proof not well formed")
-                    ranges.append(rpf)
-                except Exception:
-                    ok[i] = False
-                    ranges.append(None)
+                outputs = t.outputs if issue[i] else t[1]
+                rpf = self._range_proof(p.range_correctness, len(outputs))
+                ok[i] &= rpf is not None
+                ranges.append(None if rpf is None else (outputs, rpf))
+        ok &= self._range_verdicts(ranges)
+        # counted on COMPLETION (not entry): an ABANDONED bounded worker
+        # (verify timeout already degraded the block to host) must not
+        # report its discarded rows as device-verified — they were
+        # counted under ledger.validate.host instead. An entry-side
+        # count would always precede the deadline expiry and defeat the
+        # guard.
+        if not resilience.call_abandoned():
+            mx.counter("batch.transfer.txs").inc(B - len(issues))
+            mx.counter("batch.transfer.calls").inc()
+            mx.counter("batch.transfer.shapes").inc(len(shapes))
+            mx.counter("batch.issue.records").inc(len(issues))
+            mx.counter("batch.issue.outputs").inc(
+                sum(len(t.outputs) for t in issues)
+            )
+        return ok
 
-        # ---- membership proofs, flattened over (tx, output, digit)
+    def _range_proof(self, raw: Optional[bytes], n_out: int):
+        """-> the `RangeProof` of `raw` where it has what `n_out` outputs
+        ask for, else None (no proof, malformed bytes, a count that does
+        not fit): the row verifies False."""
+        if raw is None:
+            return None
+        exponent = self.pp.range_params.exponent
+        try:
+            rpf = RangeProof.from_bytes(raw)
+            if (
+                len(rpf.membership_proofs) != n_out
+                or len(rpf.digit_commitments) != n_out
+                or any(len(r) != exponent for r in rpf.membership_proofs)
+                or any(len(r) != exponent for r in rpf.digit_commitments)
+                or len(rpf.value_resps) != n_out
+                or len(rpf.token_bf_resps) != n_out
+                or len(rpf.com_bf_resps) != n_out
+            ):
+                return None
+            return rpf
+        except Exception:
+            return None
+
+    def _range_verdicts(
+        self, ranges: Sequence[Optional[Tuple[list, RangeProof]]]
+    ) -> np.ndarray:
+        """The range half of a call, over statements from wherever they
+        come (a transfer's outputs, an issue's): `(outputs, RangeProof)`
+        with `exponent` digits an output, or None for a row that brings
+        none. -> bool array, True where there was nothing to verify."""
+        ok = np.ones(len(ranges), dtype=bool)
+        rp = self.pp.range_params
+        exponent, base = rp.exponent, rp.base
+        live = [i for i, r in enumerate(ranges) if r is not None]
+
+        # ---- membership proofs, flattened over (row, output, digit)
         mem_proofs, mem_coms, mem_idx = [], [], []
-        for i, rpf in enumerate(ranges):
-            if rpf is None:
-                continue
-            for k in range(shapes[i][1]):
+        for i in live:
+            outputs, rpf = ranges[i]
+            for k in range(len(outputs)):
                 for d in range(exponent):
                     mem_proofs.append(rpf.membership_proofs[k][d])
                     mem_coms.append(rpf.digit_commitments[k][d])
@@ -474,21 +554,19 @@ class BatchedTransferVerifier:
                     ok[i] = False
 
         # ---- equality proofs: token rows (3 bases) + aggregate rows (2),
-        # one of each per output, flat over the live transactions
-        live = [i for i in range(B) if ranges[i] is not None]
+        # one of each per output, flat over the live rows
         if not live:
-            _count_done()
             return ok
         L = lb.NLIMBS
-        n_outs = [shapes[i][1] for i in live]
-        # live transaction li owns the flat rows at[li]:at[li+1]
+        n_outs = [len(ranges[i][0]) for i in live]
+        # live row li owns the flat rows at[li]:at[li+1]
         at = np.concatenate([[0], np.cumsum(n_outs)])
         N = int(at[-1])
         with devobs.glue("hostec"):
             # an output's digit commitments folded: prod_d com_d^(base^d)
             powers = [base**d % hm.R for d in range(exponent)]
             aggs = [
-                hm.g1_multiexp(ranges[i].digit_commitments[k], powers)
+                hm.g1_multiexp(ranges[i][1].digit_commitments[k], powers)
                 for li, i in enumerate(live)
                 for k in range(n_outs[li])
             ]
@@ -499,8 +577,7 @@ class BatchedTransferVerifier:
             agg_stmt = np.zeros((N, 3, L), dtype=np.int32)
             chals = np.zeros((len(live), L), dtype=np.int32)
             for li, i in enumerate(live):
-                rpf = ranges[i]
-                outputs = txs[i][1]
+                outputs, rpf = ranges[i]
                 for k in range(n_outs[li]):
                     r = at[li] + k
                     tok_resp[r] = cv.encode_scalars(
@@ -525,13 +602,11 @@ class BatchedTransferVerifier:
         with devobs.glue("decode"):
             com_tok_h = cv.decode_points(com_tok)
             com_val_h = cv.decode_points(com_val)
-        from .rangeproof import RangeVerifier
-
         with devobs.glue("challenge"):
             for li, i in enumerate(live):
-                rpf = ranges[i]
+                outputs, rpf = ranges[i]
                 verifier = RangeVerifier(
-                    txs[i][1], base, exponent, self.pp.ped_params,
+                    outputs, base, exponent, self.pp.ped_params,
                     rp.sign_pk, self.pp.ped_gen, rp.Q,
                 )
                 chal = verifier._challenge(
@@ -541,5 +616,4 @@ class BatchedTransferVerifier:
                 )
                 if chal != rpf.challenge:
                     ok[i] = False
-        _count_done()
         return ok

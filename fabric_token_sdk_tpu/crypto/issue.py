@@ -8,7 +8,7 @@ Reference: `crypto/issue/issue.go` (Issue action + proof composition),
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 from . import rangeproof, wellformedness as wf
 from .setup import PublicParams
@@ -28,6 +28,17 @@ class IssueProof:
     def from_bytes(cls, raw: bytes) -> "IssueProof":
         d = loads(raw)
         return cls(d["wf"], d["rc"])
+
+
+class IssueRow(NamedTuple):
+    """An issue action as a row of the batched proof plane
+    (`crypto/batch.py`): the statement `IssueVerifier` is built over and
+    the proof it reads. A block's transfer rows are plain `(inputs,
+    outputs, proof)` tuples; the type is what tells the two apart."""
+
+    outputs: list  # the issued outputs' commitment points
+    anonymous: bool
+    proof: bytes
 
 
 class IssueProver:

@@ -249,6 +249,10 @@ class MembershipVerifier:
     def verify(self, p: MembershipProof) -> None:
         if p.commitment != self.commitment:
             raise ValueError("membership proof commitment mismatch")
+        if p.signature.R is None or p.signature.S is None:
+            # with R at infinity the pairing side is e(P^{z_bf} - S^c, Q)
+            # alone, which a prover holding no signature can answer
+            raise ValueError("membership proof signature at infinity")
         pok = POK(
             challenge=p.challenge,
             signature=p.signature,

@@ -150,7 +150,13 @@ class ZKATDLogDriver(Driver):
     # ------------------------------------------------------------ validate
 
     @vguard
-    def validate_issue(self, action_bytes: bytes):
+    def validate_issue(self, action_bytes: bytes, proof_verified=None):
+        """`proof_verified`: the block-batched plane's verdict on this
+        action's proof (`issue_batch_plan`'s statement: these bytes'
+        outputs, flag and proof), tri-state like `validate_transfer`'s:
+        True skips the host proof check, False rejects, None (no
+        verdict) verifies on the host. The authorisation checks run here
+        for every issue, whatever the verdict."""
         d = loads_cached(action_bytes)
         outputs = [_ZTOKENS.lookup(raw) for raw in d["outputs"]]
         if not outputs:
@@ -162,13 +168,16 @@ class ZKATDLogDriver(Driver):
                 raise ValidationError("issuer is not authorized")
         elif issuer:
             raise ValidationError("anonymous issue must not name an issuer")
-        try:
-            with profiler.leg("fiat_shamir"):
-                issue_mod.IssueVerifier(
-                    [t.data for t in outputs], anonymous, self.pp
-                ).verify(d["proof"])
-        except ValueError as e:
-            raise ValidationError(f"invalid issue proof: {e}") from e
+        if proof_verified is False:
+            raise ValidationError("invalid issue proof")
+        if proof_verified is None:
+            try:
+                with profiler.leg("fiat_shamir"):
+                    issue_mod.IssueVerifier(
+                        [t.data for t in outputs], anonymous, self.pp
+                    ).verify(d["proof"])
+            except ValueError as e:
+                raise ValidationError(f"invalid issue proof: {e}") from e
         # non-anonymous issues require the named issuer's signature
         return d["outputs"], issuer
 
@@ -249,6 +258,29 @@ class ZKATDLogDriver(Driver):
                 [t.data for t in in_tokens],
                 [t.data for t in out_tokens],
                 proof,
+            )
+        except Exception:
+            return None
+
+    def issue_batch_plan(self, action_bytes: bytes):
+        """Block-batched plane hook: the `IssueRow` (issued outputs'
+        commitment points, the `anon` flag, proof bytes) the
+        `BatchedTransferVerifier` consumes beside the block's transfer
+        rows. The statement is the action's own bytes, the ones
+        `validate_issue` reads, so a verdict computed here is exactly the
+        host `IssueVerifier` check; who may issue is `validate_issue`'s to
+        decide, on the host, verdict or none. Malformed bytes and an
+        issue of no outputs return None and fall to the host path (which
+        rejects them with the precise error)."""
+        try:
+            d = loads_cached(action_bytes)
+            out_tokens = [_ZTOKENS.lookup(raw) for raw in d["outputs"]]
+            proof, anonymous = d["proof"], d["anon"]
+            if (not out_tokens or not isinstance(proof, bytes)
+                    or not isinstance(anonymous, bool)):
+                return None
+            return issue_mod.IssueRow(
+                [t.data for t in out_tokens], anonymous, proof
             )
         except Exception:
             return None

@@ -423,8 +423,9 @@ class Network:
         fresh, _dups = self._split_fresh(subs, resolve_known=False)
         requests = [s.request for s in fresh]
         host_pv: Dict[int, Dict[int, bool]] = {}
+        issue_pv: Dict[int, Dict[int, bool]] = {}
         verdicts = self._pipeline.proof_verdicts(
-            requests, timings, host_verdicts=host_pv
+            requests, timings, host_verdicts=host_pv, issue_verdicts=issue_pv
         )
         # the batched signature plane is state-independent too (payloads
         # and identities come from request bytes), so it overlaps the
@@ -446,6 +447,9 @@ class Network:
             },
             "host_verdicts": {
                 id(fresh[ti]): v for ti, v in host_pv.items()
+            },
+            "issue_verdicts": {
+                id(fresh[ti]): v for ti, v in issue_pv.items()
             },
             "timings": timings,
             "cut_mono": cut_mono,
@@ -522,8 +526,10 @@ class Network:
             if pre is None:
                 timings: dict = {}
                 host_pv: Dict[int, Dict[int, bool]] = {}
+                issue_pv: Dict[int, Dict[int, bool]] = {}
                 verdicts = self._pipeline.proof_verdicts(
-                    requests, timings, host_verdicts=host_pv
+                    requests, timings, host_verdicts=host_pv,
+                    issue_verdicts=issue_pv,
                 )
                 sig_verdicts = self._pipeline.sign_verdicts(requests, timings)
                 cons_verdicts = self._pipeline.conservation_verdicts(
@@ -559,6 +565,11 @@ class Network:
                     ti: phv[id(s)]
                     for ti, s in enumerate(fresh) if id(s) in phv
                 }
+                piv = pre.get("issue_verdicts") or {}
+                issue_pv = {
+                    ti: piv[id(s)]
+                    for ti, s in enumerate(fresh) if id(s) in piv
+                }
             commit_time = time.time()
             view = _BlockView(self._state, self._spent)
             events: List[FinalityEvent] = []
@@ -579,6 +590,7 @@ class Network:
                         event = self._validate_tx(
                             request, view, commit_time, proofs,
                             sig_verdicts.get(ti), cons_verdicts.get(ti),
+                            issue_pv.get(ti),
                         )
                     if fresh[ti].trace is not None:
                         event.trace_id = fresh[ti].trace.trace_id
@@ -622,7 +634,9 @@ class Network:
                 for event in events:
                     if not event.transient:
                         self._status[event.tx_id] = event
-                self._record_block_metrics(requests, events, verdicts)
+                self._record_block_metrics(
+                    requests, events, verdicts, issue_pv
+                )
             merge_s = time.monotonic() - t0
             # per-block critical-path breakdown: where this block's wall
             # time went (queue wait / grouping / device verify / host
@@ -746,14 +760,15 @@ class Network:
                      commit_time: float,
                      proofs: Optional[Dict[int, bool]],
                      sigs: Optional[Dict[tuple, tuple]] = None,
-                     cons: Optional[Dict[int, bool]] = None) -> FinalityEvent:
+                     cons: Optional[Dict[int, bool]] = None,
+                     issues: Optional[Dict[int, bool]] = None) -> FinalityEvent:
         tx_id = request.anchor
         try:
             with mx.span("network.validate", tx=tx_id):
                 result = self.validator.validate(
                     request, view.resolve, now=commit_time,
                     transfer_proofs=proofs, sig_verified=sigs,
-                    conservation=cons,
+                    conservation=cons, issue_proofs=issues,
                 )
             view.apply(tx_id, result)
             mx.counter("network.tx.valid").inc()
@@ -771,7 +786,8 @@ class Network:
                 transient=True,
             )
 
-    def _record_block_metrics(self, requests, events, verdicts) -> None:
+    def _record_block_metrics(self, requests, events, verdicts,
+                              issue_verdicts) -> None:
         mx.counter("ledger.blocks.committed").inc()
         mx.histogram(
             "ledger.block.size", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256)
@@ -780,6 +796,11 @@ class Network:
         transfers = sum(len(r.transfers) for r in requests)
         mx.counter("ledger.validate.batched").inc(batched)
         mx.counter("ledger.validate.host").inc(transfers - batched)
+        # the issue records, counted apart: the two above stay transfers'
+        issues_batched = sum(len(v) for v in issue_verdicts.values())
+        issues = sum(len(r.issues) for r in requests)
+        mx.counter("ledger.validate.issues_batched").inc(issues_batched)
+        mx.counter("ledger.validate.issues_host").inc(issues - issues_batched)
         if transfers:
             mx.histogram(
                 "ledger.block.batched_frac",

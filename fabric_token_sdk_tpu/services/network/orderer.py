@@ -133,10 +133,10 @@ class BlockPolicy:
                        with `MessageTooLarge`
                        [BatchSize.AbsoluteMaxBytes]. 0 = off.
                        A message's size is its request's wire length.
-    `min_batch`      — the least number of transfer rows in a block
-                       worth a device batch call (of any shapes: a
-                       block's rows ride one call); a block with fewer
-                       takes the host path.
+    `min_batch`      — the least number of planned records in a block
+                       (transfers of any shapes and issues: a block's
+                       rows ride one call) worth a device batch call; a
+                       block with fewer takes the host path.
     `use_batched`    — master switch for the batched proof plane.
     `queue_max`      — admission control: ordering-queue depth beyond
                        which enqueues are rejected with `Backpressure`
@@ -598,22 +598,25 @@ def _plane_share(plane: str, timings: dict):
 class BlockValidationPipeline:
     """The batched proof plane for one block.
 
-    Phase 1 (plan): ask the driver for a batch plan per transfer record —
-    `(shape, (input_points, output_points, proof_bytes))`, or None
-    for host validation (fabtoken, malformed bytes, non-batchable kinds).
-    The shape `(n_in, n_out)` is the row's label, not a grouping key.
+    Phase 1 (plan): ask the driver for a batch plan per action record —
+    a transfer's `(shape, (input_points, output_points, proof_bytes))`,
+    an issue's row (`issue_batch_plan`), or None for host validation
+    (fabtoken, malformed bytes, non-batchable kinds). The shape
+    `(n_in, n_out)` is a transfer row's label, not a grouping key.
 
-    Phase 2 (batched verify): the call is the block's. If the block has
-    at least `min_batch` planned rows they go, in request order and
-    whatever their shapes, through ONE `BatchedTransferVerifier` call
-    (constant XLA program count regardless of shape/batch, and the
-    block's fixed cost — a padded dispatch per stage call, one Miller
-    walk, one final exponentiation — paid once: see `crypto/batch.py`).
-    Verdicts come back keyed `{tx_index: {transfer_index: bool}}`.
+    Phase 2 (batched verify): the call is the block's, whatever its
+    operations. If the block has at least `min_batch` planned records
+    they go, in request order and whatever their shapes, through ONE
+    `BatchedTransferVerifier` call (constant XLA program count
+    regardless of shape/batch, and the block's fixed cost — a padded
+    dispatch per stage call, one Miller walk, one final exponentiation —
+    paid once: see `crypto/batch.py`). Verdicts come back per operation,
+    keyed `{tx_index: {record_index: bool}}`.
 
     Phase 3 is the ledger's: sequential per-tx `RequestValidator.validate`
     with MVCC over the block view; records with a verdict skip (True) or
-    fail (False) the host proof check, everything else verifies on host.
+    fail (False) the host proof check, everything else verifies on host
+    (who may issue is checked there for every issue).
 
     The SIGNATURE plane (`sign_verdicts`) is the same idea for the
     block's pk-kind signature obligations — owner/issuer/auditor Schnorr
@@ -653,16 +656,26 @@ class BlockValidationPipeline:
         self, requests: Sequence[TokenRequest],
         timings: Optional[dict] = None,
         host_verdicts: Optional[Dict[int, Dict[int, bool]]] = None,
+        issue_verdicts: Optional[Dict[int, Dict[int, bool]]] = None,
     ) -> Dict[int, Dict[int, bool]]:
-        """`timings`, when passed, is filled with the critical-path
+        """-> the device plane's verdicts on the block's transfer
+        records, `{tx_index: {transfer_index: bool}}`.
+
+        `issue_verdicts`, when passed as a dict, receives the same
+        call's verdicts on the block's issue records, `{tx_index:
+        {issue_index: bool}}`: the issues are planned beside the
+        transfers and ride the block's one call. `None` (the default)
+        leaves the issues to the host: nobody would read their verdicts.
+
+        `timings`, when passed, is filled with the critical-path
         split of this call: `grouping_s` (planning the block's rows),
         `device_verify_s` (time inside the batched verify call,
         including a failed one that degraded to host) and `verify_calls`
         (completed plane calls of the block: 0 or 1).
 
         `host_verdicts`, when passed as a dict, receives True-only
-        verdicts from the batch-first HOST pass over every row the
-        device plane left behind (`_host_proof_batch`). They are kept
+        verdicts from the batch-first HOST pass over every transfer row
+        the device plane left behind (`_host_proof_batch`). They are kept
         OUT of the returned device verdicts so the
         `ledger.validate.batched/host` accounting (and every fallback
         counter) still describes the device plane alone; the ledger
@@ -677,11 +690,14 @@ class BlockValidationPipeline:
         if timings is None:
             timings = {}
         with devobs.annotate("stageA.proof"), _plane_share("verify", timings):
-            return self._proof_verdicts(requests, timings, host_verdicts)
+            return self._proof_verdicts(
+                requests, timings, host_verdicts, issue_verdicts
+            )
 
     def _proof_verdicts(
         self, requests: Sequence[TokenRequest], timings: dict,
         host_verdicts: Optional[Dict[int, Dict[int, bool]]],
+        issue_verdicts: Optional[Dict[int, Dict[int, bool]]],
     ) -> Dict[int, Dict[int, bool]]:
         timings.setdefault("grouping_s", 0.0)
         timings.setdefault("device_verify_s", 0.0)
@@ -692,20 +708,30 @@ class BlockValidationPipeline:
         plan = getattr(driver, "transfer_batch_plan", None)
         if plan is None:
             return {}
+        plan_issue = (
+            None if issue_verdicts is None
+            else getattr(driver, "issue_batch_plan", None)
+        )
         t0 = time.monotonic()
-        # the block's plannable transfer rows in request order, each
-        # labelled with its shape: they ride ONE plane call whatever
-        # their shapes
-        rows: List[Tuple[int, int, tuple]] = []
+        # the block's plannable records in request order (a request's
+        # issues before its transfers, as the validator walks them), a
+        # transfer labelled with its shape: they ride ONE plane call
+        # whatever their shapes and operations
+        rows: List[Tuple[int, str, int, tuple]] = []
         shapes = set()
         for ti, req in enumerate(requests):
+            if plan_issue is not None:
+                for ii, rec in enumerate(req.issues):
+                    row = plan_issue(rec.action)
+                    if row is not None:
+                        rows.append((ti, "issue", ii, row))
             for ri, rec in enumerate(req.transfers):
                 p = plan(rec.action)
                 if p is None:
                     continue
                 shape, row = p
                 shapes.add(shape)
-                rows.append((ti, ri, row))
+                rows.append((ti, "transfer", ri, row))
         timings["grouping_s"] = time.monotonic() - t0
 
         ok = self._device_proof_call(rows, len(shapes), timings)
@@ -713,18 +739,24 @@ class BlockValidationPipeline:
             # rows the device plane leaves behind (a block under
             # `min_batch`, open breaker, failed/timed-out dispatch, no
             # device plane at all): the batch-first HOST pass still
-            # verifies them in one native multiexp + one block-level
-            # Fiat-Shamir call before the per-tx scalar loop sees them
+            # verifies the transfers among them in one native multiexp +
+            # one block-level Fiat-Shamir call before the per-tx scalar
+            # loop sees them; an issue left behind is the scalar path's
             if host_verdicts is not None:
-                self._host_proof_batch(rows, host_verdicts, timings)
+                self._host_proof_batch(
+                    [(ti, ri, row) for ti, kind, ri, row in rows
+                     if kind == "transfer"],
+                    host_verdicts, timings,
+                )
             return {}
         verdicts: Dict[int, Dict[int, bool]] = {}
-        for (ti, ri, _), good in zip(rows, ok):
-            verdicts.setdefault(ti, {})[ri] = bool(good)
+        for (ti, kind, ri, _), good in zip(rows, ok):
+            into = issue_verdicts if kind == "issue" else verdicts
+            into.setdefault(ti, {})[ri] = bool(good)
         return verdicts
 
     def _device_proof_call(
-        self, rows: List[Tuple[int, int, tuple]], n_shapes: int,
+        self, rows: List[Tuple[int, str, int, tuple]], n_shapes: int,
         timings: dict,
     ) -> Optional[Sequence[bool]]:
         """The block's one `BatchedTransferVerifier.verify` call over
@@ -734,16 +766,17 @@ class BlockValidationPipeline:
         failed or timed out (a fallback, counted and logged)."""
         if len(rows) < max(1, self.policy.min_batch):
             return None
+        n_issues = sum(1 for r in rows if r[1] == "issue")
+        # what the span and the flight events say of the call: its
+        # transfer rows under `txs`, as ever, its issue rows beside them
+        held = dict(shapes=n_shapes, txs=len(rows) - n_issues, issues=n_issues)
         driver = self.validator.driver
         brk = resilience.breaker("verify")
         if not brk.allow():
             # open breaker: instant host fallback — no deadline paid,
             # no worker stacked onto a sick backend. The host plane
             # re-verifies these rows with verdicts unchanged.
-            mx.flight(
-                "verify.host_fallback", shapes=n_shapes,
-                txs=len(rows), reason="breaker_open",
-            )
+            mx.flight("verify.host_fallback", reason="breaker_open", **held)
             return None
         try:
             verifier = driver.batch_verifier()
@@ -767,13 +800,11 @@ class BlockValidationPipeline:
             # worker, so a `hang` kind is governed by the deadline)
             # exercises the degrade-to-host path below
             faults.fire("batch.verify")
-            return verifier.verify([row for _, _, row in rows])
+            return verifier.verify([r[3] for r in rows])
 
         tg = time.monotonic()
         try:
-            with mx.span(
-                "ledger.block.batch_verify", shapes=n_shapes, txs=len(rows)
-            ):
+            with mx.span("ledger.block.batch_verify", **held):
                 ok = resilience.bounded_call(
                     _device_verify, resilience.device_deadline_s("verify"),
                     plane="verify",
@@ -784,27 +815,21 @@ class BlockValidationPipeline:
             # and fall to host — the block must not stall
             brk.record_failure(timeout=True)
             mx.counter("ledger.block.batch_errors").inc()
-            mx.flight(
-                "verify.host_fallback", shapes=n_shapes,
-                txs=len(rows), reason="timeout",
-            )
+            mx.flight("verify.host_fallback", reason="timeout", **held)
             return None
         except Exception:
             # the host plane re-verifies these rows; never fail a block
             # on a device-plane error
             brk.record_failure()
             mx.counter("ledger.block.batch_errors").inc()
-            mx.flight(
-                "verify.host_fallback", shapes=n_shapes, txs=len(rows)
-            )
+            mx.flight("verify.host_fallback", **held)
             return None
         finally:
             timings["device_verify_s"] += time.monotonic() - tg
         brk.record_success()
         timings["verify_calls"] += 1
         mx.flight(
-            "verify.device", shapes=n_shapes, txs=len(rows),
-            ok=int(sum(1 for g in ok if g)),
+            "verify.device", ok=int(sum(1 for g in ok if g)), **held
         )
         return ok
 

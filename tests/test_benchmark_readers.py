@@ -295,7 +295,8 @@ def test_the_readers_of_pr_40_name_what_the_program_emits(name):
     whole = by_name[f"{plane}.host_glue_share"]
     for key in ("unit", "better", "source", "layer", "moves", "workloads"):
         assert entry[key] == whole[key], key
-    assert entry["workloads"][-1] == "b300e5.wallets"
+    # (by name: later PRs append their cells behind it, `b300e5.ops` first)
+    assert "b300e5.wallets" in entry["workloads"]
     whole_spec = mf._load(mf.data_file("layer_metrics", whole["name"]))
     assert spec == dict(whole_spec, num=[f"device.{plane}.glue.{part}_us"])
     assert set(spec["num"] + spec["den"]) <= _emitted_counters(plane)

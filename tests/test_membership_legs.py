@@ -29,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import hostplane  # noqa: E402
 
 from fabric_token_sdk_tpu.crypto import (  # noqa: E402
-    batch, hostmath as hm, pssign, sigproof, token as tok,
+    batch, hostmath as hm, pssign, schnorr, sigproof, token as tok,
 )
 from fabric_token_sdk_tpu.crypto.rangeproof import RangeProof  # noqa: E402
 from fabric_token_sdk_tpu.crypto.setup import setup  # noqa: E402
@@ -259,6 +259,111 @@ def test_crafted_edge_row_gives_the_four_legs_gt_and_verdict(
     assert masks == [[False, False], identity_legs, [False, False]]
     if all(identity_legs):
         assert gts[1] == hm.FP12_ONE
+
+
+def forged_proof(pp, value, rng):
+    """-> (a membership proof for a commitment to `value`, any value, made
+    with no signature at all; the commitment; its blinding). `R` is the
+    point at infinity (JSON null on the wire) and `S = P^s`, so the four
+    legs reduce to `e(P^{z_bf - s c}, Q)`, which `z_bf = rho_bf + s c`
+    answers; the value's and the hash's responses are free (`ROADMAP.md`
+    C13)."""
+    rp, ped2 = pp.range_params, pp.ped_params[:2]
+    bf, s, rho_v, rho_cb, rho_bf, z_h = (hm.rand_zr(rng) for _ in range(6))
+    com = hm.g1_multiexp(ped2, [value, bf])
+    sig = pssign.Signature(None, hm.g1_mul(pp.ped_gen, s))
+    com_gt = hm.pairing_product([(hm.g1_mul(pp.ped_gen, rho_bf), rp.Q)])
+    com_val = hm.g1_multiexp(ped2, [rho_v, rho_cb])
+    c = sigproof.MembershipVerifier(
+        com, pp.ped_gen, rp.Q, rp.sign_pk, ped2)._challenge(com_gt, com_val, sig)
+    proof = sigproof.MembershipProof(
+        challenge=c, signature=sig, value_resp=(rho_v + c * value) % hm.R,
+        com_bf_resp=(rho_cb + c * bf) % hm.R,
+        sig_bf_resp=(rho_bf + s * c) % hm.R, hash_resp=z_h, commitment=com)
+    return proof, com, bf
+
+
+@pytest.mark.parametrize("path", ["scalar", "batched"])
+def test_a_forged_proof_with_R_at_infinity_is_rejected(monkeypatch, path):
+    """A commitment to 7 at base 4, outside the signed set: the sigma
+    equations of both verifiers hold for the forged proof (the element of
+    GT is the one the challenge was hashed over), so what rejects it is
+    the look at `R` and `S`: on the scalar path, and as the row's verdict
+    on the batched one, between two honest rows that stay accepted."""
+    hostplane.install(monkeypatch)
+    pp = _pp("base4_exp2")
+    rng = random.Random("forged")
+    forged, com, _ = forged_proof(pp, 7, rng)
+    rp = pp.range_params
+    mv = sigproof.MembershipVerifier(
+        com, pp.ped_gen, rp.Q, rp.sign_pk, pp.ped_params[:2])
+    gt, ok = reference(pp, forged, com)
+    # the equations hold: only the point at infinity gives it away
+    sp = schnorr.SchnorrProof(
+        com, [forged.value_resp, forged.com_bf_resp], forged.challenge)
+    com_val = schnorr.recompute_commitment(pp.ped_params[:2], sp)
+    assert mv._challenge(gt, com_val, forged.signature) == forged.challenge
+    assert ok is False
+    if path == "scalar":
+        with pytest.raises(ValueError, match="signature at infinity"):
+            mv.verify(forged)
+        return
+    proofs, coms = digit_rows(pp, rng)
+    proofs, coms = [proofs[0], forged, proofs[1]], [coms[0], com, coms[1]]
+    gts, oks, _ = batched(monkeypatch, pp, proofs, coms)
+    assert gts[1] == gt
+    assert oks == [True, False, True]
+
+
+@pytest.mark.parametrize("path", ["scalar", "batched"])
+def test_an_issue_of_an_out_of_range_value_over_a_forged_digit_is_rejected(
+    monkeypatch, path
+):
+    """The forgery where it matters, on the path an issuer's range proof
+    takes: an issue of 7 at base 4 / exponent 1 (one digit: the signed set
+    is 0..3) whose one membership proof is the forged one and whose every
+    other equation holds. The scalar `IssueVerifier` and the issue's row
+    on the plane both refuse it, beside an honest issue that passes."""
+    from fabric_token_sdk_tpu.crypto.issue import (
+        IssueProof, IssueProver, IssueRow, IssueVerifier,
+    )
+    from fabric_token_sdk_tpu.crypto.rangeproof import RangeVerifier
+    from fabric_token_sdk_tpu.crypto.wellformedness import IssueWFProver
+
+    hostplane.install(monkeypatch)
+    pp = setup(base=4, exponent=1, rng=random.Random(SETUP_SEED))
+    rng = random.Random("forged/issue")
+    rp, ped = pp.range_params, pp.ped_params
+    type_hash = hm.hash_to_zr(b"USD")
+    bf = hm.rand_zr(rng)
+    token = hm.g1_multiexp(ped, [type_hash, 7, bf])
+    forged, digit, bf_d = forged_proof(pp, 7, rng)
+    # the equality proof, by hand: the token opens to (type, 7, bf) and
+    # its one digit commitment to (7, bf_d)
+    r_t, r_v, r_tb, r_cb = (hm.rand_zr(rng) for _ in range(4))
+    chal = RangeVerifier(
+        [token], 4, 1, ped, rp.sign_pk, pp.ped_gen, rp.Q
+    )._challenge([hm.g1_multiexp(ped, [r_t, r_v, r_tb])],
+                 [hm.g1_multiexp(ped[:2], [r_v, r_cb])], [[digit]])
+    rpf = RangeProof(
+        challenge=chal, type_resp=(r_t + chal * type_hash) % hm.R,
+        value_resps=[(r_v + chal * 7) % hm.R],
+        token_bf_resps=[(r_tb + chal * bf) % hm.R],
+        com_bf_resps=[(r_cb + chal * bf_d) % hm.R],
+        digit_commitments=[[digit]], membership_proofs=[[forged]])
+    wf = IssueWFProver([("USD", 7, bf)], [token], True, ped, rng).prove()
+    raw = IssueProof(wf=wf, range_correctness=rpf.to_bytes()).to_bytes()
+    sound = hm.g1_multiexp(ped, [type_hash, 3, bf])
+    honest = IssueProver(
+        [tok.TokenDataWitness("USD", 3, bf)], [sound], True, pp, rng).prove()
+    if path == "scalar":
+        IssueVerifier([sound], True, pp).verify(honest)
+        with pytest.raises(ValueError, match="signature at infinity"):
+            IssueVerifier([token], True, pp).verify(raw)
+    else:
+        rows = [IssueRow([sound], True, honest), IssueRow([token], True, raw)]
+        assert batch.BatchedTransferVerifier(pp).verify(rows).tolist() \
+            == [True, False]
 
 
 def test_the_infinity_mask_reads_zero_and_p_as_zero():
