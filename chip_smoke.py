@@ -26,9 +26,11 @@ Phases, in order of cost:
           "cpu" in its result and is never the default)
   native  remove + rebuild _bn254.so/_fastser.so from the committed C
           sources; the native host runtime must pass its self-check
-  field   the two numeric assumptions of ops/, bit-exact against Python
-          ints: `limbs.mul_full` (f32 dot_general, Precision.HIGHEST),
-          `FP.mul`/`FR.mul`, and `curve.msm_select` (int32 einsum)
+  field   the numeric assumptions of ops/, bit-exact against Python
+          ints: `limbs.mul_full` (f32 dot_general, Precision.HIGH),
+          `limbs.mul_const` (one pass at Precision.DEFAULT over byte
+          operands), `FP.mul`/`FR.mul`, and `curve.msm_select` (int32
+          einsum); prints the product forms of `ops.health()`
   warmup  `ops.warmup.warmup()`: the 14 canonical programs compile (or
           load from the persistent cache), seconds per program
   tiles   one tile of each group/pairing program through the stage
@@ -205,6 +207,7 @@ def phase_field(run: Run, rng, pairs: int) -> None:
         from fabric_token_sdk_tpu.crypto import hostmath as hm
         from fabric_token_sdk_tpu.ops import curve as cv, limbs as lb
         from fabric_token_sdk_tpu.ops.field import FP, FR
+        from fabric_token_sdk_tpu.utils import devobs
 
         W = 1 << (lb.RADIX_BITS * lb.NLIMBS)
 
@@ -224,8 +227,29 @@ def phase_field(run: Run, rng, pairs: int) -> None:
                                 2 * lb.NLIMBS + 1)
         bad = int((got != want).any(axis=-1).sum())
         check(bad == 0, f"limbs.mul_full inexact on {bad}/{len(xs)} pairs "
-              "(f32 dot_general at Precision.HIGHEST is not exact here)")
+              "(f32 dot_general at Precision.HIGH is not exact here)")
         info["mul_full"] = len(xs)
+
+        # limbs.mul_const: canonical limbs by each baked constant, raw
+        # columns; the all-255 operand drives every column to its bound
+        xs = [0, 1, W - 1] + [rng.randrange(W) for _ in range(pairs)]
+        x_limbs = lb.ints_to_limbs(xs)
+        for spec in (FP, FR):
+            for c in (spec.pprime_limbs, spec.p_limbs):
+                ci = lb.limbs_to_int(c)
+                for keep in (lb.NLIMBS, 2 * lb.NLIMBS):
+                    cols = np.asarray(jax.jit(
+                        lambda x, c=c, keep=keep: lb.mul_const(x, c, keep=keep)
+                    )(x_limbs))
+                    want = [(x * ci) % (1 << (lb.RADIX_BITS * keep)) for x in xs]
+                    got = [lb.limbs_to_int(row) % (1 << (lb.RADIX_BITS * keep))
+                           for row in cols]
+                    bad = sum(1 for g, w in zip(got, want) if g != w)
+                    check(bad == 0 and cols.min() >= 0, f"limbs.mul_const inexact "
+                          f"on {bad}/{len(xs)} operands ({spec.name}, keep {keep}: "
+                          "one pass at Precision.DEFAULT is not exact here)")
+        info["mul_const"] = 8 * len(xs)
+        info["fp_mul"] = devobs.health_section()["fp_mul"]
 
         # FP.mul / FR.mul: Montgomery product on the redundant domain
         # [0, 2p): out < 2p and out == x*y*R^-1 (mod p)

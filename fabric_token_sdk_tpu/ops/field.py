@@ -119,14 +119,19 @@ class FieldSpec:
 
     @_opjit
     def mul(self, x, y):
-        """Montgomery product: REDC(x*y); stays in [0, 2p)."""
+        """Montgomery product: REDC(x*y); stays in [0, 2p).
+
+        One general limb product (x*y) and two by the spec's constants:
+        m = (x*y mod R) * p' mod R, then (x*y + m*p) / R."""
         n = self.nlimbs
-        t = lb.mul_full(x, y)  # (..., 2n+1) canonical digits
-        m = lb.mul_low(t[..., :n], self.pprime_limbs, keep=n)
-        mp = lb.mul_full(m, self.p_limbs)  # (..., 2n+1)
-        pad = [(0, 0)] * (t.ndim - 1) + [(0, 1)]
-        acc = jnp.pad(t, pad) + jnp.pad(mp, pad)  # digits <= 510
-        return lb.normalize_fixed(acc, 1)[..., n : 2 * n]
+        t = lb.mul_full(x, y)  # (..., 2n+1) canonical digits, the top one 0
+        # m mod R: the low n columns, the carry out of limb n-1 dropped;
+        # canonical because it is the next product's operand
+        m = lb.normalize_fixed(lb.mul_const(t[..., :n], self.pprime_limbs, keep=n), 3)
+        mp = lb.mul_const(m, self.p_limbs)  # (..., 2n) raw columns < 2^21
+        # digits <= 255 + 2,080,800: three passes bring them to [0, 256];
+        # t + m*p < 2pR < R^2, so nothing is carried out of limb 2n-1
+        return lb.normalize_fixed(t[..., : 2 * n] + mp, 3)[..., n:]
 
     @_opjit
     def sqr(self, x):

@@ -4,11 +4,19 @@ A k-bit integer is a little-endian vector of 8-bit limbs stored as int32.
 All intermediates are engineered to stay inside int32:
 
 * 8x8-bit partial products are < 2^16,
-* a product column accumulates at most 2*NLIMBS-1 = 63 of them plus a
-  carried-in limb: < 2^23,
+* a product column accumulates at most NLIMBS = 32 of them
+  (32 * 255^2 = 2,080,800 < 2^21), and two such columns plus a digit
+  stay < 2^23,
 * carry normalization uses arithmetic shifts (floor semantics), so signed
   intermediates from subtraction are handled exactly — provided the TOTAL
   value is non-negative (callers add a modulus before subtracting).
+
+Two products, told apart by what the caller holds. Two limb tensors take
+``mul_full``: the outer product's 1,024 cells contracted with a one-hot
+matrix (``Precision.HIGH``: a 16-bit cell is two bfloat16 pieces, not
+one). A limb tensor by a constant known when the program is traced takes
+``mul_const``: one matmul of the 32 limbs by a dense 32-row weight that
+holds the constant's bytes, exact in one bfloat16 pass.
 
 These helpers are modulus-agnostic; ``field.py`` builds Montgomery fields
 on top.
@@ -21,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..utils import devobs, metrics as mx
 
 RADIX_BITS = 8
 RADIX = 1 << RADIX_BITS
@@ -150,11 +160,22 @@ def is_zero(x):
 
 # ---------------------------------------------------------------- multiply
 
+# The precision each product's contraction is given, and from it the form
+# `ops.health()["device"]["fp_mul"]` names: the label is the argument.
+_VAR_PRECISION = jax.lax.Precision.HIGH
+_CONST_PRECISION = jax.lax.Precision.DEFAULT
+PRODUCT_FORMS = {
+    "const": "dense32/" + _CONST_PRECISION.name.lower(),
+    "var": "onehot1024/" + _VAR_PRECISION.name.lower(),
+}
+devobs.note_product_forms(PRODUCT_FORMS)
+
+
 @functools.lru_cache(maxsize=None)
 def _conv_matrix(nx: int, ny: int):
     """One-hot (nx*ny, nx+ny+1) matrix mapping outer-product cell (i,j) to
-    product column i+j. Turns schoolbook multiplication into one dense
-    matmul — the MXU-friendly formulation of limb convolution."""
+    product column i+j: the column sums of a variable-by-variable
+    schoolbook product as one matmul over the flattened outer product."""
     k = nx + ny + 1
     c = np.zeros((nx, ny, k), dtype=np.int32)
     for i in range(nx):
@@ -168,11 +189,17 @@ def _conv_matrix(nx: int, ny: int):
 def mul_full(x, y):
     """Full product of two limb vectors -> nx+ny+1 canonical limbs.
 
-    Outer products are < 2^16 and each column sum < 2^23: all values are
-    exactly representable in float32, so the column contraction runs as an
-    f32 matmul (CPU: real GEMM; TPU: MXU with HIGHEST precision) and is
-    cast back to int32 losslessly. Fully branch-free.
+    The general product: neither operand is known when the program is
+    traced (a constant operand takes `mul_const`). Both operands must be
+    canonical (limbs in [0, 255], as field elements are): outer products are
+    < 2^16 and each column sum < 2^21, all values exactly representable
+    in float32, so the column contraction runs as an f32 matmul over the
+    nx*ny cells and is cast back to int32 losslessly (CPU: real GEMM; TPU:
+    MXU at `Precision.HIGH`, three bfloat16 passes — a 16-bit cell is
+    exactly two bfloat16 pieces and the one-hot weight one, so the third
+    piece `HIGHEST` adds is always zero). Fully branch-free.
     """
+    mx.counter("field.product.general").inc()  # at trace time only
     nx, ny = x.shape[-1], y.shape[-1]
     prod = x[..., :, None] * y[..., None, :]  # int32, exact (< 2^16)
     flat = prod.reshape(prod.shape[:-2] + (nx * ny,)).astype(jnp.float32)
@@ -180,12 +207,46 @@ def mul_full(x, y):
         flat,
         _conv_matrix(nx, ny).astype(np.float32),
         (((flat.ndim - 1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
+        precision=_VAR_PRECISION,
     )
     return normalize_fixed(acc.astype(jnp.int32), 3)
 
 
-def mul_low(x, y, keep=None):
-    """Low `keep` limbs of the product (i.e. product mod RADIX^keep)."""
-    keep = x.shape[-1] if keep is None else keep
-    return mul_full(x, y)[..., :keep]
+@functools.lru_cache(maxsize=None)
+def _const_matrix(c_bytes: bytes, keep: int):
+    """Dense (n, keep) f32 weight T with T[i, i+j] = c_j: x @ T are the
+    columns of x*c. Numpy, for the reason `_conv_matrix` gives."""
+    c = np.frombuffer(c_bytes, dtype=np.uint8)
+    n = len(c)
+    t = np.zeros((n, keep), dtype=np.float32)
+    for i in range(n):
+        w = min(n, keep - i)
+        t[i, i : i + w] = c[:w]
+    return t
+
+
+def mul_const(x, c_limbs, keep=None):
+    """RAW product columns of a limb tensor by a constant: (..., n) by the
+    n canonical limbs of a numpy constant -> (..., keep or 2n) int32
+    columns, column k = sum_i x_i * c_(k-i). Not normalized: the caller
+    runs the carry chain (three passes of `normalize_fixed` do).
+
+    `x` must be canonical (limbs in [0, 255]): operand and weight are then
+    exact in bfloat16 and a column is <= 32 * 255^2 = 2,080,800 < 2^21,
+    exact in the f32 accumulator — one MXU pass at `Precision.DEFAULT` on
+    the TPU, a real f32 GEMM on the CPU backend. `keep=n` builds only the
+    low n columns: the product mod RADIX^n needs no others.
+    """
+    mx.counter("field.product.const").inc()  # at trace time only
+    c = np.asarray(c_limbs)
+    n = c.shape[-1]
+    weight = _const_matrix(c.astype(np.uint8).tobytes(), 2 * n if keep is None else keep)
+    xf = x.astype(jnp.float32)
+    cols = jax.lax.dot_general(
+        xf,
+        weight,
+        (((xf.ndim - 1,), (0,)), ((), ())),
+        precision=_CONST_PRECISION,
+        preferred_element_type=jnp.float32,
+    )
+    return cols.astype(jnp.int32)

@@ -105,6 +105,9 @@ _planes: Dict[str, dict] = {}
 # best-effort fallback for compile events fired on a thread that has
 # no frame open (the dispatch frame lives on the caller's thread)
 _last_frame: Optional[Tuple[str, str]] = None
+# the form of each limb product under every program, as `ops.limbs`
+# declares it at import; empty in a process that never imported ops
+_product_forms: Dict[str, str] = {}
 
 _NULL = contextlib.nullcontext()
 _trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is there
@@ -547,6 +550,13 @@ def plane_snapshot() -> Dict[str, dict]:
         }
 
 
+def note_product_forms(forms: Dict[str, str]) -> None:
+    """`ops.limbs` hands over the form of each limb product (`fp_mul` in
+    `health_section`), as frames hand over `window_bits`: ops pushes, this
+    module never imports ops. Not ledger state: `reset` keeps it."""
+    _product_forms.update(forms)
+
+
 def reset() -> None:
     """Drop all ledger state (registry metrics are untouched)."""
     global _last_frame
@@ -568,7 +578,8 @@ def _waste(rows: int, padded: int) -> Optional[float]:
 
 def health_section() -> dict:
     """The `device` block of `Network.health()` / the `ops.health` RPC:
-    per-plane occupancy plus the full per-program ledger."""
+    per-plane occupancy plus the full per-program ledger, and the form of
+    each limb product under every program (`fp_mul`)."""
     snap = snapshot()
     spans = plane_snapshot()
     programs: Dict[str, dict] = {}
@@ -614,7 +625,12 @@ def health_section() -> dict:
             agg["glue_parts"] = {
                 part: round(v, 6) for part, v in sp["glue_parts"].items()
             }
-    return {"enabled": enabled(), "planes": planes, "programs": programs}
+    return {
+        "enabled": enabled(),
+        "fp_mul": dict(_product_forms),
+        "planes": planes,
+        "programs": programs,
+    }
 
 
 def section() -> dict:
